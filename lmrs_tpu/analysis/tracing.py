@@ -18,9 +18,8 @@ recompile every step) inside jitted/scanned code in ``models/``, ``ops/``,
   the value at first trace and will never see the update;
 * ``tracing.deprecated-api`` — the deprecated/moved-API table (run on
   EVERY module): ``jax.shard_map`` / ``jax.experimental.shard_map`` /
-  ``pltpu.CompilerParams`` outside ``utils/jax_compat.py`` (AttributeError
-  on the pinned 0.4.x CPU build — the class behind the five pre-existing
-  ``test_kernels`` failures), ``jax.tree_map`` family (removed upstream).
+  ``pltpu.CompilerParams`` outside ``utils/jax_compat.py`` (the one module
+  that follows their renames), ``jax.tree_map`` family (removed upstream).
 
 Traced contexts: functions decorated ``@jax.jit`` (bare or via
 ``partial``), functions wrapped ``jax.jit(f)``, and local functions passed
@@ -45,15 +44,14 @@ _LAX_HOFS = frozenset(("scan", "while_loop", "cond", "switch", "fori_loop",
 
 # dotted-name -> (replacement hint).  The shim module itself is exempt.
 _DEPRECATED = {
-    "jax.shard_map": "use lmrs_tpu.utils.jax_compat.shard_map (the pinned "
-                     "0.4.x build has no jax.shard_map — AttributeError "
-                     "at call time)",
+    "jax.shard_map": "use lmrs_tpu.utils.jax_compat.shard_map (one "
+                     "place to follow the next rename)",
     "jax.experimental.shard_map": "import via lmrs_tpu.utils.jax_compat."
-                                  "shard_map (one bridge for both jax "
-                                  "generations)",
+                                  "shard_map (the experimental home is "
+                                  "gone from the installed jax)",
     "pltpu.CompilerParams": "use lmrs_tpu.utils.jax_compat."
-                            "tpu_compiler_params (named TPUCompilerParams "
-                            "on the pinned 0.4.x build)",
+                            "tpu_compiler_params (one place to follow "
+                            "the next rename)",
     "jax.tree_map": "use jax.tree.map (removed from the jax namespace)",
     "jax.tree_multimap": "use jax.tree.map",
     "jax.tree_leaves": "use jax.tree.leaves",

@@ -326,13 +326,15 @@ class SupervisedHostPool:
         import subprocess
         import sys
 
+        from lmrs_tpu.utils.platform import child_env
+
         port = self._free_port(self.host)
         netloc = f"{self.host}:{port}"
         argv = [sys.executable, "-m", "lmrs_tpu.serving.cli",
                 "--supervise", "--host", self.host, "--port", str(port),
                 "--quiet", *self.base_argv]
         try:
-            proc = subprocess.Popen(argv)
+            proc = subprocess.Popen(argv, env=child_env())
         except OSError:
             logger.warning("supervised spawn exec failed", exc_info=True)
             return None

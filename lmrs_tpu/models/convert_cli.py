@@ -46,10 +46,6 @@ def main(argv: list[str] | None = None) -> int:
 
     args = build_parser().parse_args(argv)
     setup_logging(quiet=args.quiet)
-    from lmrs_tpu.utils.platform import honor_platform_env
-
-    honor_platform_env()
-
     from lmrs_tpu.config import model_preset
     from lmrs_tpu.models.loader import (
         convert_hf_gemma, convert_hf_llama, save_checkpoint,

@@ -398,6 +398,7 @@ def forward_paged(
         paged_decode_xla,
         ragged_spans_pallas,
         ragged_spans_xla,
+        scatter_kv_rows,
     )
     from lmrs_tpu.ops.quant import (kv_dequant, kv_quant, kv_quant_tokens,
                                     kv_scale_from)
@@ -618,9 +619,9 @@ def forward_paged(
             return _finish_layer(lp, x, attn_out, kp_all, vp_all, ksc, vsc)
 
         # scatter current K/V into the page-major pool: [L*P, K, ps, hd]
-        # at [g_page_idx[b,s], :, offsets[b,s]] (advanced indices around
-        # the head slice put the advanced dims first: updates are
-        # [B, S, K, hd] — the K/V's own layout).  Int8 pools store the
+        # at [g_page_idx[b,s], :, offsets[b,s]] (updates are [B, S, K, hd]
+        # — the K/V's own layout; scatter_kv_rows says why it is spelled
+        # the way it is).  Int8 pools store the
         # quantized rows; attention below reads the ORIGINAL k/v wherever
         # the current tokens are the whole context (fresh prefill), so only
         # pool readers pay quantization error
@@ -632,8 +633,8 @@ def forward_paged(
             else:
                 k_store = kv_quant(k, row_scales[0])
                 v_store = kv_quant(v, row_scales[1])
-        kp_all = kp_all.at[g_page_idx, :, offsets].set(k_store)
-        vp_all = vp_all.at[g_page_idx, :, offsets].set(v_store)
+        kp_all = scatter_kv_rows(kp_all, g_page_idx, offsets, k_store)
+        vp_all = scatter_kv_rows(vp_all, g_page_idx, offsets, v_store)
 
         if is_decode:
             attn = paged_decode_xla(q[:, 0], kp_all, vp_all, g_tables, kv_lens,

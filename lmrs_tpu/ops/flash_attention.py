@@ -12,7 +12,9 @@ valid-length (`lengths`, from SMEM) masks padded KV — the kernel equivalent
 of ops.attention's (causal & kv_length) rule.
 
 Correctness contract: must match ops.attention.attention() to f32 tolerance —
-see tests/test_kernels.py.  Falls back to interpret mode off-TPU.
+see tests/test_kernels.py.  Interpret mode is explicit (``interpret=True``,
+the CPU test path); there is no automatic fallback off-TPU — without it
+the kernel lowers through Mosaic or raises.
 """
 
 from __future__ import annotations

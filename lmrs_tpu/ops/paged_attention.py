@@ -129,6 +129,23 @@ def _fetch_page(page_tables_ref, k_hbm, v_hbm, k_scr, v_scr, sem,
 # ------------------------------------------------------------ XLA fallback
 
 
+def scatter_kv_rows(pool: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray,
+                    rows: jnp.ndarray) -> jnp.ndarray:
+    """Write ``rows`` [..., K, hd] into the page-major pool [P, K, ps, hd]
+    at ``[page[...], :, off[...]]`` — THE XLA pool write (prefill, and the
+    XLA decode / multi / span paths).
+
+    All three leading dims are indexed (the kv heads through an arange), so
+    the scatter window is a single hd row.  The obvious spelling
+    ``pool.at[page, :, off].set(rows)`` has a [K, hd] window, for which the
+    TPU compiler re-lays-out the WHOLE pool: a pool-sized HBM temporary per
+    pool, copied in and out of every program that writes — 2 x 2 GiB at the
+    Llama-3-8B shape with the default pool, which does not fit one chip.
+    This form compiles with no temporary, also kv-head-sharded under tp."""
+    kh = pool.shape[1]
+    return pool.at[page[..., None], jnp.arange(kh), off[..., None]].set(rows)
+
+
 def paged_decode_xla(
     q: jnp.ndarray,            # [B, H, hd]
     k_pages: jnp.ndarray,      # [P, K, ps, hd]
@@ -902,10 +919,8 @@ def paged_decode_multi_xla(
 
         k_new = kv_quant(k_new, kv_scales[0])
         v_new = kv_quant(v_new, kv_scales[1])
-    # page-major scatter: advanced indices (page, off) with the head slice
-    # between put the advanced dims first -> updates take [B, T, K, hd]
-    k_pages = k_pages.at[page, :, off].set(k_new)
-    v_pages = v_pages.at[page, :, off].set(v_new)
+    k_pages = scatter_kv_rows(k_pages, page, off, k_new)  # [B, T, K, hd]
+    v_pages = scatter_kv_rows(v_pages, page, off, v_new)
 
     n_rep = h // kh
     k_win = k_pages[page_tables].transpose(0, 1, 3, 2, 4).reshape(
@@ -1167,8 +1182,8 @@ def ragged_spans_xla(
     anc_masks: jnp.ndarray | None = None,  # [Tp] int32 ancestor bitmasks
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Scatter + gather reference for the ragged span kernel: same contract
-    on any platform (correctness baseline, the sp>1 path, and the CPU /
-    first-run-lowering fallback).  ``row_flat`` is the host-built inverse
+    on any platform (correctness baseline, the sp>1 / tp-mesh path, and the
+    CPU path).  ``row_flat`` is the host-built inverse
     of the span list — the kernel derives it from (q_starts, q_lens); XLA
     wants it materialized.  Out-of-span tokens park their writes on the
     reserved null page (id 0) and produce zero output rows.
@@ -1205,8 +1220,8 @@ def ragged_spans_xla(
 
         k_new = kv_quant_tokens(k_new, kv_scales[0][rf])
         v_new = kv_quant_tokens(v_new, kv_scales[1][rf])
-    k_pages = k_pages.at[page, :, pos_c % ps].set(k_new)
-    v_pages = v_pages.at[page, :, pos_c % ps].set(v_new)
+    k_pages = scatter_kv_rows(k_pages, page, pos_c % ps, k_new)
+    v_pages = scatter_kv_rows(v_pages, page, pos_c % ps, v_new)
 
     n_rep = h // kh
     k_win = k_pages[page_tables].transpose(0, 1, 3, 2, 4).reshape(

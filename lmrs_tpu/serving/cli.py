@@ -101,9 +101,9 @@ def main(argv: list[str] | None = None) -> int:
         raw = list(argv) if argv is not None else _sys.argv[1:]
         child_argv = [a for a in raw if a != "--supervise"]
         return Supervisor(child_argv, host=args.host, port=args.port).run()
-    from lmrs_tpu.utils.platform import honor_platform_env
+    from lmrs_tpu.utils.platform import setup_compile_cache
 
-    honor_platform_env()
+    setup_compile_cache()
     if args.trace or env_bool("LMRS_TRACE", False):
         # before the engine builds: the scheduler captures the tracer per
         # run, and serving spans must cover the first request
@@ -152,6 +152,17 @@ def main(argv: list[str] | None = None) -> int:
         logger.error("cannot bind %s:%d: %s", args.host, args.port, e)
         engine.shutdown()
         return 1
+    # SIGTERM (the supervisor's and any launcher's graceful stop) takes
+    # the same path as Ctrl-C: drain the server, release the device
+    import signal
+
+    def _on_sigterm(_signum, _frame):
+        raise KeyboardInterrupt
+
+    try:
+        signal.signal(signal.SIGTERM, _on_sigterm)
+    except ValueError:
+        pass  # not the main thread (tests drive main() from a thread)
     try:
         server.serve_forever()
     except KeyboardInterrupt:

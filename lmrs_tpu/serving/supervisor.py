@@ -41,6 +41,7 @@ import urllib.error
 import urllib.request
 
 from lmrs_tpu.utils.env import env_float, env_int, env_str
+from lmrs_tpu.utils.platform import child_env
 
 logger = logging.getLogger("lmrs.supervisor")
 
@@ -71,7 +72,7 @@ class Supervisor:
     def _spawn(self) -> subprocess.Popen:
         cmd = [sys.executable, "-m", "lmrs_tpu.serving.cli",
                *self.child_argv]
-        child = subprocess.Popen(cmd)
+        child = subprocess.Popen(cmd, env=child_env())
         logger.info("supervisor: child pid %d spawned (restart #%d)",
                     child.pid, self.restarts)
         if self.pidfile:

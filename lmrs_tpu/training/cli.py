@@ -102,10 +102,10 @@ def batches(seqs, masks, batch_size: int, seq_len: int, seed: int):
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     from lmrs_tpu.utils.logging import setup_logging
-    from lmrs_tpu.utils.platform import honor_platform_env
+    from lmrs_tpu.utils.platform import setup_compile_cache
 
     setup_logging(quiet=args.quiet)
-    honor_platform_env()
+    setup_compile_cache()
 
     import jax
     import jax.numpy as jnp

@@ -3,8 +3,9 @@
 The reference makes every pipeline module self-demoing against
 ``transcript-example.json`` (preprocessor.py:364, big_chunkeroosky.py:570,
 llm_executor.py:460, result_aggregator.py:527) — the de-facto smoke tests.
-This helper feeds the same pattern here: the real example transcript when the
-reference checkout is present, otherwise a deterministic synthetic one.
+This helper feeds the same pattern here: the example transcript when the
+checkout carries it under ``tests/data``, otherwise a deterministic synthetic
+one (never a path outside the checkout).
 """
 
 from __future__ import annotations
@@ -14,7 +15,6 @@ import random
 from pathlib import Path
 
 _CANDIDATES = (
-    Path("/root/reference/transcript-example.json"),
     Path(__file__).resolve().parents[2] / "tests" / "data" / "transcript-example.json",
 )
 

@@ -1,108 +1,25 @@
-"""Version-bridging wrappers for JAX APIs that moved between releases.
+"""The one place the repo names JAX APIs that have moved between releases.
 
-The repo targets the modern public surface (``jax.shard_map`` with its
-``check_vma`` kwarg) but must also run on the pinned CPU build
-(jax 0.4.37), where ``shard_map`` still lives in ``jax.experimental``
-under the older ``check_rep`` spelling — accessing ``jax.shard_map``
-there raises ``AttributeError`` from the deprecation registry.
-
-All call sites import from HERE (the lmrs-lint deprecated-API sub-pass
-flags direct ``jax.shard_map`` / ``jax.experimental.shard_map`` use
-anywhere else), so the day the old build is dropped this module shrinks
-to one line instead of a five-file sweep.
+All call sites import ``shard_map`` / ``tpu_compiler_params`` from HERE
+(the lmrs-lint deprecated-API sub-pass flags direct ``jax.shard_map`` /
+``pltpu.CompilerParams`` use anywhere else), so the next rename is a
+one-file change.  Only the installed JAX is supported: no branch here
+probes for another version.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):  # modern surface (jax >= 0.6)
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:  # pinned 0.4.x: experimental home, check_rep spelling
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-
-shard_map.__doc__ = """``jax.shard_map`` on every supported jax.
-
-Keyword-only, mirroring the modern signature; ``check_vma`` maps onto the
-legacy ``check_rep`` on 0.4.x builds (same meaning: verify per-axis value
-replication instead of trusting ``out_specs``)."""
-
-
-_SHARDED_DONATION_PROBE: list = []  # memoized [error-or-None]
-
-
-def sharded_donation_error() -> str | None:
-    """Capability probe for donated sharded train updates, memoized per
-    process.  The pinned CPU jaxlib (0.4.37 under the forced-host-device
-    environment) fails donation aliasing on dp×tp-sharded train steps
-    with ``INTERNAL: Expected aliased input ... to have the same size``
-    — the runtime compares a replicated input's GLOBAL shape against the
-    output's per-shard sub-shape.  Real TPU builds are unaffected.
-
-    Runs ONE micro train step (dim 16, 1 layer, ~2 s on CPU) through the
-    repo's own ``make_train_step`` — the exact machinery the capability
-    gates — and returns the error string ONLY for the known
-    donation-aliasing signature.  Any other failure returns ``None``
-    (as does a probe that cannot run: fewer than 4 devices, optax
-    missing): a genuine regression in make_train_step/shard_params must
-    FAIL the real tests, never hide behind an "environmental" skip.
-    Tests that need donated sharded updates skip-with-reason on a
-    non-None return instead of erroring."""
-    if _SHARDED_DONATION_PROBE:
-        return _SHARDED_DONATION_PROBE[0]
-    err: str | None = None
-    try:
-        import jax.numpy as jnp
-        import numpy as np
-        import optax
-
-        from lmrs_tpu.config import MeshConfig, ModelConfig
-        from lmrs_tpu.models.transformer import init_params
-        from lmrs_tpu.parallel.mesh import build_mesh
-        from lmrs_tpu.parallel.sharding import shard_params
-        from lmrs_tpu.training.train import make_train_step
-
-        if len(jax.devices()) < 4:
-            _SHARDED_DONATION_PROBE.append(None)
-            return None
-        cfg = ModelConfig(vocab_size=32, dim=16, n_layers=1, n_heads=4,
-                          n_kv_heads=2, hidden_dim=32, max_seq_len=32,
-                          dtype="float32")
-        mesh = build_mesh(MeshConfig(dp=2, tp=2, sp=1, pp=1),
-                          jax.devices()[:4])
-        params = shard_params(init_params(cfg, jax.random.PRNGKey(0)),
-                              mesh, cfg.tie_embeddings)
-        opt = optax.adam(1e-3)
-        step = make_train_step(cfg, opt, mesh)
-        tokens = jnp.asarray(np.zeros((4, 16), dtype=np.int32))
-        _, _, loss = step(params, opt.init(params), tokens)
-        float(loss)
-    except ImportError:
-        err = None  # can't probe here; don't mask anything
-    except Exception as e:  # noqa: BLE001 - filtered to the known class
-        # ONLY the documented runtime-aliasing bug counts as a missing
-        # capability; anything else is a real error the tests must see
-        if "Expected aliased input" in str(e):
-            err = f"{type(e).__name__}: {e}"
-    _SHARDED_DONATION_PROBE.append(err)
-    return err
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    """``jax.shard_map``, keyword-only."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` across the rename: modern Pallas calls it
-    ``CompilerParams``, 0.4.x ``TPUCompilerParams`` — same fields
-    (``dimension_semantics`` et al.)."""
+    """``pltpu.CompilerParams`` (``dimension_semantics`` et al.)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)

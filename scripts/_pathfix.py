@@ -1,5 +1,5 @@
-"""Make the repo root importable when a script runs without the editable
-install (`python scripts/x.py` puts scripts/ on sys.path, not the root).
+"""Make the repo root importable: nothing is pip-installed, and
+`python scripts/x.py` puts scripts/ on sys.path, not the root.
 Import for its side effect: ``import _pathfix``."""
 import sys
 from pathlib import Path

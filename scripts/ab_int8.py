@@ -4,6 +4,9 @@ Two engines (params differ), alternating decode-heavy waves A B B A.
 Run: python scripts/ab_int8.py
 """
 import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import time
 
 import numpy as np

@@ -3,6 +3,9 @@ both arms with int8 weights — the bench default).  Decode-heavy waves.
 Run: python scripts/ab_kv_int8.py
 """
 import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import time
 
 import numpy as np

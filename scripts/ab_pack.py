@@ -7,6 +7,9 @@ LMRS_AB_KV=int8: both arms run int8 KV pools (the r4 composition row —
 packed+int8 vs unpacked+int8, VERDICT r3 item 3).
 """
 import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import sys
 import time
 

@@ -9,6 +9,9 @@ unset/1 and reports its accept/dispatch block; LMRS_SPEC_TREE=0 is the
 linear-speculation A/B control for the same command line.
 """
 import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import time
 
 import numpy as np

@@ -12,6 +12,9 @@ amortize), unlike the in-tree tiny quality model (RTT-bound; docs/PERF.md
 round 3).  Run on the real chip: python scripts/ab_spec_trained.py
 """
 import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import json
 import tempfile
 import time

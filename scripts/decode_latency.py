@@ -19,6 +19,9 @@ import argparse
 import time
 
 import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import numpy as np
 
 from lmrs_tpu.config import EngineConfig, model_preset

@@ -31,6 +31,9 @@ import time
 
 
 import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -1,9 +1,12 @@
 """perf_sentry — noise-aware perf-regression checker over the bench history.
 
-Every hardware round appends a ``BENCH_r*.json`` / ``BENCH8B_r*.json`` /
-``MULTICHIP_r*.json`` artifact to the repo root (and the A/B rounds
-append ``FAIRNESS_r*.json`` / ``MIGRATE_r*.json``, scripts/ab_fairness.py
-and scripts/ab_migrate.py), but nothing READ them:
+The pre-round workflow appended a ``BENCH_r*.json`` / ``BENCH8B_r*.json`` /
+``MULTICHIP_r*.json`` artifact to the repo root per hardware round (and the
+A/B rounds ``FAIRNESS_r*.json`` / ``MIGRATE_r*.json``, scripts/ab_fairness.py
+and scripts/ab_migrate.py).  Of the root history only ``BENCH8B_r05``, the
+CPU-mesh ``MULTICHIP`` dry runs and ``FAIRNESS_r01`` remain (the
+``BENCH_r01-r05`` rows left in PR 22; this round's numbers go to
+``PERF_LEDGER.jsonl``).  Nothing READ such artifacts:
 a regression slipped into a round would sit unnoticed until a human
 diffed the trajectory.  The sentry makes the history a gate:
 

@@ -19,7 +19,10 @@ CPU/interpret smoke: LMRS_SERVE_MODEL=bench-smoke LMRS_SERVE_CPU=1 runs
 the identical harness without int8 (the no-chip admission-interleave
 demonstration CI quotes)."""
 import json, sys, time
-sys.path.insert(0, "/root/repo")
+import _pathfix  # noqa: F401  (repo-root import shim)
+from lmrs_tpu.utils.platform import setup_compile_cache
+
+setup_compile_cache()
 import numpy as np
 from lmrs_tpu.config import EngineConfig, model_preset
 from lmrs_tpu.utils.env import env_bool, env_str

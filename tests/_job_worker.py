@@ -29,6 +29,8 @@ import sys
 from pathlib import Path
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# started as a script, so tests/ — not the checkout — is on sys.path
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
 def job_transcript(n: int = 30, seed: int = 1) -> dict:
@@ -127,15 +129,9 @@ def main(spec_path: str) -> int:
     spec = json.loads(Path(spec_path).read_text(encoding="utf-8"))
     # share the parent's persistent XLA compile cache (conftest.py): the
     # child's engine compiles the same tiny shapes the suite already built
-    try:
-        import jax
+    from lmrs_tpu.utils.platform import setup_compile_cache
 
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_compilation_cache_dir", "/tmp/lmrs_jax_cache")
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 - mock arm / old jax: cache is optional
-        pass
+    setup_compile_cache()
     if spec.get("mode") == "serve":
         return serve(spec)
 

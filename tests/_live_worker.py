@@ -27,6 +27,8 @@ import sys
 from pathlib import Path
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# started as a script, so tests/ — not the checkout — is on sys.path
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
 def live_segments(n: int = 60, seed: int = 2) -> list[dict]:

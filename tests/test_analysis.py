@@ -632,8 +632,7 @@ def test_flash_block_empty_string_does_not_crash(monkeypatch):
 # ------------------------------------------------------------ shim smoke
 
 def test_jax_compat_shard_map_resolves():
-    """The compat shim must resolve on whichever jax is pinned — the
-    class behind the five pre-existing test_kernels AttributeErrors."""
+    """The compat shim must resolve on the installed jax."""
     from lmrs_tpu.utils.jax_compat import shard_map, tpu_compiler_params
 
     assert callable(shard_map)

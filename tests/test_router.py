@@ -23,6 +23,7 @@ import pytest
 
 from lmrs_tpu.engine.api import GenerationRequest
 from lmrs_tpu.serving.router import RouterEngine
+from lmrs_tpu.utils.platform import child_env
 
 
 from tests.conftest import free_port as _free_port
@@ -54,7 +55,7 @@ def _spawn_mock_worker(port: int) -> subprocess.Popen:
     return subprocess.Popen(
         [sys.executable, "-m", "lmrs_tpu.serving.cli",
          "--backend", "mock", "--port", str(port), "-q"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd="/root/repo",
+        env=child_env(JAX_PLATFORMS="cpu"),
         stdout=subprocess.DEVNULL, stderr=subprocess.PIPE)
 
 
@@ -75,14 +76,14 @@ def cluster():
     compiles on CPU) + a RouterEngine over both."""
     ports = [_free_port(), _free_port()]
     urls = [f"http://127.0.0.1:{p}" for p in ports]
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = child_env(JAX_PLATFORMS="cpu")
     procs = [
         subprocess.Popen(
             [sys.executable, "-m", "lmrs_tpu.serving.cli",
              "--backend", "jax", "--model", "quality-tiny",
              "--tokenizer", "byte", "--port", str(p),
              "--batch-slots", "2", "--max-tokens-cap", "1024", "-q"],
-            env=env, cwd="/root/repo",
+            env=env,
             stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
         )
         for p in ports
@@ -320,7 +321,7 @@ def test_dead_host_recovers_via_probe(cluster):
     procs[1] = subprocess.Popen(
         [sys.executable, "-m", "lmrs_tpu.serving.cli",
          "--backend", "mock", "--port", port, "-q"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd="/root/repo",
+        env=child_env(JAX_PLATFORMS="cpu"),
         stdout=subprocess.DEVNULL, stderr=subprocess.PIPE)
     _wait_healthy(urls[1], procs[1], deadline_s=60)
     # each wave launches probes at unhealthy hosts; a couple of waves give

@@ -169,24 +169,25 @@ def test_mock_engine_metrics_empty():
     assert MockEngine().engine_metrics() == {}
 
 
-def test_ragged_kernel_failure_degrades_to_xla(cont_engine):
-    """If the ragged Pallas kernel can't lower on this platform, the decode
-    dispatch must fall back to the XLA gather path, not fail the batch."""
-    sched = cont_engine._scheduler
+def test_ragged_kernel_failure_raises_no_xla_fallback():
+    """A Pallas kernel that cannot lower RAISES out of the scheduler: there
+    is no catch-and-retry on XLA attention (on hardware one Mosaic refusal
+    would otherwise quietly move the whole engine off its kernels), and the
+    kernel gate is never flipped behind the caller's back."""
+    engine = JaxEngine(EngineConfig(backend="jax", scheduler="continuous",
+                                    max_tokens=8, max_batch_slots=2, seed=0),
+                       tiny_model())
+    sched = engine._scheduler
     sched._use_ragged = True  # force the kernel on CPU, where it can't lower
-    sched._decode_fns.clear()
-    # drop run-history: the fallback (correctly) only triggers on shapes that
-    # have never executed — a failure on a proven shape re-raises
-    sched._ran_ok = {k for k in sched._ran_ok if k[0] != "decode"}
     try:
-        out = cont_engine.generate_batch(
-            [GenerationRequest(prompt="fallback probe", request_id=0,
-                               max_new_tokens=4)])
+        with pytest.raises(ValueError, match="interpret mode"):
+            engine.generate_batch(
+                [GenerationRequest(prompt="no fallback probe", request_id=0,
+                                   max_new_tokens=4)])
+        assert sched._use_ragged is True
+        assert not hasattr(sched, "_invalidate_compiled")
     finally:
-        sched._use_ragged = False
-        sched._decode_fns.clear()
-    assert out[0].error is None
-    assert out[0].completion_tokens > 0
+        engine.shutdown()
 
 
 def test_tp_sharded_continuous_serving_matches_single_device():
@@ -402,10 +403,28 @@ def test_preemption_under_page_pressure_preserves_output():
         assert g.completion_tokens == w.completion_tokens
 
 
-def test_roofline_microbench_smoke(cont_engine):
+def test_roofline_microbench_refuses_unknown_device(cont_engine):
+    """No peaks are known for the CPU backend, so the probe has nothing to
+    divide by: it raises instead of reporting against assumed v5e peaks, and
+    leaves the engine serving."""
+    with pytest.raises(RuntimeError, match="no peaks known"):
+        cont_engine._scheduler.roofline_microbench(prefill_reps=1,
+                                                   decode_reps=1)
+    out = cont_engine.generate_batch(
+        [GenerationRequest(prompt="still serving", request_id=0,
+                           max_new_tokens=4)])
+    assert out[0].error is None and out[0].completion_tokens > 0
+
+
+def test_roofline_microbench_smoke(cont_engine, monkeypatch):
     """The roofline probe shares the compiled-program arg contract with the
-    scheduler; this smoke run catches signature drift off-chip (the real
-    numbers only mean something on TPU — bench.py)."""
+    scheduler; this smoke run catches signature drift off-chip.  The peaks
+    are handed in HERE (the test's own steering): the numbers only mean
+    something on a TPU — bench.py."""
+    from lmrs_tpu.utils import perf_model
+
+    monkeypatch.setattr(perf_model, "chip_spec",
+                        lambda: perf_model.ChipSpec("test", 197e12, 819e9))
     out = cont_engine._scheduler.roofline_microbench(prefill_reps=2,
                                                      decode_reps=1)
     for key in ("prefill_tokens_per_sec", "decode_tokens_per_sec"):

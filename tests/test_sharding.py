@@ -16,21 +16,6 @@ pytestmark = pytest.mark.skipif(
 )
 
 
-def _require_donated_sharded_steps():
-    """Skip-with-reason when donated sharded train updates are broken on
-    this build (the pinned CPU jaxlib fails donation aliasing under
-    dp×tp meshes with ``INTERNAL: Expected aliased input ...``) — a
-    detected environment capability, not a repo regression.  The probe
-    (utils/jax_compat.sharded_donation_error) runs the repo's own micro
-    train step once per process and memoizes."""
-    from lmrs_tpu.utils.jax_compat import sharded_donation_error
-
-    err = sharded_donation_error()
-    if err:
-        pytest.skip("donated sharded train steps broken on this jaxlib "
-                    f"build (environmental): {err[:160]}")
-
-
 def cfg8():
     return ModelConfig(vocab_size=64, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
                        hidden_dim=64, max_seq_len=128, dtype="float32")
@@ -86,7 +71,6 @@ def test_param_sharding_layout():
 def test_training_step_on_mesh():
     """Full sharded train step (the dryrun_multichip path) runs and reduces
     loss over a few steps on memorizable data."""
-    _require_donated_sharded_steps()
     import optax
 
     from lmrs_tpu.training.train import make_train_step
@@ -110,7 +94,6 @@ def test_training_step_on_mesh():
 
 
 def test_dryrun_multichip_entrypoint():
-    _require_donated_sharded_steps()
     import importlib.util, pathlib
 
     spec = importlib.util.spec_from_file_location(
@@ -159,7 +142,6 @@ def test_remat_grads_match_non_remat():
 
 
 def test_remat_train_step_on_mesh():
-    _require_donated_sharded_steps()
     import optax
 
     from lmrs_tpu.training.train import make_train_step

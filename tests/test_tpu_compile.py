@@ -1,0 +1,254 @@
+"""Ask the TPU's compiler before the chip: the main path's Pallas kernels,
+at the widths ``chip_smoke.py`` runs them, must COMPILE for a described
+v5e (``jax.experimental.topologies``) — no device attached, nothing runs.
+
+Interpret-mode tests cannot see what Mosaic refuses (a slice off the
+tiling, too much VMEM) or what XLA's TPU layout assignment does around a
+kernel, and a chip call to find that out costs minutes.  Each compile here
+is a second or two.
+
+Rules (on-chip-measurement guide §2): the topology is described inside a
+module-scoped, non-autouse fixture of THIS file (only one process may load
+the TPU library, and every xdist worker imports every test file), nothing
+touches it at import, compiles run in the test's own process with the
+persistent compilation cache off (a TPU executable written from here cannot
+be read back without a chip).
+"""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = Path(__file__).resolve().parent.parent
+
+# (H, K, hd) — Llama-3-8B and Gemma-2B attention head shapes
+LLAMA8B = (32, 8, 128)
+GEMMA2B = (8, 1, 256)
+SHAPES = [pytest.param(LLAMA8B, id="llama3-8b"),
+          pytest.param(GEMMA2B, id="gemma-2b")]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower + compile ``fn`` for the described chip on abstract args;
+    returns (compiled, optimized-HLO text)."""
+    args = [None if s is None
+            else jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+def test_topology_is_v5e(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    assert len(topo.devices) == 4
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["ragged", "packed"])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_flash_prefill_compiles(one_chip, shape, packed):
+    from lmrs_tpu.ops.flash_attention import flash_attention
+
+    h, k, hd = shape
+    s = 4096 if packed else 2048
+    bf = jnp.bfloat16
+
+    def fn(q, kk, v, lengths, seg):
+        return flash_attention(q, kk, v, lengths, segment_ids=seg)
+
+    _, hlo = _compile(fn, one_chip, ((1, s, h, hd), bf), ((1, s, k, hd), bf),
+                      ((1, s, k, hd), bf), ((1,), jnp.int32),
+                      ((1, s), jnp.int32) if packed else None)
+    assert "tpu_custom_call" in hlo
+
+
+def _decode_shapes(shape, b, ps, w, pool_dtype, t=None):
+    h, k, hd = shape
+    n_pages = 1 + b * w
+    bf = jnp.bfloat16
+    lead = (b,) if t is None else (b, t)
+    out = [(lead + (h, hd), bf), (lead + (k, hd), bf), (lead + (k, hd), bf),
+           ((n_pages, k, ps, hd), pool_dtype),
+           ((n_pages, k, ps, hd), pool_dtype),
+           ((b, w), jnp.int32), ((b,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        out += [((b, k, hd), jnp.float32), ((b, k, hd), jnp.float32)]
+    return out
+
+
+# (page_size, row_group, pool dtype): the old bench geometry (ps 512 bf16,
+# ps 1024 int8, B=24) and the CLI-default geometry chip_smoke.py serves
+# (ps 128, int8, row_group 4)
+FUSED_CASES = [
+    pytest.param(512, 1, jnp.bfloat16, id="bf16-ps512-g1"),
+    pytest.param(512, 4, jnp.bfloat16, id="bf16-ps512-g4"),
+    pytest.param(1024, 4, jnp.int8, id="int8-ps1024-g4"),
+    pytest.param(128, 4, jnp.int8, id="int8-ps128-g4"),
+]
+
+
+@pytest.mark.parametrize("ps,row_group,pool_dtype", FUSED_CASES)
+@pytest.mark.parametrize("shape", SHAPES)
+def test_fused_decode_compiles(one_chip, shape, ps, row_group, pool_dtype):
+    from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
+
+    quant = pool_dtype == jnp.int8
+
+    def fn(q, kn, vn, kp, vp, tables, lens, ks=None, vs=None):
+        return paged_decode_pallas_fused(q, kn, vn, kp, vp, tables, lens,
+                                         kscale=ks, vscale=vs,
+                                         row_group=row_group)
+
+    _, hlo = _compile(fn, one_chip,
+                      *_decode_shapes(shape, 24, ps, 2048 // ps, pool_dtype))
+    assert "tpu_custom_call" in hlo
+    assert quant == ("s8[" in hlo)
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_multi_token_verify_compiles(one_chip, shape, pool_dtype):
+    from lmrs_tpu.ops.paged_attention import paged_decode_pallas_multi
+
+    def fn(q, kn, vn, kp, vp, tables, lens, ks=None, vs=None):
+        return paged_decode_pallas_multi(q, kn, vn, kp, vp, tables, lens,
+                                         kscale=ks, vscale=vs)
+
+    _, hlo = _compile(fn, one_chip,
+                      *_decode_shapes(shape, 24, 512, 4, pool_dtype, t=5))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("ps,pool_dtype", [
+    pytest.param(512, jnp.bfloat16, id="bf16-ps512"),
+    pytest.param(512, jnp.int8, id="int8-ps512"),
+    pytest.param(128, jnp.int8, id="int8-ps128"),
+])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_ragged_spans_compile(one_chip, shape, ps, pool_dtype):
+    from lmrs_tpu.ops.paged_attention import ragged_spans_pallas
+
+    h, k, hd = shape
+    b, tp, w = 24, 512, 2048 // ps
+    bf = jnp.bfloat16
+    shapes = [((tp, h, hd), bf), ((tp, k, hd), bf), ((tp, k, hd), bf),
+              ((1 + b * w, k, ps, hd), pool_dtype),
+              ((1 + b * w, k, ps, hd), pool_dtype),
+              ((b, w), jnp.int32), ((b,), jnp.int32), ((b,), jnp.int32),
+              ((b,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        shapes += [((b, k, hd), jnp.float32), ((b, k, hd), jnp.float32)]
+
+    def fn(q, kn, vn, kp, vp, tables, lens, qs, ql, ks=None, vs=None):
+        return ragged_spans_pallas(q, kn, vn, kp, vp, tables, lens, qs, ql,
+                                   kscale=ks, vscale=vs)
+
+    _, hlo = _compile(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_pool_write_needs_no_pool_sized_temporary(one_chip, pool_dtype):
+    """The XLA pool write (prefill / XLA decode paths) inside a donated
+    scan carry, at the Llama-3-8B pool shape of the CLI defaults (32 layers
+    x 512 pages): the TPU compiler must not re-lay-out the whole pool.  The
+    ``pool.at[page, :, off]`` spelling cost a pool-sized temporary per pool
+    here — the prefill program then needed 16.3 GB of a 15.75 GB chip."""
+    from lmrs_tpu.ops.paged_attention import scatter_kv_rows
+
+    lp, k, ps, hd, b, s = 32 * 512, 8, 128, 128, 8, 2048
+
+    def prog(pool, page, off, rows):
+        def body(carry, li):
+            return scatter_kv_rows(carry, page + li, off, rows), None
+
+        return jax.lax.scan(body, pool, jnp.arange(4))[0]
+
+    args = [jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+            for shp, dt in (((lp, k, ps, hd), pool_dtype),
+                            ((b, s), jnp.int32), ((b, s), jnp.int32),
+                            ((b, s, k, hd), pool_dtype))]
+    compiled = jax.jit(prog, donate_argnums=(0,)).lower(*args).compile()
+    ma = compiled.memory_analysis()
+    pool_bytes = lp * k * ps * hd * jnp.dtype(pool_dtype).itemsize
+    assert ma.alias_size_in_bytes >= pool_bytes  # donated in place
+    assert ma.temp_size_in_bytes < pool_bytes // 8, ma.temp_size_in_bytes
+
+
+# ------------------------------------------------ the new rules, no TPU needed
+
+
+def test_compile_cache_helper_leaves_config_alone_when_env_set(monkeypatch,
+                                                                tmp_path):
+    from lmrs_tpu.utils import platform
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "sentinel-untouched")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        platform.setup_compile_cache()
+        # the env var is JAX's to read; the helper set no directory in code
+        assert jax.config.jax_compilation_cache_dir == "sentinel-untouched"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        platform.setup_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(
+            ROOT / ".jax_cache")
+        assert platform.COMPILE_CACHE_DIR == ROOT / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_on_tpu_is_exact_and_unknown_device_has_no_peaks():
+    from lmrs_tpu.utils.perf_model import chip_spec
+    from lmrs_tpu.utils.platform import on_tpu
+
+    assert jax.devices()[0].platform == "cpu"
+    assert on_tpu() is False
+    assert chip_spec() is None
+
+
+def test_chip_smoke_refuses_a_cpu():
+    """``chip_smoke.py`` with JAX held to the CPU: non-zero exit, says it
+    found no TPU, never prints ``"ok": true``."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, str(ROOT / "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=300, env=env,
+                       cwd=str(ROOT))
+    assert r.returncode != 0
+    assert "no TPU found" in r.stderr
+    assert '"ok": true' not in r.stdout

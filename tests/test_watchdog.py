@@ -32,6 +32,7 @@ from lmrs_tpu.engine.mock import MockEngine
 from lmrs_tpu.engine.replicated import ReplicatedEngine
 from lmrs_tpu.testing import faults
 from lmrs_tpu.testing.faults import FaultPlan
+from lmrs_tpu.utils.platform import child_env
 
 sys.path.insert(0, os.path.dirname(__file__))
 import _job_worker as jw  # noqa: E402 - shared job transcript builder
@@ -621,8 +622,8 @@ def test_supervised_sigkill_respawn_resumes_job_token_identical(tmp_path):
     jobs_dir.mkdir()
     pidfile = tmp_path / "child.pid"
     port = _free_port()
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
+    env = child_env(
+        JAX_PLATFORMS="cpu",
         LMRS_SUPERVISE_PIDFILE=str(pidfile),
         LMRS_SUPERVISE_POLL_S="0.3",
         LMRS_SUPERVISE_BACKOFF_S="0.1",
@@ -635,7 +636,7 @@ def test_supervised_sigkill_respawn_resumes_job_token_identical(tmp_path):
         [sys.executable, "-m", "lmrs_tpu.serving.cli", "--supervise",
          "--backend", "mock", "--port", str(port),
          "--jobs-dir", str(jobs_dir), "-q"],
-        env=env, cwd="/root/repo",
+        env=env,
         stdout=subprocess.DEVNULL, stderr=subprocess.PIPE)
     base = f"http://127.0.0.1:{port}"
     try:
