@@ -47,7 +47,8 @@ from lmrs_tpu.models.transformer import forward_paged
 from lmrs_tpu.ops.paged_attention import pack_spans, pow2_bucket
 from lmrs_tpu.obs import (POW2_TOKEN_BUCKETS, RATIO_BUCKETS, CostLedger,
                           DispatchAttribution, MetricsRegistry, SLOEngine,
-                          dump_postmortem, get_tracer, maybe_anatomy, req_tid)
+                          dump_postmortem, get_tracer, maybe_anatomy, req_tid,
+                          span)
 from lmrs_tpu.ops.sampling import sample_logits
 from lmrs_tpu.testing import faults
 from lmrs_tpu.utils.env import env_bool, env_float, env_int, env_str
@@ -750,6 +751,10 @@ class ContinuousScheduler:
             "spec_accept_depth_sum": self._h_spec_depth.sum,
             "watchdog_fires": int(self._c_watchdog_fires.value),
             "wedged_requests": int(self._c_wedged.value),
+            # sums of the anatomy's dispatch records (obs/anatomy.py):
+            # prefill_dispatches / _query_tokens / _token_slots,
+            # cold_dispatches / cold_seconds; no keys under LMRS_ANATOMY=0
+            **self._an.counters(),
         }
 
     def metrics_registry(self) -> MetricsRegistry:
@@ -1163,6 +1168,13 @@ class ContinuousScheduler:
         preempted slot resumes deltas where it left off (progress is
         tracked per request id, not per slot).
         """
+        # sched.run: the outermost scheduler span; unix_ns is the host's
+        # wall clock at its start, so the Chrome-JSON export (on
+        # time.time()) can be laid over an xplane
+        with span("sched.run", unix_ns=time.time_ns()):
+            return self._run(requests, on_result, on_tokens)
+
+    def _run(self, requests, on_result, on_tokens):
         t_run = time.time()
         # taken BEFORE the first allocator touch: an off-thread
         # release_handoff freeing inline holds this lock, so it either
@@ -2024,7 +2036,11 @@ class ContinuousScheduler:
         # anatomy conservation: iteration wall == segment sums + residual
         # (obs/anatomy.py; totals only advance at iter_end, so this is
         # safe to call mid-run from a callback)
-        violations += self._an.audit()
+        # and the dispatch table's identities: its prompt positions add up
+        # to lmrs_prefill_tokens_total, its query positions to the flat
+        # counter, and every key holds dispatches x its bucket
+        violations += self._an.audit(
+            prefill_tokens=int(self._c_prefill_tokens.value))
         if violations:
             # an invariant break is exactly the moment the last-N spans
             # and counters matter; no-op unless the recorder is armed
@@ -3323,11 +3339,6 @@ class ContinuousScheduler:
             self._slo.observe_gap(now - last_block_t)
         last_block_t = now
         flops = self._perf.prefill_flops(c, kv_start=pos)
-        if self._tr:
-            self._tr.instant("prefill_dispatch",
-                             args={"rows": 1, "tokens": c, "bucket": T,
-                                   "mixed": True,
-                                   "flops_g": round(flops / 1e9, 3)})
         st_pf.prefill_pos = pos + c
 
         self._key, sub = jax.random.split(self._key)
@@ -3341,7 +3352,11 @@ class ContinuousScheduler:
         if not warm:
             self._wd_grace_cold()
         t_disp = time.time()
-        with self._an.seg("dispatch"):
+        with self._an.dispatch(
+                "mixed", key_, rows=len(rows) + 1, row_slots=self.B,
+                q_tokens=len(rows) + c, prompt_tokens=c,
+                q_slots=self.B * T, ctx_tokens=live_tokens + pos,
+                cold=not warm):
             nxt, self.cache.k, self.cache.v = \
                 self._get_mixed_fn(T, w)(*args)
         self._note_ran_ok(key_)
@@ -3937,10 +3952,9 @@ class ContinuousScheduler:
         else:
             gidx = last_of
 
+        # this dispatch pays for a tpb-token bucket but carries ``real``
+        # span tokens (the dispatch record below says so)
         real = dec_tokens + c
-        # bucket economics (obs/anatomy.py): this dispatch pays for a
-        # tpb-token bucket but carries ``real`` span tokens
-        self._an.note_bucket(tpb, w, real)
         self._h_occupancy.observe(len(rows) / self.B)
         self._c_decode_dispatches.inc()
         self._h_mixed_fill.observe(real / self.mixed_token_budget)
@@ -3956,12 +3970,6 @@ class ContinuousScheduler:
             self._c_prefill_tokens.inc(c)
             self._h_prefill_batch.observe(c)
             flops = self._perf.prefill_flops(c, kv_start=pos)
-            if self._tr:
-                self._tr.instant("prefill_dispatch",
-                                 args={"rows": 1, "tokens": c,
-                                       "bucket": tpb, "mixed": True,
-                                       "rpa": True,
-                                       "flops_g": round(flops / 1e9, 3)})
             st_pf.prefill_pos = pos + c
         if tree_live:
             self._c_spec_tree_disp.inc()
@@ -4004,12 +4012,13 @@ class ContinuousScheduler:
                 jnp.asarray(table[:, :w]), sub, jnp.asarray(temps),
                 jnp.asarray(top_k), jnp.asarray(top_p))
 
-        with self._an.seg("dispatch"):
+        # on a cold key the dispatch call blocks on the XLA compile: the
+        # record bills that wall to the key
+        with self._an.dispatch(
+                "rpa", key_, rows=len(rows) + (pf is not None),
+                row_slots=self.B, q_tokens=real, prompt_tokens=c,
+                q_slots=tpb, ctx_tokens=live_tokens + pos, cold=not warm):
             out = dispatch()
-        if not warm:
-            # cold key: the dispatch call just blocked on the XLA compile
-            # — bill it to this bucket's compile economics
-            self._an.note_compile(tpb, w, time.time() - t_disp)
         self._note_ran_ok(key_)
         with self._an.seg("fetch"):
             if tree_live:
@@ -4263,13 +4272,6 @@ class ContinuousScheduler:
                     self._cost_pending_prefill.append(
                         (st_i.req, len(c_i), f_i))
             self._attr_pending_flops += flops
-            if self._tr:
-                self._tr.instant("prefill_dispatch",
-                                 args={"rows": len(items),
-                                       "tokens": batch_tokens,
-                                       "bucket": s_bucket,
-                                       "fresh": bool(fresh),
-                                       "flops_g": round(flops / 1e9, 3)})
             self._key, sub = jax.random.split(self._key)
             args = (
                 self.params, self.cache.k, self.cache.v,
@@ -4278,11 +4280,20 @@ class ContinuousScheduler:
                 jnp.asarray(alloc), jnp.asarray(table[:, :w]), sub,
                 jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
             )
-            key_ = ("prefill", fresh, s_bucket, w, ring)
-            if key_ not in self._ran_ok:
+            # n is in the key: [1, S] and [B, S] are two compiled programs
+            # (the dispatch table's q_slots == dispatches x bucket identity
+            # found the key without it calling a compiling [B, S] warm)
+            key_ = ("prefill", fresh, n, s_bucket, w, ring)
+            cold = key_ not in self._ran_ok
+            if cold:
                 self._attr_prefill_cold = True  # compiling: no MFU sample
                 self._wd_grace_cold()
-            with self._an.seg("dispatch"):
+            with self._an.dispatch(
+                    "prefill" if fresh else "prefill_chunk", key_,
+                    rows=len(items), row_slots=n, q_tokens=batch_tokens,
+                    prompt_tokens=batch_tokens, q_slots=n * s_bucket,
+                    ctx_tokens=sum(p for _, _, _, p, _ in items),
+                    cold=cold):
                 fn = (self._get_prefill_fn(s_bucket, use_ring=ring)
                       if fresh
                       else self._get_prefill_window_fn(s_bucket, w))
@@ -4345,12 +4356,6 @@ class ContinuousScheduler:
         self._h_prefill_batch.observe(batch_tokens)
         self._h_rpa_span.observe(batch_tokens)
         self._attr_pending_flops += flops
-        if self._tr:
-            self._tr.instant("prefill_dispatch",
-                             args={"rows": len(items),
-                                   "tokens": batch_tokens, "bucket": tpb,
-                                   "fresh": False, "rpa": True,
-                                   "flops_g": round(flops / 1e9, 3)})
         self._key, sub = jax.random.split(self._key)
         srows = jnp.arange(self.B, dtype=jnp.int32)
         args = (self.params, self.cache.k, self.cache.v,
@@ -4365,18 +4370,17 @@ class ContinuousScheduler:
         if not warm:
             self._attr_prefill_cold = True  # compiling: no MFU sample
             self._wd_grace_cold()
-        # bucket economics: chunked-prefill spans ride the same ragged
-        # (token bucket, page window) family as the mixed step — real
-        # tokens vs the tpb pad tail is the padding-waste trade PR 16 made
-        self._an.note_bucket(tpb, w, batch_tokens)
-        t_disp = time.time()
-        with self._an.seg("dispatch"):
+        # chunked-prefill spans ride the same ragged (token bucket, page
+        # window) family as the mixed step — real tokens vs the tpb pad
+        # tail is the padding-waste trade PR 16 made.  A cold key's wall
+        # ~= compile time (tracing + lowering block the call; execution is
+        # async)
+        with self._an.dispatch(
+                "rpa", key_, rows=len(items), row_slots=self.B,
+                q_tokens=batch_tokens, prompt_tokens=batch_tokens,
+                q_slots=tpb, ctx_tokens=int(base_np.sum()), cold=not warm):
             tok0, self.cache.k, self.cache.v, ks, vs = \
                 self._get_rpa_fn(tpb, w)(*args)
-        if not warm:
-            # cold-key dispatch wall ~= compile time (tracing + lowering
-            # block the call; execution is async)
-            self._an.note_compile(tpb, w, time.time() - t_disp)
         self._note_ran_ok(key_)
         if self._kv_quant:
             self.kscale, self.vscale = ks, vs
@@ -4450,11 +4454,6 @@ class ContinuousScheduler:
             if self._cost.enabled:
                 self._cost_pending_prefill.append((st_i.req, len(c_i), f_i))
         self._attr_pending_flops += flops
-        if self._tr:
-            self._tr.instant("prefill_dispatch",
-                             args={"rows": len(items), "tokens": s_real,
-                                   "bucket": s_bucket, "packed": True,
-                                   "flops_g": round(flops / 1e9, 3)})
         self._key, sub = jax.random.split(self._key)
         args = (
             self.params, self.cache.k, self.cache.v,
@@ -4465,10 +4464,14 @@ class ContinuousScheduler:
             jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
         )
         key_ = ("packed", s_bucket)
-        if key_ not in self._ran_ok:
+        cold = key_ not in self._ran_ok
+        if cold:
             self._attr_prefill_cold = True  # compiling: no MFU sample
             self._wd_grace_cold()
-        with self._an.seg("dispatch"):
+        with self._an.dispatch(
+                "packed", key_, rows=len(items), row_slots=self.B,
+                q_tokens=s_real, prompt_tokens=s_real, q_slots=s_bucket,
+                ctx_tokens=0, cold=cold):
             tok0, self.cache.k, self.cache.v, \
                 self.kscale, self.vscale = \
                 self._get_packed_prefill_fn(s_bucket)(*args)
@@ -4708,18 +4711,25 @@ class ContinuousScheduler:
             jnp.asarray(table[:, :w]), jnp.asarray(active), sub,
             jnp.asarray(temps), jnp.asarray(top_k), jnp.asarray(top_p),
         )
-        decode_warm = ("decode", bc, w) in self._ran_ok
+        key_ = ("decode", bc, w)
+        decode_warm = key_ in self._ran_ok
         if not decode_warm:
             self._wd_grace_cold()
         t_disp = time.time()
-        with self._an.seg("dispatch"):
+        # q_tokens: the tokens the block emits, known once it is fetched
+        with self._an.dispatch(
+                "decode", key_, rows=attr_live_rows, row_slots=bc,
+                q_tokens=0, prompt_tokens=0,
+                q_slots=bc * self.decode_block,
+                ctx_tokens=attr_live_tokens, cold=not decode_warm) as disp:
             out = self._get_decode_fn(w)(*args)
-        self._note_ran_ok(("decode", bc, w))
+        self._note_ran_ok(key_)
         toks, n_valid, self.cache.k, self.cache.v = out
         with self._an.seg("fetch"):
             toks, n_valid, *tok0s = self._timed_get(  # one transfer
                 (toks, n_valid, *[t for t, _ in pending]))
         toks, n_valid = np.asarray(toks), np.asarray(n_valid)
+        disp.emitted(int(n_valid.sum()))
         t_done = time.time()
         with self._an.seg("finish"):
             # live roofline attribution: the fetch above waited out this
@@ -4865,15 +4875,24 @@ class ContinuousScheduler:
             jnp.asarray(table[:, :w]), jnp.asarray(active), sub,
             jnp.asarray(temps), jnp.asarray(top_k), jnp.asarray(top_p),
         )
-        if ("specfn", w) not in self._ran_ok:
+        key_ = ("specfn", w)
+        cold = key_ not in self._ran_ok
+        if cold:
             self._wd_grace_cold()
         t_disp = time.time()
-        with self._an.seg("dispatch"):
+        # a spec block is decode_steps verify steps of 1 + spec_k positions
+        with self._an.dispatch(
+                "spec", key_, rows=int(np.sum(active)), row_slots=self.B,
+                q_tokens=0, prompt_tokens=0,
+                q_slots=self.B * self.decode_steps * (1 + self.spec_k),
+                ctx_tokens=int(np.sum(kv_lens[active])),
+                cold=cold) as disp:
             out = self._get_spec_decode_fn(w)(*args)
-        self._note_ran_ok(("specfn", w))
+        self._note_ran_ok(key_)
         toks, counts, self._spec_buf, self.cache.k, self.cache.v = out
         with self._an.seg("fetch"):
             toks, counts = self._timed_get((toks, counts))  # one transfer
+        disp.emitted(int(np.sum(counts)))
         t_done = time.time()
         with self._an.seg("finish"):
             # spec blocks contribute step gaps but no byte/FLOP samples

@@ -64,6 +64,7 @@ from lmrs_tpu.obs.trace import (
     get_tracer,
     new_trace_id,
     req_tid,
+    span,
     stitch_traces,
     stitched_chains,
     validate_trace_events,
@@ -87,6 +88,6 @@ __all__ = [
     "PID_ENGINE", "PID_PIPELINE", "PID_STITCH", "TID_SCHED",
     "TRACE_TRACK_PREFIX", "Tracer",
     "disable_tracing", "enable_tracing", "export_current", "get_tracer",
-    "new_trace_id", "req_tid", "stitch_traces", "stitched_chains",
+    "new_trace_id", "req_tid", "span", "stitch_traces", "stitched_chains",
     "validate_trace_events", "validate_trace_file",
 ]

@@ -20,18 +20,25 @@ shapes".  This module names every microsecond between two dispatches:
   ``iter_abort`` DISCARDS the open record — an iteration killed by a
   dispatch fault contributes nothing, so the audit identity survives
   chaos arms by construction rather than by luck.
-* Bucket economics for the PR 16 ragged-span family: per
-  (pow2 query-token bucket, pow2 page-window) key the profiler counts
-  dispatches, real vs padded span tokens (padding-waste ratio), and
-  cumulative compile seconds — the pow2 family's padding-vs-compile
-  trade becomes a number per bucket instead of a guess.
+* ``dispatch(program, key, ...)`` is the ``dispatch`` segment of one
+  device dispatch AND its record: the site says what the dispatch carried
+  (rows and row slots, real and padded query positions, prompt positions,
+  context tokens, cold or warm) and the record folds into a per-program,
+  per-key table that ``snapshot()`` / ``report(before=...)`` window like
+  the segment totals.  On a cold key the segment's wall is the compile.
+  The ragged-span bucket economics of PR 16/18 (``buckets``,
+  ``rpa_pad_waste_ratio``) are a view of the table's ``rpa`` program.
+* Every segment goes through ``obs.trace.span``: ``sched.<segment>`` in a
+  running ``jax.profiler`` trace (the device's clock) and, when the
+  Chrome-JSON ``Tracer`` is armed, the same name and args in its ring.
 
 Always-on by default; ``LMRS_ANATOMY=0`` swaps in ``NULL_ANATOMY``, which
 registers NO metrics and no-ops every call — output, wire format, and the
 pre-existing metrics shape are byte-identical to a build without this
 module.  Overhead when on is a handful of ``time.time()`` calls and dict
-adds per iteration; trace spans are only formatted when a tracer is
-armed (same ≤2% budget discipline as obs/trace.py).
+adds per iteration plus one ``span`` per segment, which outside a profiler
+session and with the tracer off is two object allocations and a flag test
+(same ≤2% budget discipline as obs/trace.py).
 """
 
 from __future__ import annotations
@@ -41,7 +48,7 @@ from collections import deque
 
 from lmrs_tpu.obs.flight import dump_postmortem
 from lmrs_tpu.obs.metrics import MetricsRegistry, log_buckets
-from lmrs_tpu.obs.trace import get_tracer
+from lmrs_tpu.obs.trace import get_tracer, span
 from lmrs_tpu.utils.env import env_bool, env_float, env_int
 
 # the named host segments of one scheduler iteration, in loop order:
@@ -55,6 +62,17 @@ from lmrs_tpu.utils.env import env_bool, env_float, env_int
 SEGMENTS: tuple[str, ...] = ("admit", "plan", "draft", "dispatch",
                              "fetch", "finish", "io")
 _SEG_SET = frozenset(SEGMENTS)
+
+# what a scheduler dispatch site may call itself (``dispatch(program, ...)``);
+# the first five compute prompt positions and feed the prefill_* counters
+PROGRAMS: tuple[str, ...] = ("prefill", "packed", "prefill_chunk", "rpa",
+                             "mixed", "decode", "spec")
+PROMPT_PROGRAMS = frozenset(PROGRAMS[:5])
+# the additive fields of one dispatch record, as the table and the report
+# carry them (the report adds ``cold_ms`` and, per program, ``keys``)
+RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
+                                  "q_tokens", "prompt_tokens", "q_slots",
+                                  "ctx_tokens", "cold")
 
 # iteration step classes (the decode_split/serving_latency split axis)
 CLASSES: tuple[str, ...] = ("plain", "mixed", "spec", "prefill")
@@ -86,14 +104,27 @@ class _Seg:
     enclosing segment, exiting resumes it — re-entrant on the same name
     and exception-safe (an unwind closes every frame on the way out)."""
 
-    __slots__ = ("a", "name")
+    __slots__ = ("a", "name", "_span")
 
     def __init__(self, a: "StepAnatomy", name: str):
         self.a = a
         self.name = name
+        self._span = None
+
+    def _span_args(self) -> dict:
+        if self.name == "fetch":
+            # the device runs dispatches in order, so the fetch that
+            # returns retires every dispatch issued since the last one
+            ids, self.a._unretired = self.a._unretired, []
+            # "+"-joined: a comma would end the value in the profiler's
+            # "name#key=value,key=value#" encoding of an annotation
+            return {"retires": "+".join(map(str, ids))}
+        return {}
 
     def __enter__(self):
         a = self.a
+        self._span = span("sched." + self.name, **self._span_args())
+        self._span.__enter__()
         if not a._open:
             return self
         t = a._clock()
@@ -107,16 +138,72 @@ class _Seg:
     def __exit__(self, exc_type, exc, tb):
         a = self.a
         st = a._stack
-        if not a._open or not st:
-            return False
-        t = a._clock()
-        e = st.pop()
-        a._cur[e[0]] += t - e[2]
-        if st:
-            st[-1][2] = t  # resume the enclosing segment
-        if a._tr is not None:
-            a._tr.complete("anatomy." + e[0], e[1], t)
+        if a._open and st:
+            t = a._clock()
+            e = st.pop()
+            a._cur[e[0]] += t - e[2]
+            if st:
+                st[-1][2] = t  # resume the enclosing segment
+        self._span.__exit__(exc_type, exc, tb)
         return False
+
+
+class _Dispatch(_Seg):
+    """One ``with anatomy.dispatch(program, key, ...) as d:`` activation:
+    the ``dispatch`` segment, with the record of what it carried.  The
+    counts fold into the table on entry, beside the scheduler's own
+    counters (``lmrs_prefill_tokens_total`` counts before the call too), so
+    a dispatch that raises keeps the audit identities; the wall of a cold
+    key folds on exit.  ``d.emitted(n)`` adds query tokens known only once
+    the result is back (a decode block's emitted tokens)."""
+
+    __slots__ = ("rec", "id", "_cold_t0")
+
+    def __init__(self, a: "StepAnatomy", rec: dict):
+        super().__init__(a, "dispatch")
+        self.rec = rec
+        self.id = 0
+        self._cold_t0 = 0.0
+
+    def _span_args(self) -> dict:
+        r = self.rec
+        return {"program": r["program"], "key": _key_str(r["key"]),
+                "id": self.id, "rows": r["rows"], "q_tokens": r["q_tokens"],
+                "q_slots": r["q_slots"], "cold": r["cold"]}
+
+    def __enter__(self):
+        a, r = self.a, self.rec
+        a._dispatch_id += 1
+        self.id = a._dispatch_id
+        a._unretired.append(self.id)
+        a._fold(r)
+        if r["cold"]:
+            self._cold_t0 = a._clock()
+        tr = get_tracer()
+        if tr is not None and r["prompt_tokens"] > 0:
+            # the ring's per-dispatch instant, under the name it has had
+            # since PR 7, now with the record's one set of fields
+            tr.instant("prefill_dispatch", args={
+                "id": self.id, "program": r["program"],
+                "key": _key_str(r["key"]),
+                **{f: int(r[f]) for f in RECORD_FIELDS[1:]}})
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        if self.rec["cold"]:
+            self.a._fold_cold(self.rec,
+                              max(self.a._clock() - self._cold_t0, 0.0))
+        return super().__exit__(exc_type, exc, tb)
+
+    def emitted(self, n: int) -> None:
+        self.a._table[(self.rec["program"], self.rec["key"])][
+            "q_tokens"] += int(n)
+
+
+def _key_str(key: tuple) -> str:
+    """``("rpa", 32768, 16)`` -> ``"rpa:32768:16"``: a dispatch key as the
+    report, the spans and the ring spell it."""
+    return ":".join(str(k) for k in key)
 
 
 class _NullSeg:
@@ -128,14 +215,17 @@ class _NullSeg:
     def __exit__(self, exc_type, exc, tb):
         return False
 
+    def emitted(self, n: int) -> None:
+        pass
+
 
 _NULL_SEG = _NullSeg()
 
 
 class StepAnatomy:
-    """Conservation-audited per-iteration host-segment profiler + ragged
-    bucket economics (module docstring).  One instance per scheduler run
-    context; NOT thread-safe by design — only the scheduler loop thread
+    """Conservation-audited per-iteration host-segment profiler + the
+    per-program dispatch table (module docstring).  One instance per
+    scheduler run context; NOT thread-safe by design — only the scheduler loop thread
     touches the iteration lifecycle, matching every other per-run
     accumulator in the scheduler."""
 
@@ -145,7 +235,6 @@ class StepAnatomy:
                  clock=time.time):
         self._clock = clock
         self._metrics_cb = metrics_cb
-        self._tr = None
         # iteration lifecycle state
         self._open = False
         self._stack: list[list] = []
@@ -163,8 +252,16 @@ class StepAnatomy:
         cap = reservoir_size()
         self._res: dict[str, deque] = {c: deque(maxlen=cap) for c in CLASSES}
         self._cls_iters = {c: 0 for c in CLASSES}
-        # bucket economics: (tpb, w) -> {dispatches, real, padded, compile_s}
-        self._buckets: dict[tuple[int, int], dict] = {}
+        # the dispatch table: (program, key) -> RECORD_FIELDS + cold_s +
+        # slots (the q_slots of the key's first dispatch: a key is one
+        # compiled shape, so every dispatch on it holds as many)
+        self._table: dict[tuple[str, tuple], dict] = {}
+        self._dispatch_id = 0
+        self._unretired: list[int] = []  # ids no fetch has retired yet
+        # flat sums of the same records, for ``scheduler.metrics``
+        self._flat = {"prefill_dispatches": 0, "prefill_query_tokens": 0,
+                      "prefill_token_slots": 0, "cold_dispatches": 0,
+                      "cold_seconds": 0.0}
 
         c, g, h = (registry.counter, registry.gauge, registry.histogram)
         self._c_iters = c("lmrs_anatomy_iterations_total",
@@ -213,7 +310,6 @@ class StepAnatomy:
     def iter_begin(self) -> None:
         if self._open:  # defensive: a lost iter_end must not leak forever
             self.iter_abort()
-        self._tr = get_tracer()
         self._stack = []
         self._cur = {s: 0.0 for s in SEGMENTS}
         self._t_iter = self._clock()
@@ -295,29 +391,60 @@ class StepAnatomy:
         self._open = False
         self._stack = []
 
-    # ------------------------------------------------------ bucket economics
+    # --------------------------------------------------------- dispatch table
 
-    def note_bucket(self, tpb: int, w: int, real_tokens: int) -> None:
-        """One ragged-span dispatch on bucket (``tpb`` pow2 query tokens,
-        ``w`` pow2 page window) that carried ``real_tokens`` real span
-        tokens — the rest of the bucket is padding."""
-        rec = self._buckets.setdefault((int(tpb), int(w)), {
-            "dispatches": 0, "real": 0, "padded": 0, "compile_s": 0.0})
-        pad = max(int(tpb) - int(real_tokens), 0)
-        rec["dispatches"] += 1
-        rec["real"] += int(real_tokens)
-        rec["padded"] += pad
-        self._c_b_disp.inc()
-        self._c_b_real.inc(max(int(real_tokens), 0))
-        self._c_b_pad.inc(pad)
+    def dispatch(self, program: str, key: tuple, *, rows: int,
+                 row_slots: int, q_tokens: int, prompt_tokens: int,
+                 q_slots: int, ctx_tokens: int, cold: bool) -> _Dispatch:
+        """The ``dispatch`` segment of one device dispatch, with what it
+        carried.  ``program`` is one of ``PROGRAMS`` and ``key`` the site's
+        own compile key; ``rows`` carry work out of ``row_slots`` operand
+        rows; ``q_tokens`` are the real query positions (``prompt_tokens``
+        of them prompt positions) out of the ``q_slots`` the operand
+        holds; ``ctx_tokens`` are the KV tokens already in pages that the
+        dispatch attends; ``cold`` says the key has never run, so the
+        segment's wall is its compile."""
+        if program not in PROGRAMS:
+            raise ValueError(f"unknown dispatch program {program!r} "
+                             f"(want one of {PROGRAMS})")
+        return _Dispatch(self, {
+            "program": program, "key": tuple(key), "dispatches": 1,
+            "rows": int(rows), "row_slots": int(row_slots),
+            "q_tokens": int(q_tokens), "prompt_tokens": int(prompt_tokens),
+            "q_slots": int(q_slots), "ctx_tokens": int(ctx_tokens),
+            "cold": bool(cold)})
 
-    def note_compile(self, tpb: int, w: int, seconds: float) -> None:
-        """Cold-key dispatch wall for a bucket — the compile cost the pow2
-        family pays to keep the bucket count finite."""
-        rec = self._buckets.setdefault((int(tpb), int(w)), {
-            "dispatches": 0, "real": 0, "padded": 0, "compile_s": 0.0})
-        rec["compile_s"] += max(float(seconds), 0.0)
-        self._c_b_compile.inc(max(float(seconds), 0.0))
+    def _fold(self, r: dict) -> None:
+        rec = self._table.get((r["program"], r["key"]))
+        if rec is None:
+            rec = self._table[(r["program"], r["key"])] = {
+                **dict.fromkeys(RECORD_FIELDS, 0), "cold_s": 0.0,
+                "slots": r["q_slots"]}
+        for f in RECORD_FIELDS:
+            rec[f] += r[f]
+        flat = self._flat
+        flat["cold_dispatches"] += r["cold"]
+        if r["program"] in PROMPT_PROGRAMS:
+            flat["prefill_dispatches"] += 1
+            flat["prefill_query_tokens"] += r["q_tokens"]
+            flat["prefill_token_slots"] += r["q_slots"]
+        if r["program"] == "rpa":
+            self._c_b_disp.inc()
+            self._c_b_real.inc(r["q_tokens"])
+            self._c_b_pad.inc(max(r["q_slots"] - r["q_tokens"], 0))
+
+    def _fold_cold(self, r: dict, seconds: float) -> None:
+        self._table[(r["program"], r["key"])]["cold_s"] += seconds
+        self._flat["cold_seconds"] += seconds
+        if r["program"] == "rpa":
+            self._c_b_compile.inc(seconds)
+
+    def counters(self) -> dict:
+        """The flat sums ``ContinuousScheduler.metrics`` carries: prefill
+        dispatches, their real query positions and the positions their
+        operands held (the prompt programs), cold dispatches and their
+        wall (all programs)."""
+        return dict(self._flat)
 
     # --------------------------------------------------------------- reading
 
@@ -328,12 +455,16 @@ class StepAnatomy:
         return {"iters": self._iters, "aborted": self._aborted,
                 "wall": self._wall, "residual": self._residual,
                 "host_us": self._host_us,
-                "segs": dict(self._segs)}
+                "segs": dict(self._segs),
+                "table": {k: dict(rec) for k, rec in self._table.items()}}
 
-    def audit(self) -> list[str]:
+    def audit(self, prefill_tokens: int | None = None) -> list[str]:
         """Conservation check over the CUMULATIVE totals (safe to call
         mid-iteration: totals only advance at ``iter_end``).  Violations
-        are returned as strings for ``scheduler.audit()`` to aggregate."""
+        are returned as strings for ``scheduler.audit()`` to aggregate.
+        ``prefill_tokens`` is the scheduler's own count of prompt positions
+        dispatched (``lmrs_prefill_tokens_total``): the table's
+        ``prompt_tokens`` have to add up to it."""
         violations: list[str] = []
         seg_sum = sum(self._segs.values())
         eps = 1e-6 * max(1, self._iters) + 1e-9
@@ -349,20 +480,33 @@ class StepAnatomy:
         for s, v in self._segs.items():
             if v < -eps:
                 violations.append(f"anatomy segment {s} went negative: {v}")
-        for key, rec in self._buckets.items():
-            if rec["real"] + rec["padded"] != rec["dispatches"] * key[0]:
+        prompt = q_prompt_programs = 0
+        for (program, key), rec in self._table.items():
+            prompt += rec["prompt_tokens"]
+            if program in PROMPT_PROGRAMS:
+                q_prompt_programs += rec["q_tokens"]
+            if rec["q_slots"] != rec["dispatches"] * rec["slots"]:
                 violations.append(
-                    f"anatomy bucket {key[0]}x{key[1]}: real+padded "
-                    f"({rec['real']}+{rec['padded']}) != dispatches*bucket "
-                    f"({rec['dispatches']}*{key[0]})")
+                    f"anatomy dispatch table {_key_str(key)}: q_slots "
+                    f"{rec['q_slots']} != dispatches*bucket "
+                    f"({rec['dispatches']}*{rec['slots']})")
+        if prefill_tokens is not None and prompt != prefill_tokens:
+            violations.append(
+                f"anatomy dispatch table: prompt_tokens over all programs "
+                f"{prompt} != prefill_tokens {prefill_tokens}")
+        if q_prompt_programs != self._flat["prefill_query_tokens"]:
+            violations.append(
+                f"anatomy dispatch table: q_tokens over the prompt programs "
+                f"{q_prompt_programs} != prefill_query_tokens "
+                f"{self._flat['prefill_query_tokens']}")
         return violations
 
     def report(self, before: dict | None = None, *,
                rtt: tuple | None = None) -> dict:
         """The ``anatomy`` block (``metrics_report()`` / ``/v1/anatomy`` /
-        bench detail).  Top-level totals window off ``before`` (a
-        ``snapshot()``); per-class percentiles and bucket economics stay
-        cumulative, like the rpa block's compile shapes.  ``rtt`` is
+        bench detail).  Top-level totals, the ``programs`` table and its
+        ``buckets`` view window off ``before`` (a ``snapshot()``); the
+        per-class percentiles stay cumulative (a reservoir).  ``rtt`` is
         ``(rtt_s | None, age_s | None)`` from ``DispatchAttribution.
         rtt_sample()`` — a STALE sample is reported but never subtracted
         from the fetch split (the satellite-3 guard)."""
@@ -392,19 +536,8 @@ class StepAnatomy:
             classes[cls] = {"iterations": self._cls_iters[cls],
                             "p50_us": p50, "p95_us": p95}
 
-        buckets: dict[str, dict] = {}
-        tot_real = tot_pad = 0
-        for (tpb, w), rec in sorted(self._buckets.items()):
-            span = rec["real"] + rec["padded"]
-            buckets[f"{tpb}x{w}"] = {
-                "dispatches": rec["dispatches"],
-                "real_tokens": rec["real"],
-                "padded_tokens": rec["padded"],
-                "pad_waste": round(rec["padded"] / span, 4) if span else 0.0,
-                "compile_ms": round(rec["compile_s"] * 1e3, 1),
-            }
-            tot_real += rec["real"]
-            tot_pad += rec["padded"]
+        programs = _programs_report(self._table, b.get("table", {}))
+        buckets, pad_ratio = _rpa_buckets(programs)
 
         rtt_s, rtt_age = (rtt if rtt is not None else (None, None))
         out = {
@@ -418,10 +551,9 @@ class StepAnatomy:
             "host_overhead_us_step": (round(host_us / iters, 1)
                                       if iters > 0 else None),
             "classes": classes,
+            "programs": programs,
             "buckets": buckets,
-            "rpa_pad_waste_ratio": (
-                round(tot_pad / (tot_real + tot_pad), 4)
-                if (tot_real + tot_pad) else None),
+            "rpa_pad_waste_ratio": pad_ratio,
         }
         if rtt_s is not None:
             stale = rtt_age is None or rtt_age > 2.0 * rtt_resample_s()
@@ -435,6 +567,56 @@ class StepAnatomy:
                 out["device_wait_us_step"] = round(
                     max(fetch_s / iters - rtt_s, 0.0) * 1e6, 1)
         return out
+
+
+def _programs_report(table: dict, before: dict) -> dict:
+    """The ``programs`` block: the table's records since ``before`` (a
+    snapshot's copy of it), per program and, under ``keys``, per key; a key
+    with no dispatch in the window is left out."""
+    programs: dict[str, dict] = {}
+    for (program, key), rec in sorted(
+            table.items(), key=lambda kv: (kv[0][0], _key_str(kv[0][1]))):
+        b = before.get((program, key), {})
+        d = {f: rec[f] - b.get(f, 0) for f in RECORD_FIELDS}
+        if not d["dispatches"]:
+            continue
+        d["cold_ms"] = (rec["cold_s"] - b.get("cold_s", 0.0)) * 1e3
+        tot = programs.setdefault(program, {
+            **dict.fromkeys(RECORD_FIELDS, 0), "cold_ms": 0.0, "keys": {}})
+        for f, v in d.items():
+            tot[f] += v
+        tot["keys"][_key_str(key)] = d
+    for tot in programs.values():
+        for rec in (tot, *tot["keys"].values()):
+            rec["cold_ms"] = round(rec["cold_ms"], 1)
+    return programs
+
+
+def _rpa_buckets(programs: dict) -> tuple[dict, float | None]:
+    """The ragged-span bucket economics (``buckets``, ``rpa_pad_waste_
+    ratio``) as a view of ``programs["rpa"]``: keys ``rpa:<tpb>:<w>`` and
+    ``rpa_spec:<tpb>:<w>`` share the bucket ``"<tpb>x<w>"``."""
+    buckets: dict[str, dict] = {}
+    for key, rec in (programs.get("rpa") or {"keys": {}})["keys"].items():
+        _, tpb, w = key.split(":")
+        m = buckets.setdefault(f"{tpb}x{w}", {
+            "dispatches": 0, "real_tokens": 0, "padded_tokens": 0,
+            "pad_waste": 0.0, "compile_ms": 0.0})
+        m["dispatches"] += rec["dispatches"]
+        m["real_tokens"] += rec["q_tokens"]
+        m["padded_tokens"] += rec["q_slots"] - rec["q_tokens"]
+        m["compile_ms"] = round(m["compile_ms"] + rec["cold_ms"], 1)
+    buckets = dict(sorted(buckets.items(),
+                          key=lambda kv: tuple(map(int, kv[0].split("x")))))
+    tot_real = tot_pad = 0
+    for m in buckets.values():
+        span_tokens = m["real_tokens"] + m["padded_tokens"]
+        m["pad_waste"] = (round(m["padded_tokens"] / span_tokens, 4)
+                          if span_tokens else 0.0)
+        tot_real += m["real_tokens"]
+        tot_pad += m["padded_tokens"]
+    return buckets, (round(tot_pad / (tot_real + tot_pad), 4)
+                     if (tot_real + tot_pad) else None)
 
 
 def merge_anatomy(docs: list[dict]) -> dict:
@@ -473,6 +655,21 @@ def merge_anatomy(docs: list[dict]) -> dict:
                                     for c, n in per) / n_cls, 1)
                        for k in keys},
         }
+    zero = {**dict.fromkeys(RECORD_FIELDS, 0), "cold_ms": 0.0}
+
+    def add(tot: dict, rec: dict) -> None:
+        for f in RECORD_FIELDS:
+            tot[f] += int(rec.get(f) or 0)
+        tot["cold_ms"] = round(tot["cold_ms"]
+                               + float(rec.get("cold_ms") or 0.0), 1)
+
+    programs: dict[str, dict] = {}
+    for d in live:
+        for program, rec in (d.get("programs") or {}).items():
+            tot = programs.setdefault(program, {**zero, "keys": {}})
+            add(tot, rec)
+            for key, krec in (rec.get("keys") or {}).items():
+                add(tot["keys"].setdefault(key, dict(zero)), krec)
     buckets: dict[str, dict] = {}
     tot_real = tot_pad = 0
     for d in live:
@@ -505,6 +702,7 @@ def merge_anatomy(docs: list[dict]) -> dict:
             round(sum(v * n for v, n in hosts_us) / w_iters, 1)
             if w_iters else None),
         "classes": classes,
+        "programs": programs,
         "buckets": dict(sorted(buckets.items())),
         "rpa_pad_waste_ratio": (
             round(tot_pad / (tot_real + tot_pad), 4)
@@ -530,7 +728,8 @@ def _pct(sorted_vals: list[float], q: float) -> float:
 
 class NullAnatomy:
     """The ``LMRS_ANATOMY=0`` object: registers no metrics, every call is
-    a no-op, ``seg`` hands back one shared null context — the scheduler
+    a no-op, ``seg`` and ``dispatch`` hand back one shared null context
+    and ``counters`` is empty — the scheduler
     keeps one unconditional code path while the kill switch restores the
     exact pre-anatomy metrics shape and wire format."""
 
@@ -551,16 +750,16 @@ class NullAnatomy:
     def iter_discard(self) -> None:
         pass
 
-    def note_bucket(self, tpb: int, w: int, real_tokens: int) -> None:
-        pass
+    def dispatch(self, program: str, key: tuple, **record):
+        return _NULL_SEG
 
-    def note_compile(self, tpb: int, w: int, seconds: float) -> None:
-        pass
+    def counters(self) -> dict:
+        return {}
 
     def snapshot(self) -> dict:
         return {}
 
-    def audit(self) -> list[str]:
+    def audit(self, prefill_tokens: int | None = None) -> list[str]:
         return []
 
     def report(self, before: dict | None = None, *,

@@ -17,8 +17,10 @@ Deadline-lifecycle terminals add ``shed`` (rejected before prefill) and
 ``decode`` span and emits ``finish`` with ``reason="deadline"``
 (docs/ROBUSTNESS.md).
 
-plus scheduler-track ``decode_block``/``prefill_dispatch`` dispatch spans
-and pipeline-track ``map_stage``/``reduce_level``/stage spans.  Export is
+plus scheduler-track ``decode_block``/``prefill_dispatch`` dispatch spans,
+the scheduler's ``sched.*`` segment spans (``span()`` below: the same names
+land in a ``jax.profiler`` trace, on the device's clock) and pipeline-track
+``map_stage``/``reduce_level``/stage spans.  Export is
 Chrome trace-event JSON (``{"traceEvents": [...]}``) loadable directly in
 Perfetto / chrome://tracing; ``validate_trace_file`` checks the fields
 Perfetto requires and is shared by the tests and the CI trace-export gate.
@@ -242,6 +244,57 @@ def export_current(path: str | Path) -> tuple[int | None, str | None]:
         return tr.export(path), None
     except Exception as e:  # noqa: BLE001 - includes serialization errors;
         return None, str(e)  # a raise here would mask the run's real error
+
+
+# ------------------------------------------------------- one span, two sinks
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, looked up on first use
+
+
+def _annotation(name: str, args: dict):
+    global _annotation_cls
+    if _annotation_cls is None:
+        import jax.profiler  # obs/ has no top-level JAX import
+
+        _annotation_cls = jax.profiler.TraceAnnotation
+    return _annotation_cls(name, **args)
+
+
+class span:
+    """``with span("sched.fetch", retires="4+5"):`` one host span in both
+    sinks.  It enters a ``jax.profiler.TraceAnnotation``, which puts the
+    span into a running profiler session's xplane on the clock of the
+    device's own events (outside a session that is a flag test), and, if
+    the ``Tracer`` is armed, records the same name and args into the ring
+    on exit.  ``annotate=False`` keeps a caller off JAX (``StageTimer``
+    without ``profile=``); ``pid``/``tid`` place the ring event."""
+
+    __slots__ = ("name", "args", "pid", "tid", "_ann", "_t0")
+
+    def __init__(self, name: str, *, annotate: bool = True,
+                 pid: int = PID_ENGINE, tid: int = TID_SCHED, **args):
+        self.name = name
+        self.args = args
+        self.pid = pid
+        self.tid = tid
+        self._ann = _annotation(name, args) if annotate else None
+        self._t0 = 0.0
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        if _tracer is not None:
+            self._t0 = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        tr = _tracer
+        if tr is not None and self._t0:
+            tr.complete(self.name, self._t0, time.time(), pid=self.pid,
+                        tid=self.tid, args=self.args or None)
+        return False
 
 
 # ----------------------------------------------------------------- validation

@@ -2,9 +2,8 @@
 
 The reference reports manual ``time.time()`` deltas per stage (main.py:110,
 239-245; llm_executor.py:129,150-154; result_aggregator.py:72,102-103); this
-keeps that user-visible stage report and adds structured spans that can also
-emit ``jax.profiler.TraceAnnotation`` ranges when profiling is enabled
-(SURVEY.md §5.1).
+keeps that user-visible stage report and adds structured spans that also
+land in a ``jax.profiler`` trace when profiling is enabled (SURVEY.md §5.1).
 """
 
 from __future__ import annotations
@@ -36,23 +35,15 @@ class StageTimer:
 
     @contextlib.contextmanager
     def stage(self, name: str):
-        ctx = contextlib.nullcontext()
-        if self.profile:
-            import jax.profiler
+        # one span in both sinks (obs/trace.span): the jax.profiler trace
+        # when ``profile`` is on, and the lifecycle tracer's pipeline track
+        # (the engine-level spans nest under these in Perfetto)
+        from lmrs_tpu.obs import PID_PIPELINE, span
 
-            ctx = jax.profiler.TraceAnnotation(name)
         start = time.time()
-        with ctx:
+        with span(name, annotate=self.profile, pid=PID_PIPELINE):
             yield
-        end = time.time()
-        self.spans[name] = self.spans.get(name, 0.0) + (end - start)
-        # mirror the stage into the lifecycle tracer's pipeline track (the
-        # engine-level spans nest under these in Perfetto)
-        from lmrs_tpu.obs import PID_PIPELINE, get_tracer
-
-        tr = get_tracer()
-        if tr:
-            tr.complete(name, start, end, pid=PID_PIPELINE)
+        self.spans[name] = self.spans.get(name, 0.0) + (time.time() - start)
 
     @property
     def total(self) -> float:

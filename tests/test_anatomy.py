@@ -143,14 +143,24 @@ def test_audit_detects_broken_conservation(an):
     assert an.audit() == []
 
 
+def _rpa(an, tpb, w, real, *, cold=False, prompt=None, spec=False):
+    """One span-program dispatch record on bucket (tpb, w)."""
+    return an.dispatch("rpa", ("rpa_spec" if spec else "rpa", tpb, w),
+                       rows=1, row_slots=2, q_tokens=real,
+                       prompt_tokens=real if prompt is None else prompt,
+                       q_slots=tpb, ctx_tokens=0, cold=cold)
+
+
 def test_bucket_economics_hand_computed(an):
-    """Bucket counters vs a hand-computed span list: three dispatches on
-    bucket (32, 4) carrying 20/32/7 real tokens -> 59 real, 37 padded,
-    real + padded == dispatches * 32, pad_waste 37/96."""
-    for real in (20, 32, 7):
-        an.note_bucket(32, 4, real)
-    an.note_compile(32, 4, 0.25)
-    an.note_bucket(64, 8, 50)
+    """The buckets view of programs["rpa"] vs a hand-computed span list:
+    three dispatches on bucket (32, 4) carrying 20/32/7 real tokens -> 59
+    real, 37 padded, real + padded == dispatches * 32, pad_waste 37/96;
+    the first was cold and its 0.25 s wall is the bucket's compile."""
+    for i, real in enumerate((20, 32, 7)):
+        with _rpa(an, 32, 4, real, cold=i == 0):
+            an.clock.tick(0.25)
+    with _rpa(an, 64, 8, 50, spec=True):  # rpa_spec shares the bucket view
+        pass
     assert an.audit() == []
     rep = an.report()
     b = rep["buckets"]["32x4"]
@@ -162,11 +172,115 @@ def test_bucket_economics_hand_computed(an):
     assert rep["buckets"]["64x8"]["padded_tokens"] == 14
     # overall ratio spans both buckets: (37+14) / (96+64)
     assert rep["rpa_pad_waste_ratio"] == pytest.approx(51 / 160, abs=1e-4)
-    # negative case: a corrupted count is a conservation violation
-    an._buckets[(32, 4)]["real"] += 1
-    assert any("bucket 32x4" in v for v in an.audit())
-    an._buckets[(32, 4)]["real"] -= 1
+    # the registry series keep their names and are fed from the table
+    assert an._c_b_disp.value == 4
+    assert an._c_b_real.value == 109 and an._c_b_pad.value == 51
+    assert an._c_b_compile.value == pytest.approx(0.25)
+    # negative case: a corrupted count breaks q_slots == dispatches*bucket
+    an._table[("rpa", ("rpa", 32, 4))]["q_slots"] += 1
+    assert any("rpa:32:4" in v for v in an.audit())
+    an._table[("rpa", ("rpa", 32, 4))]["q_slots"] -= 1
     assert an.audit() == []
+
+
+def test_dispatch_record_table_and_flat_counters(an):
+    """Every field of a record lands in programs[program] and under its
+    key; the prompt programs feed the prefill_* sums, decode stays in the
+    table only, and emitted() adds the tokens a block turned out."""
+    with an.dispatch("prefill", ("prefill", True, 4, 64, 4, False), rows=3,
+                     row_slots=4, q_tokens=150, prompt_tokens=150,
+                     q_slots=256, ctx_tokens=0, cold=False):
+        pass
+    with an.dispatch("decode", ("decode", 4, 4), rows=3, row_slots=4,
+                     q_tokens=0, prompt_tokens=0, q_slots=16,
+                     ctx_tokens=150, cold=False) as d:
+        pass
+    d.emitted(11)
+    assert d.id == 2
+    rep = an.report()["programs"]
+    assert set(rep) == {"prefill", "decode"}
+    pf = rep["prefill"]
+    assert (pf["dispatches"], pf["rows"], pf["row_slots"]) == (1, 3, 4)
+    assert (pf["q_tokens"], pf["prompt_tokens"], pf["q_slots"]) == (
+        150, 150, 256)
+    assert pf["keys"]["prefill:True:4:64:4:False"]["q_slots"] == 256
+    assert rep["decode"]["q_tokens"] == 11
+    assert rep["decode"]["ctx_tokens"] == 150
+    assert an.counters() == {
+        "prefill_dispatches": 1, "prefill_query_tokens": 150,
+        "prefill_token_slots": 256, "cold_dispatches": 0,
+        "cold_seconds": 0.0}
+    assert an.audit(prefill_tokens=150) == []
+    with pytest.raises(ValueError):
+        an.dispatch("warp", ("warp",), rows=0, row_slots=0, q_tokens=0,
+                    prompt_tokens=0, q_slots=0, ctx_tokens=0, cold=False)
+
+
+def test_programs_and_buckets_window_off_a_snapshot(an):
+    """report(before=snapshot) holds only the dispatches after it — the
+    buckets view included (cumulative before PR 25); without ``before``
+    the document is the whole life, as /v1/anatomy serves it."""
+    with _rpa(an, 32, 4, 20, cold=True):
+        an.clock.tick(0.5)
+    with _rpa(an, 64, 8, 40):
+        pass
+    snap = an.snapshot()
+    with _rpa(an, 32, 4, 30):
+        pass
+    win = an.report(snap)
+    assert list(win["buckets"]) == ["32x4"]
+    assert win["buckets"]["32x4"] == {
+        "dispatches": 1, "real_tokens": 30, "padded_tokens": 2,
+        "pad_waste": pytest.approx(2 / 32, abs=1e-4), "compile_ms": 0.0}
+    rpa = win["programs"]["rpa"]
+    assert rpa["dispatches"] == 1 and rpa["cold"] == 0
+    assert list(rpa["keys"]) == ["rpa:32:4"]
+    assert win["rpa_pad_waste_ratio"] == pytest.approx(2 / 32, abs=1e-4)
+    whole = an.report()
+    assert whole["programs"]["rpa"]["dispatches"] == 3
+    assert whole["programs"]["rpa"]["cold_ms"] == pytest.approx(500.0)
+    assert set(whole["buckets"]) == {"32x4", "64x8"}
+    # an empty window has no programs at all
+    assert an.report(an.snapshot())["programs"] == {}
+
+
+def test_cold_key_counted_once_with_its_wall(an):
+    """A cold dispatch is one cold_dispatch and its segment wall is
+    cold_seconds (the compile); the warm dispatch that follows on the same
+    key adds nothing to either."""
+    c = an.clock
+    an.iter_begin()
+    with _rpa(an, 32, 4, 20, cold=True):
+        c.tick(1.5)
+    with _rpa(an, 32, 4, 20):
+        c.tick(0.01)
+    an.iter_end("prefill")
+    got = an.counters()
+    assert got["cold_dispatches"] == 1
+    assert got["cold_seconds"] == pytest.approx(1.5)
+    key = an.report()["programs"]["rpa"]["keys"]["rpa:32:4"]
+    assert key["cold"] == 1 and key["cold_ms"] == pytest.approx(1500.0)
+    assert key["dispatches"] == 2
+    # the record's segment is the dispatch segment: both walls are in it
+    assert an.report()["segments_ms"]["dispatch"] == pytest.approx(1510.0)
+    assert an.audit() == []
+
+
+@pytest.mark.parametrize("corrupt,needle", [
+    (lambda a: a._table[("rpa", ("rpa", 32, 4))].__setitem__(
+        "prompt_tokens", 19), "prompt_tokens over all programs"),
+    (lambda a: a._flat.__setitem__("prefill_query_tokens", 21),
+     "q_tokens over the prompt programs"),
+    (lambda a: a._table[("rpa", ("rpa", 32, 4))].__setitem__(
+        "dispatches", 2), "dispatches*bucket"),
+])
+def test_audit_detects_each_broken_table_identity(an, corrupt, needle):
+    """The three dispatch-table identities are each PROVEN able to fail."""
+    with _rpa(an, 32, 4, 20):
+        pass
+    assert an.audit(prefill_tokens=20) == []
+    corrupt(an)
+    assert any(needle in v for v in an.audit(prefill_tokens=20))
 
 
 def test_report_stale_rtt_guard(an):
@@ -322,6 +436,168 @@ def test_jax_fault_armed_chaos_arm_conserves(mixed_engine):
     assert rep["iterations"] > 0
 
 
+def _kernel_model() -> ModelConfig:
+    # head_dim 128: the Pallas kernels arm under LMRS_FORCE_KERNELS, so a
+    # prefix hit continues through the span program as it does on the chip
+    return ModelConfig(vocab_size=512, dim=512, n_layers=2, n_heads=4,
+                       n_kv_heads=2, hidden_dim=256, max_seq_len=512,
+                       dtype="float32")
+
+
+_LONG = "the quarterly planning review covered budgets and hiring " * 3
+
+# path -> (env, engine config, model, batches of prompts, program it
+# has to show).  Two batches where the second has to meet a warm cache.
+_PATHS = {
+    "fresh_prefill": ({}, dict(), tiny_model, [["one fresh prompt"]],
+                      "prefill"),
+    "packed": ({}, dict(), tiny_model,
+               [["packed prompt one", "second packed prompt here"]],
+               "packed"),
+    "chunked": ({}, dict(prefill_chunk=64, page_size=16, num_pages=40),
+                tiny_model, [[_LONG]], "prefill_chunk"),
+    "prefix_hit_spans": (
+        {"LMRS_FORCE_KERNELS": "interpret"},
+        dict(prefix_cache=True, num_pages=64), _kernel_model,
+        [[_LONG + "first question"], [_LONG + "second question"]], "rpa"),
+    "span_mixed": ({}, dict(mixed_batch=True, prefill_chunk=64,
+                            decode_block=3, num_pages=40), tiny_model,
+                   [["short probe", _LONG, "third " * 12]], "rpa"),
+    "legacy_mixed": ({"LMRS_RPA": "0"},
+                     dict(mixed_batch=True, prefill_chunk=64,
+                          decode_block=3, num_pages=40), tiny_model,
+                     [["short probe", _LONG, "third " * 12]], "mixed"),
+    "decode": ({}, dict(), tiny_model, [["decode probe"]], "decode"),
+    "spec": ({"LMRS_SPEC_TREE": "0"}, dict(speculate_k=4), tiny_model,
+             [["spec probe alpha", "spec probe bravo"]], "spec"),
+}
+
+
+@pytest.mark.parametrize("path", sorted(_PATHS))
+def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
+    """One tiny engine run per dispatch site: the site's program is in the
+    windowed report with sane fields, the flat counters are the table's
+    sums, the ring's prefill_dispatch carries the record, and the audit's
+    three table identities hold."""
+    from lmrs_tpu.obs import disable_tracing, enable_tracing
+    from lmrs_tpu.obs.anatomy import PROMPT_PROGRAMS, RECORD_FIELDS
+
+    env, cfg_kw, model, batches, want = _PATHS[path]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    disable_tracing()
+    tracer = enable_tracing()
+    eng = JaxEngine(_cfg(**cfg_kw), model())
+    try:
+        sched = eng._scheduler
+        an0, m0 = sched.anatomy_snapshot(), dict(sched.metrics)
+        rid = 0
+        for prompts in batches:
+            out = eng.generate_batch([
+                GenerationRequest(prompt=p, request_id=rid + i,
+                                  temperature=0.0, max_new_tokens=6)
+                for i, p in enumerate(prompts)])
+            rid += len(prompts)
+            assert all(r.error is None for r in out)
+        assert sched.audit() == []
+        programs = sched.anatomy_report(an0)["programs"]
+        assert want in programs, sorted(programs)
+        assert "decode" in programs or "spec" in programs or (
+            "rpa" in programs)
+        for name, rec in programs.items():
+            assert 0 < rec["rows"] <= rec["row_slots"], (name, rec)
+            assert 0 <= rec["q_tokens"] <= rec["q_slots"], (name, rec)
+            assert rec["prompt_tokens"] <= rec["q_tokens"] or (
+                name not in PROMPT_PROGRAMS)
+            assert rec["cold"] <= rec["dispatches"]
+            for f in RECORD_FIELDS:
+                assert rec[f] == sum(k[f] for k in rec["keys"].values())
+        if path == "prefix_hit_spans":
+            # the continuation attends the cached pages it did not compute
+            assert programs["rpa"]["ctx_tokens"] > 0
+            assert programs["rpa"]["prompt_tokens"] > 0
+        m1 = sched.metrics
+        prompt = [r for n, r in programs.items() if n in PROMPT_PROGRAMS]
+        assert m1["prefill_dispatches"] - m0["prefill_dispatches"] == sum(
+            r["dispatches"] for r in prompt)
+        assert (m1["prefill_query_tokens"] - m0["prefill_query_tokens"]
+                == sum(r["q_tokens"] for r in prompt))
+        assert (m1["prefill_token_slots"] - m0["prefill_token_slots"]
+                == sum(r["q_slots"] for r in prompt))
+        assert m1["prefill_tokens"] - m0["prefill_tokens"] == sum(
+            r["prompt_tokens"] for r in programs.values())
+        # a fresh engine's first sight of each key is its one cold dispatch
+        assert m1["cold_dispatches"] == sum(
+            len(r["keys"]) for r in programs.values())
+        assert m1["cold_seconds"] > 0.0
+        evs = [e for e in tracer.events() if e["name"] == "prefill_dispatch"]
+        spans = {e["args"]["id"]: e["args"] for e in tracer.events()
+                 if e["name"] == "sched.dispatch"}
+        assert len(spans) == sum(r["dispatches"] for r in programs.values())
+        assert evs and {e["args"]["id"] for e in evs} <= set(spans)
+        assert sum(e["args"]["prompt_tokens"] for e in evs) == (
+            m1["prefill_tokens"] - m0["prefill_tokens"])
+        for e in evs:
+            assert {"id", "program", "key", *RECORD_FIELDS[1:]} == set(
+                e["args"])
+    finally:
+        disable_tracing()
+        eng.shutdown()
+
+
+def test_warm_rerun_is_not_cold_and_windows_to_its_own_dispatches(
+        mixed_engine):
+    """The same traffic again on one engine, once its prefix cache and
+    its programs are warm: a window's programs table repeats the window
+    before it and holds no cold dispatch."""
+    sched = mixed_engine._scheduler
+    for _ in range(2):  # the second pass meets the cache the first left
+        mixed_engine.generate_batch(_reqs(3, start=200))
+    snap, m0 = sched.anatomy_snapshot(), dict(sched.metrics)
+    mixed_engine.generate_batch(_reqs(3, start=200))
+    first = sched.anatomy_report(snap)["programs"]
+    m1 = dict(sched.metrics)
+    assert m1["cold_dispatches"] == m0["cold_dispatches"]
+    assert m1["cold_seconds"] == m0["cold_seconds"]
+    assert all(r["cold"] == 0 and r["cold_ms"] == 0.0
+               for r in first.values())
+    snap2 = sched.anatomy_snapshot()
+    mixed_engine.generate_batch(_reqs(3, start=200))
+    second = sched.anatomy_report(snap2)["programs"]
+    strip = lambda ps: {n: {f: v for f, v in r.items() if f != "keys"}
+                        for n, r in ps.items()}
+    assert strip(second) == strip(first)
+    assert sched.audit() == []
+
+
+def test_table_identities_survive_a_dispatch_fault(mixed_engine):
+    """A fault between two dispatches: the iteration is discarded from the
+    segment totals, but the records of what was dispatched stay beside the
+    scheduler's own counters — audit() keeps all three identities."""
+    from lmrs_tpu.engine.executor import MapExecutor
+    from lmrs_tpu.obs.anatomy import PROMPT_PROGRAMS
+    from lmrs_tpu.testing import faults
+    from lmrs_tpu.testing.faults import FaultPlan
+
+    sched = mixed_engine._scheduler
+    ex = MapExecutor(mixed_engine, EngineConfig(retry_attempts=3,
+                                                retry_delay=0.01))
+    with faults.injected(FaultPlan(seed=5, faults=[
+            {"site": "scheduler.step", "at": [2, 5], "max_fires": 2}])):
+        out = ex.run_requests(_reqs(4, start=300))
+    assert all(r.finish_reason is not None for r in out)
+    assert sched.audit() == []
+    programs = sched.anatomy_report()["programs"]
+    m = sched.metrics
+    assert sum(r["prompt_tokens"] for r in programs.values()) == (
+        m["prefill_tokens"])
+    assert sum(r["q_tokens"] for n, r in programs.items()
+               if n in PROMPT_PROGRAMS) == m["prefill_query_tokens"]
+    for rec in programs.values():
+        for key, k in rec["keys"].items():
+            assert k["q_slots"] % k["dispatches"] == 0, key
+
+
 def test_slow_step_postmortem_schema(mixed_engine, monkeypatch, tmp_path):
     """LMRS_ANATOMY_SLOW_MS armed at a hair-trigger threshold: every
     iteration files a schema-valid slow_step postmortem whose extra block
@@ -438,7 +714,9 @@ def test_mock_anatomy_is_deterministic_and_schema_matched():
     assert a["residual_ms"] == 0.0
     assert a["iterations"] > 0
     # schema parity with the scheduler's report (the rtt keys are
-    # optional extras the scheduler adds when a sample exists)
+    # optional extras the scheduler adds when a sample exists, and the
+    # ``programs`` table is the scheduler's alone: the mock dispatches
+    # no device program)
     want = {"object", "enabled", "iterations", "aborted_iterations",
             "wall_ms", "residual_ms", "segments_ms",
             "host_overhead_us_step", "classes", "buckets",

@@ -325,6 +325,140 @@ def test_timestamps_filter(tracer):
     assert len(tracer.timestamps("decode_block")) == 3
 
 
+# ------------------------------------------------ span(): one span, two sinks
+
+
+class _StandInAnnotation:
+    """What jax.profiler.TraceAnnotation is to span(): built with the name
+    and the args, entered and left; it writes only while a session runs."""
+
+    session = False
+    log: list = []
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        if _StandInAnnotation.session:
+            _StandInAnnotation.log.append(("enter", self.name, self.args))
+        return self
+
+    def __exit__(self, *exc):
+        if _StandInAnnotation.session:
+            _StandInAnnotation.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def stand_in(monkeypatch):
+    from lmrs_tpu.obs import trace
+
+    monkeypatch.setattr(trace, "_annotation_cls", _StandInAnnotation)
+    monkeypatch.setattr(_StandInAnnotation, "session", False)
+    monkeypatch.setattr(_StandInAnnotation, "log", [])
+    return _StandInAnnotation
+
+
+@pytest.mark.parametrize("session,ring", [(True, True), (True, False),
+                                          (False, True), (False, False)])
+def test_span_records_name_and_args_in_the_sinks_that_are_on(
+        stand_in, session, ring):
+    from lmrs_tpu.obs import span
+
+    disable_tracing()
+    tr = enable_tracing() if ring else None
+    if tr:
+        tr.clear()
+    stand_in.session = session
+    try:
+        with span("sched.dispatch", program="rpa", id=7, cold=False):
+            with span("inner"):
+                pass
+    finally:
+        disable_tracing()
+    want_args = {"program": "rpa", "id": 7, "cold": False}
+    if session:
+        assert stand_in.log == [
+            ("enter", "sched.dispatch", want_args), ("enter", "inner", {}),
+            ("exit", "inner"), ("exit", "sched.dispatch")]
+    else:
+        assert stand_in.log == []
+    if ring:
+        evs = {e["name"]: e for e in tr.events()}
+        assert set(evs) == {"sched.dispatch", "inner"}
+        assert evs["sched.dispatch"]["args"] == want_args
+        assert evs["sched.dispatch"]["ph"] == "X"
+        assert "args" not in evs["inner"]
+        validate_trace_events(tr.events())
+        outer, inner = evs["sched.dispatch"], evs["inner"]
+        assert outer["ts"] <= inner["ts"]
+        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+def test_span_without_annotation_stays_off_jax(stand_in, tracer):
+    """StageTimer without profile=: the ring gets the stage, the profiler
+    class is never built (a mock-backend run must not import JAX for it)."""
+    from lmrs_tpu.obs import PID_PIPELINE
+    from lmrs_tpu.utils.timing import StageTimer
+
+    stand_in.session = True
+    timer = StageTimer(profile=False)
+    with timer.stage("map"):
+        pass
+    assert stand_in.log == []
+    assert [(e["name"], e["pid"]) for e in tracer.events()] == [
+        ("map", PID_PIPELINE)]
+    assert timer.spans["map"] >= 0.0
+    with StageTimer(profile=True).stage("reduce"):
+        pass
+    assert [x[:2] for x in stand_in.log] == [("enter", "reduce"),
+                                             ("exit", "reduce")]
+
+
+def test_scheduler_segments_reach_the_profiler_sink(stand_in):
+    """With a session on and the Chrome tracer off, a run writes sched.run
+    (with unix_ns), every segment under its sched.* name, sched.dispatch
+    with the record's arguments, and a sched.fetch that retires the
+    dispatch ids issued before it."""
+    from lmrs_tpu.config import EngineConfig
+    from lmrs_tpu.engine.api import GenerationRequest
+    from lmrs_tpu.engine.jax_engine import JaxEngine
+
+    disable_tracing()
+    eng = JaxEngine(EngineConfig(backend="jax", scheduler="continuous",
+                                 max_tokens=8, max_batch_slots=2, seed=0),
+                    _tiny_model())
+    stand_in.session = True
+    try:
+        out = eng.generate_batch([GenerationRequest(
+            prompt="profiler sink probe", request_id=0, temperature=0.0,
+            max_new_tokens=5)])
+    finally:
+        stand_in.session = False
+        eng.shutdown()
+    assert out[0].error is None
+    enters = [(x[1], x[2]) for x in stand_in.log if x[0] == "enter"]
+    names = [n for n, _ in enters]
+    assert names[0] == "sched.run"
+    assert abs(enters[0][1]["unix_ns"] * 1e-9 - __import__("time").time()) < 600
+    assert {"sched.admit", "sched.plan", "sched.dispatch", "sched.fetch",
+            "sched.finish", "sched.io"} <= set(names)
+    disp = [a for n, a in enters if n == "sched.dispatch"]
+    assert set(disp[0]) == {"program", "key", "id", "rows", "q_tokens",
+                            "q_slots", "cold"}
+    assert [a["id"] for a in disp] == list(range(1, len(disp) + 1))
+    assert disp[0]["program"] == "prefill" and disp[0]["cold"] is True
+    retired = [int(i) for n, a in enters if n == "sched.fetch"
+               for i in a["retires"].split("+") if i]
+    assert retired == [a["id"] for a in disp]  # each once, in order
+    # spans nest: every enter has its exit, innermost first
+    depth = 0
+    for kind, *_ in stand_in.log:
+        depth += 1 if kind == "enter" else -1
+        assert depth >= 0
+    assert depth == 0
+
+
 # ------------------------------------------------- scheduler span chains
 
 
