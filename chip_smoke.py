@@ -527,17 +527,19 @@ def check_int8_multi_verify(interpret: bool) -> float:
 
 
 def check_ragged_spans(interpret: bool, int8: bool = False) -> float:
-    """``ragged_spans_pallas`` on a MIXED span list — decode rows, a long
+    """``ragged_spans_pallas`` on a MIXED span list — decode rows, a
     prefill-slice row whose length is not a tile multiple, an inactive
-    row — vs ``ragged_spans_xla``: in-span outputs and every row's valid
-    pool prefix (past it lies the kernel's future-position padding)."""
+    row, and a span long enough for the wide query tile (two tiles, the
+    second sliding back over the first) — vs ``ragged_spans_xla``: in-span
+    outputs and every row's valid pool prefix (past it lies the narrow
+    path's future-position padding)."""
     import jax.numpy as jnp
     import numpy as np
 
     from lmrs_tpu.ops.paged_attention import (pack_spans, ragged_spans_pallas,
                                               ragged_spans_xla)
 
-    q_lens = np.asarray([1, 45, 1, 0, 1, 130], np.int32)
+    q_lens = np.asarray([1, 45, 1, 0, 1, 300], np.int32)
     b, h, kh, hd, ps, w = len(q_lens), 8, 4, 128, 128, 3
     bases = np.asarray([200, 7, 0, 0, ps - 1, 64], np.int32)
     n_pages = 1 + b * w

@@ -44,7 +44,8 @@ from lmrs_tpu.engine.kv_cache import (OutOfPages, PagedKVCache, SequencePages,
 from lmrs_tpu.engine.prefix_cache import PrefixCache
 from lmrs_tpu.fleet.qos import maybe_qos
 from lmrs_tpu.models.transformer import forward_paged
-from lmrs_tpu.ops.paged_attention import pack_spans, pow2_bucket
+from lmrs_tpu.ops.paged_attention import (pack_spans, pow2_bucket,
+                                          span_walk_counts)
 from lmrs_tpu.obs import (POW2_TOKEN_BUCKETS, RATIO_BUCKETS, CostLedger,
                           DispatchAttribution, MetricsRegistry, SLOEngine,
                           dump_postmortem, get_tracer, maybe_anatomy, req_tid,
@@ -4017,7 +4018,9 @@ class ContinuousScheduler:
         with self._an.dispatch(
                 "rpa", key_, rows=len(rows) + (pf is not None),
                 row_slots=self.B, q_tokens=real, prompt_tokens=c,
-                q_slots=tpb, ctx_tokens=live_tokens + pos, cold=not warm):
+                q_slots=tpb, ctx_tokens=live_tokens + pos, cold=not warm,
+                **self._span_walk(q_lens_np, base_np, w,
+                                  kernel=not tree_live)):
             out = dispatch()
         self._note_ran_ok(key_)
         with self._an.seg("fetch"):
@@ -4307,6 +4310,18 @@ class ContinuousScheduler:
 
         return pending
 
+    def _span_walk(self, q_lens, bases, w: int, kernel: bool = True) -> dict:
+        """The span dispatch record's ``wide_tokens`` / ``kv_page_reads``:
+        what the ragged span kernel will do with these spans, counted by
+        its own rule.  Empty where the dispatch's attention is the XLA
+        twin's (no kernel armed, a tp mesh, a tree-verify ancestor mask):
+        there is no page walk to count."""
+        if not (kernel and self._use_ragged and self._kernel_mesh() is None):
+            return {}
+        wide_tokens, kv_page_reads = span_walk_counts(
+            q_lens, bases, self.cache.page_size, w, self.max_len)
+        return {"wide_tokens": wide_tokens, "kv_page_reads": kv_page_reads}
+
     def _dispatch_rpa_chunks(self, items) -> tuple[object, list]:
         """Windowed continuation chunks as ragged SPANS (LMRS_RPA with the
         kernel armed): every chunk is one long-span row of a single
@@ -4378,7 +4393,8 @@ class ContinuousScheduler:
         with self._an.dispatch(
                 "rpa", key_, rows=len(items), row_slots=self.B,
                 q_tokens=batch_tokens, prompt_tokens=batch_tokens,
-                q_slots=tpb, ctx_tokens=int(base_np.sum()), cold=not warm):
+                q_slots=tpb, ctx_tokens=int(base_np.sum()), cold=not warm,
+                **self._span_walk(q_lens_np, base_np, w)):
             tok0, self.cache.k, self.cache.v, ks, vs = \
                 self._get_rpa_fn(tpb, w)(*args)
         self._note_ran_ok(key_)

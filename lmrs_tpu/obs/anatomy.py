@@ -23,7 +23,8 @@ shapes".  This module names every microsecond between two dispatches:
 * ``dispatch(program, key, ...)`` is the ``dispatch`` segment of one
   device dispatch AND its record: the site says what the dispatch carried
   (rows and row slots, real and padded query positions, prompt positions,
-  context tokens, cold or warm) and the record folds into a per-program,
+  context tokens, the span kernel's wide-tile tokens and page fetches,
+  cold or warm) and the record folds into a per-program,
   per-key table that ``snapshot()`` / ``report(before=...)`` window like
   the segment totals.  On a cold key the segment's wall is the compile.
   The ragged-span bucket economics of PR 16/18 (``buckets``,
@@ -72,7 +73,8 @@ PROMPT_PROGRAMS = frozenset(PROGRAMS[:5])
 # carry them (the report adds ``cold_ms`` and, per program, ``keys``)
 RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
                                   "q_tokens", "prompt_tokens", "q_slots",
-                                  "ctx_tokens", "cold")
+                                  "ctx_tokens", "wide_tokens",
+                                  "kv_page_reads", "cold")
 
 # iteration step classes (the decode_split/serving_latency split axis)
 CLASSES: tuple[str, ...] = ("plain", "mixed", "spec", "prefill")
@@ -260,7 +262,8 @@ class StepAnatomy:
         self._unretired: list[int] = []  # ids no fetch has retired yet
         # flat sums of the same records, for ``scheduler.metrics``
         self._flat = {"prefill_dispatches": 0, "prefill_query_tokens": 0,
-                      "prefill_token_slots": 0, "cold_dispatches": 0,
+                      "prefill_token_slots": 0, "rpa_wide_tokens": 0,
+                      "rpa_kv_page_reads": 0, "cold_dispatches": 0,
                       "cold_seconds": 0.0}
 
         c, g, h = (registry.counter, registry.gauge, registry.histogram)
@@ -395,7 +398,8 @@ class StepAnatomy:
 
     def dispatch(self, program: str, key: tuple, *, rows: int,
                  row_slots: int, q_tokens: int, prompt_tokens: int,
-                 q_slots: int, ctx_tokens: int, cold: bool) -> _Dispatch:
+                 q_slots: int, ctx_tokens: int, cold: bool,
+                 wide_tokens: int = 0, kv_page_reads: int = 0) -> _Dispatch:
         """The ``dispatch`` segment of one device dispatch, with what it
         carried.  ``program`` is one of ``PROGRAMS`` and ``key`` the site's
         own compile key; ``rows`` carry work out of ``row_slots`` operand
@@ -403,7 +407,11 @@ class StepAnatomy:
         of them prompt positions) out of the ``q_slots`` the operand
         holds; ``ctx_tokens`` are the KV tokens already in pages that the
         dispatch attends; ``cold`` says the key has never run, so the
-        segment's wall is its compile."""
+        segment's wall is its compile.  A span dispatch whose attention
+        runs in the ragged span kernel also says what the kernel will do
+        with it (``ops/paged_attention.span_walk_counts``, the kernel's
+        rule on the host): ``wide_tokens`` of the query positions fall in
+        wide tiles, and its page walks fetch ``kv_page_reads`` pages."""
         if program not in PROGRAMS:
             raise ValueError(f"unknown dispatch program {program!r} "
                              f"(want one of {PROGRAMS})")
@@ -412,7 +420,8 @@ class StepAnatomy:
             "rows": int(rows), "row_slots": int(row_slots),
             "q_tokens": int(q_tokens), "prompt_tokens": int(prompt_tokens),
             "q_slots": int(q_slots), "ctx_tokens": int(ctx_tokens),
-            "cold": bool(cold)})
+            "wide_tokens": int(wide_tokens),
+            "kv_page_reads": int(kv_page_reads), "cold": bool(cold)})
 
     def _fold(self, r: dict) -> None:
         rec = self._table.get((r["program"], r["key"]))
@@ -429,6 +438,8 @@ class StepAnatomy:
             flat["prefill_query_tokens"] += r["q_tokens"]
             flat["prefill_token_slots"] += r["q_slots"]
         if r["program"] == "rpa":
+            flat["rpa_wide_tokens"] += r["wide_tokens"]
+            flat["rpa_kv_page_reads"] += r["kv_page_reads"]
             self._c_b_disp.inc()
             self._c_b_real.inc(r["q_tokens"])
             self._c_b_pad.inc(max(r["q_slots"] - r["q_tokens"], 0))
@@ -442,8 +453,9 @@ class StepAnatomy:
     def counters(self) -> dict:
         """The flat sums ``ContinuousScheduler.metrics`` carries: prefill
         dispatches, their real query positions and the positions their
-        operands held (the prompt programs), cold dispatches and their
-        wall (all programs)."""
+        operands held (the prompt programs), the span kernel's wide-tile
+        tokens and page fetches (the ``rpa`` program), cold dispatches and
+        their wall (all programs)."""
         return dict(self._flat)
 
     # --------------------------------------------------------------- reading
@@ -485,6 +497,10 @@ class StepAnatomy:
             prompt += rec["prompt_tokens"]
             if program in PROMPT_PROGRAMS:
                 q_prompt_programs += rec["q_tokens"]
+            if rec["wide_tokens"] > rec["q_tokens"]:
+                violations.append(
+                    f"anatomy dispatch table {_key_str(key)}: wide_tokens "
+                    f"{rec['wide_tokens']} > q_tokens {rec['q_tokens']}")
             if rec["q_slots"] != rec["dispatches"] * rec["slots"]:
                 violations.append(
                     f"anatomy dispatch table {_key_str(key)}: q_slots "

@@ -51,7 +51,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from lmrs_tpu.utils.jax_compat import shard_map
+from lmrs_tpu.utils.jax_compat import shard_map, tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -948,10 +948,14 @@ def paged_decode_multi_xla(
 
 # ------------------------------------------------- ragged span kernel (RPA)
 
-# Query-tile height of the span kernel.  Spans are host-packed to QT-token
-# alignment (pack_spans), so every tile's flat offset is provably aligned
-# for Mosaic's dynamic-slice prover and no tile straddles two spans.
+# Query-tile height of the span kernel's narrow path.  Spans are host-packed
+# to QT-token alignment (pack_spans), so every tile's flat offset is provably
+# aligned for Mosaic's dynamic-slice prover and no tile straddles two spans.
 SPAN_QT = 8
+# Query-tile height of the wide path: a span of at least this many tokens is
+# walked flash-style, SPAN_QT_WIDE queries against ONE pass over the row's
+# pages.  Chosen by a sweep on the chip (PERF.md section 6, PR 26).
+SPAN_QT_WIDE = 256
 
 
 # canonical bucket edges of the ragged-span compile-key family — defined
@@ -973,7 +977,307 @@ def pack_spans(q_lens, floor: int = 16):
     return q_starts.astype(np.int32), int(max(floor, aligned.sum()))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "max_pos"))
+def span_walk_counts(q_lens, kv_lens, page_size: int, table_pages: int,
+                     max_pos: int | None = None,
+                     qt_wide: int | None = None) -> tuple[int, int]:
+    """What ``ragged_spans_pallas`` will do with one dispatch, counted on the
+    host by the kernel's own rule: ``(wide_tokens, kv_page_reads)`` — the
+    query tokens that fall in wide tiles, and the K/V pages the walks fetch
+    (per tile, the pages up to its causal limit).
+
+    The rule, per row with span length ``ql`` behind ``base`` cached tokens:
+    ``ql >= W`` (``SPAN_QT_WIDE``) takes wide tiles at span offsets
+    ``min(i*W, ql8 - W)`` (``ql8`` = ``ql`` rounded up to SPAN_QT; the last
+    tile slides back over its predecessor instead of running past the span),
+    each walking the pages below ``min(base + offset + W, base + ql, cap)``;
+    a shorter span takes SPAN_QT-token tiles, tile ``i`` walking the pages
+    below ``base + (i+1)*SPAN_QT``.  ``cap`` is the table span, and
+    ``max_pos`` where given.  Pure numpy; never traced."""
+    wide = SPAN_QT_WIDE if qt_wide is None else qt_wide
+    ps = page_size
+    cap = table_pages * ps if max_pos is None else min(table_pages * ps,
+                                                       max_pos)
+    q_lens = np.asarray(q_lens, np.int64)
+    kv_lens = np.asarray(kv_lens, np.int64)
+    # one-tile rows (decode, verify) in one vector pass; long spans, a few
+    # a dispatch, row by row
+    one = (q_lens > 0) & (q_lens <= SPAN_QT)
+    reads = int(np.minimum(-(-(kv_lens[one] + SPAN_QT) // ps),
+                           table_pages).sum())
+    wide_tokens = 0
+    for r in np.flatnonzero(q_lens > SPAN_QT):
+        ql, base = int(q_lens[r]), int(kv_lens[r])
+        if ql >= wide:
+            ql8 = -(-ql // SPAN_QT) * SPAN_QT
+            t0 = np.minimum(np.arange(-(-ql8 // wide)) * wide, ql8 - wide)
+            end = np.minimum(base + np.minimum(t0 + wide, ql), cap)
+            wide_tokens += ql
+            reads += int((-(-end // ps)).sum())
+        else:
+            end = base + (np.arange(-(-ql // SPAN_QT)) + 1) * SPAN_QT
+            reads += int(np.minimum(-(-end // ps), table_pages).sum())
+    return wide_tokens, reads
+
+
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` value at width ``n``: its first
+    ``n`` lanes, or copies side by side where ``n`` is a multiple of 128.
+    Either way whole vregs: no cross-lane move."""
+    if n <= 128:
+        return x[:, :n]
+    assert n % 128 == 0, n
+    return jnp.concatenate([x] * (n // 128), axis=1)
+
+
+def _fold_page_wide(q, k, v, masked, sm_scale, acc_ref, m_ref, l_ref):
+    """Fold one page of one kv head into a wide span tile's online softmax:
+    scores ``q·kᵀ`` ([rows, ps], f32 accumulation whatever the operands'
+    dtype), masked, against the head's running max / sum ([rows, 128] f32,
+    every lane of a row the same number) and accumulator ([rows, hd] f32).
+    The same arithmetic as the walk of ``_ragged_decode_all_heads``, with
+    the row statistics kept LANE-REPLICATED end to end: read whole, combined
+    whole, stored whole.  Reading lane 0 and broadcasting it back (the
+    narrow walk's spelling, fine at its 8-64 rows) costs four cross-lane
+    permutes per 8 rows per page here beside the two reductions, and the
+    cross-lane unit is what bounds a 512-row tile (PERF.md, PR 26).
+    No all-masked guard: a tile's walk starts at page 0, whose position 0
+    every row sees, so the running max is finite from the first fold on."""
+    hd = acc_ref.shape[-1]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale  # [rows, ps]
+    s = jnp.where(masked, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    pw = jnp.exp(s - _lanes(m_new, s.shape[1]))
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(pw, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * _lanes(alpha, hd) + jax.lax.dot_general(
+        pw, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _wide_span_tiles(
+    # scalar prefetch, and this program's row with its span
+    pt_ref, qs_ref, bi, ql, base,
+    # HBM operands
+    q_hbm, kn_hbm, vn_hbm, k_out, v_out, o_hbm,
+    # scratch
+    k_scr, v_scr,     # VMEM [2, kh, ps, hd] double-buffered whole pages
+    sem,              # DMA (2, 2): page fetches [buffer parity, k/v]
+    q_scr,            # VMEM [2, kh, W*n_rep, hd] q tiles (tile parity)
+    kn_scr, vn_scr,   # VMEM [2, kh, W, hd] the tiles' new-token K/V
+    o_scr,            # VMEM [kh, W*n_rep, hd]
+    acc_scr, m_scr, l_scr,  # f32 [kh, W*n_rep, hd | 128 | 128]
+    psem,             # DMA (2, 2): page write-backs
+    lsem,             # DMA (2, 3): tile loads [tile parity, q/kn/vn]
+    osem,             # DMA (1,): tile output store
+    qf_scr=None,      # VMEM [kh, W*n_rep, hd] f32: int8 pools (q ⊙ k scale)
+    *, wide: int, n_rep: int, kh: int, page_size: int, sm_scale: float,
+    max_pos: int | None, get_kscale, get_vscale,
+):
+    """The wide path of ``ragged_spans_pallas``: row ``bi``'s span (at least
+    ``wide`` tokens) in ``wide``-token query tiles, flash-style — per tile
+    ONE walk over the row's pages, each page's ``[ps, hd]`` block against
+    the tile's ``[wide * n_rep, hd]`` query rows per kv head.
+
+    The tile's own tokens are written on the way: a page the tile's
+    positions fall in is blended in VMEM when its fetch lands (new rows
+    over the fetched ones, a 0/1 selection matmul as in ``_make_rmw``),
+    attended in its blended form, and written back whole — one ``[kh, ps,
+    hd]`` DMA per such page instead of a read-blend-write cycle per 8 rows.
+    Only real tokens below the position cap land (no padding garbage).
+
+    Tile ``i`` covers span tokens ``[t0, t0 + wide)`` with ``t0 = min(i *
+    wide, ql8 - wide)``: the last tile slides back over its predecessor
+    rather than past the span's SPAN_QT-aligned end, so every tile is full
+    width, in bounds of the flat buffer and inside its own span; the
+    overlap recomputes the same rows to the same values (rows of a tile are
+    independent, and a fully masked page folds as the identity).
+
+    Pipeline: the loads of tile i+1 (q, new K/V) are issued before tile
+    i's walk and the store of tile i drains under tile i+1's walk; inside a
+    walk page p+1 streams while page p computes."""
+    ps, qt = page_size, SPAN_QT
+    w8 = wide // qt
+    rows = wide * n_rep
+    hd = q_scr.shape[-1]
+    quantized = get_kscale is not None
+    # bf16 q against a bf16 pool (f32 against f32) feeds the MXU as it is:
+    # the products are the numbers an f32 cast would give
+    direct = (not quantized) and q_scr.dtype == k_scr.dtype
+    cap = pt_ref.shape[1] * ps
+    if max_pos is not None:
+        cap = min(cap, max_pos)
+    ql8 = jax.lax.div(ql + qt - 1, qt)      # span length in QT units
+    n_tiles = jax.lax.div(ql8 + w8 - 1, w8)
+    qs8 = jax.lax.div(qs_ref[bi], qt)       # q_starts is QT-aligned
+
+    def tile_off8(ti):
+        return jnp.minimum(ti * w8, ql8 - w8)
+
+    def flat8(ti):
+        # the tile's flat offset in QT units: times a constant it is
+        # provably aligned for Mosaic
+        return qs8 + tile_off8(ti)
+
+    def loads(ti, slot):
+        tok8 = flat8(ti)
+        return (
+            pltpu.make_async_copy(
+                q_hbm.at[:, pl.ds(tok8 * (qt * n_rep), rows)],
+                q_scr.at[slot], lsem.at[slot, 0]),
+            pltpu.make_async_copy(
+                kn_hbm.at[:, pl.ds(tok8 * qt, wide)],
+                kn_scr.at[slot], lsem.at[slot, 1]),
+            pltpu.make_async_copy(
+                vn_hbm.at[:, pl.ds(tok8 * qt, wide)],
+                vn_scr.at[slot], lsem.at[slot, 2]))
+
+    def store(ti):
+        return pltpu.make_async_copy(
+            o_scr, o_hbm.at[:, pl.ds(flat8(ti) * (qt * n_rep), rows)],
+            osem.at[0])
+
+    def writeback(page, slot):
+        return (pltpu.make_async_copy(k_scr.at[slot], k_out.at[page],
+                                      psem.at[slot, 0]),
+                pltpu.make_async_copy(v_scr.at[slot], v_out.at[page],
+                                      psem.at[slot, 1]))
+
+    for c in loads(0, 0):
+        c.start()
+
+    def tile(ti, carry):
+        slot = jax.lax.rem(ti, 2)
+        t0 = tile_off8(ti) * qt
+        p0 = base + t0                      # position of the tile's token 0
+        # positions < end exist for this tile: its real tokens, capped
+        end = jnp.minimum(p0 + jnp.minimum(wide, ql - t0), cap)
+        n_pages = jax.lax.div(end + ps - 1, ps)
+        first_new = jax.lax.div(p0, ps)     # pages >= it take new tokens
+
+        @pl.when(n_pages > 0)
+        def _prime():
+            _fetch_page(pt_ref, k_out, v_out, k_scr, v_scr, sem, bi, 0, 0)
+
+        for c in loads(ti, slot):
+            c.wait()
+
+        @pl.when(ti + 1 < n_tiles)
+        def _next_loads():
+            for c in loads(ti + 1, 1 - slot):
+                c.start()
+
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+        if quantized:
+            for ki in range(kh):
+                qf_scr[ki] = (q_scr[slot, ki].astype(jnp.float32)
+                              * get_kscale(bi, ki)[None, :])
+        # row r is token r // n_rep at position p0 + that: it attends
+        # positions <= its own, below ``end``
+        tok_of_row = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) // n_rep
+        limit = jnp.minimum(p0 + tok_of_row + 1, end)      # [rows, 1]
+
+        def body(p, _):
+            cur = jax.lax.rem(p, 2)
+
+            @pl.when(p + 1 < n_pages)
+            def _prefetch():
+                # the other buffer last held page p-1: a write-back of it
+                # must land before the next fetch overwrites the buffer
+                @pl.when(p - 1 >= first_new)
+                def _():
+                    for c in writeback(0, 1 - cur):
+                        c.wait()
+
+                _fetch_page(pt_ref, k_out, v_out, k_scr, v_scr, sem,
+                            bi, p + 1, 1 - cur)
+
+            page = pt_ref[bi, p]
+            pltpu.make_async_copy(
+                k_out.at[page], k_scr.at[cur], sem.at[cur, 0]).wait()
+            pltpu.make_async_copy(
+                v_out.at[page], v_scr.at[cur], sem.at[cur, 1]).wait()
+
+            @pl.when(p >= first_new)
+            def _blend():
+                # page row i holds position p*ps + i = tile token
+                # (p*ps + i - p0): select it with a 0/1 matmul (no dynamic
+                # VMEM indexing), head-independent mask computed once
+                row_pos = p * ps + jax.lax.broadcasted_iota(
+                    jnp.int32, (ps, wide), 0)
+                tok = jax.lax.broadcasted_iota(jnp.int32, (ps, wide), 1)
+                sel = ((row_pos - p0 == tok)
+                       & (row_pos < end)).astype(kn_scr.dtype)
+                pos = p * ps + jax.lax.broadcasted_iota(
+                    jnp.int32, (ps, hd), 0)
+                hit = (pos >= p0) & (pos < end)
+                for ki in range(kh):
+                    k_rows = jax.lax.dot_general(
+                        sel, kn_scr[slot, ki], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    v_rows = jax.lax.dot_general(
+                        sel, vn_scr[slot, ki], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    if quantized:
+                        k_rows = jnp.clip(jnp.round(
+                            k_rows / get_kscale(bi, ki)[None, :]), -127, 127)
+                        v_rows = jnp.clip(jnp.round(
+                            v_rows / get_vscale(bi, ki)[None, :]), -127, 127)
+                    k_scr[cur, ki] = jnp.where(
+                        hit, k_rows.astype(k_scr.dtype), k_scr[cur, ki])
+                    v_scr[cur, ki] = jnp.where(
+                        hit, v_rows.astype(v_scr.dtype), v_scr[cur, ki])
+                for c in writeback(page, cur):
+                    c.start()
+
+            masked = (p * ps + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, ps), 1)) < limit
+            for ki in range(kh):
+                if quantized:
+                    q, k = qf_scr[ki], k_scr[cur, ki].astype(jnp.float32)
+                elif direct:
+                    q, k = q_scr[slot, ki], k_scr[cur, ki]
+                else:
+                    q = q_scr[slot, ki].astype(jnp.float32)
+                    k = k_scr[cur, ki].astype(jnp.float32)
+                _fold_page_wide(q, k, v_scr[cur, ki], masked, sm_scale,
+                                acc_scr.at[ki], m_scr.at[ki], l_scr.at[ki])
+            return _
+
+        jax.lax.fori_loop(0, n_pages, body, None)
+
+        # write-backs the loop did not have to wait for: the last two pages
+        for back in (2, 1):
+            @pl.when(n_pages - back >= first_new)
+            def _drain(back=back):
+                for c in writeback(0, jax.lax.rem(n_pages - back, 2)):
+                    c.wait()
+
+        @pl.when(ti > 0)
+        def _prev_store():  # it had this whole walk to land
+            store(ti - 1).wait()
+
+        for ki in range(kh):
+            l = _lanes(l_scr[ki], hd)
+            out = acc_scr[ki] / jnp.where(l > 0, l, 1.0)
+            if quantized:
+                out = out * get_vscale(bi, ki)[None, :]
+            o_scr[ki] = out.astype(o_scr.dtype)
+        store(ti).start()
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, tile, None)
+    store(n_tiles - 1).wait()
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "max_pos", "qt_wide"))
 def ragged_spans_pallas(
     q: jnp.ndarray,            # [Tp, H, hd] flat query tokens (all spans)
     k_new: jnp.ndarray,        # [Tp, K, hd] the tokens' K (post-rope)
@@ -990,32 +1294,48 @@ def ragged_spans_pallas(
     max_pos: int | None = None,
     kscale: jnp.ndarray | None = None,  # [B, K, hd] f32 (int8 pools)
     vscale: jnp.ndarray | None = None,
+    qt_wide: int | None = None,  # tests and the tile sweep shrink the wide
+                                 # tile here; every caller leaves it at
+                                 # SPAN_QT_WIDE
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """ONE ragged kernel for every phase (the RPA shape, PAPERS.md): each
     dispatch is a list of (row, query-span) pairs over the paged pool —
     plain decode is q_len=1 rows, speculative verify q_len=k+1 rows, a
     SARATHI mixed step is decode rows plus one long prefill-slice row, and
     a prefill continuation chunk is a long-span row.  One program per
-    batch row loops over its span's SPAN_QT-token tiles: per tile it DMAs
-    the tile's q rows and new-token K/V from HBM, RMWs the tokens into the
-    row's pages (``_make_rmw`` with a running prefix length), and walks the
-    prefix pages through the existing double-buffered pipeline with
-    per-token causal limits.  VMEM is bounded by the TILE — span length
-    only moves the trip counts — so the compile bucket family is
-    (pow2 total-query-tokens, page window) instead of the per-phase matrix.
+    batch row; the row's span length, read from ``q_lens`` inside the
+    kernel, picks the query tile:
 
-    Token j of row b sits at absolute position ``kv_lens[b] + j``; tile t
-    walks with prefix length ``kv_lens[b] + (t+1)*QT`` so its per-token
-    limits are exact.  The last tile's padding tokens write garbage K/V at
-    FUTURE positions (masked by every real query's limit; overwritten by
-    the row's next real tokens — the mixed path's existing convention) and
-    their query rows compute garbage outputs the consumer never gathers.
-    Flat tokens outside every span are untouched in the output buffer.
+    * a span of at least ``SPAN_QT_WIDE`` tokens takes wide tiles
+      (``_wide_span_tiles``): per tile the new tokens' K/V land in their
+      pages once and the row's pages are walked ONCE against all the tile's
+      query rows, so a c-token span behind n cached pages costs about
+      c/SPAN_QT_WIDE walks of n..n+c/ps pages;
+    * a shorter span (decode rows, verify spans, short mixed slices) takes
+      SPAN_QT-token tiles: per tile it DMAs the tile's q rows and
+      new-token K/V from HBM, RMWs the tokens into the row's pages
+      (``_make_rmw`` with a running prefix length), and walks the prefix
+      pages with per-token causal limits — c/SPAN_QT partial walks, each
+      from page 0, which is what a span of a few tokens should cost.
 
-    Per-tile page walks restart at page 0 (attention needs the whole
-    prefix), so a c-token span costs ~c/QT partial walks — fine at mixed
-    and chunk sizes where spans ≲ the prefill chunk; the flash path
-    remains the right tool for large FRESH prefills with no prior KV."""
+    VMEM is bounded by the TILE — span length only moves the trip counts —
+    so the compile bucket family is (pow2 total-query-tokens, page window)
+    instead of the per-phase matrix, and a dispatch mixes both kinds of row.
+    ``span_walk_counts`` is the same rule on the host.
+
+    Token j of row b sits at absolute position ``kv_lens[b] + j``.  A
+    narrow tile t walks with prefix length ``kv_lens[b] + (t+1)*QT`` so its
+    per-token limits are exact; the last narrow tile's padding tokens write
+    garbage K/V at FUTURE positions (masked by every real query's limit;
+    overwritten by the row's next real tokens — the mixed path's existing
+    convention).  Padding query rows of either path compute garbage outputs
+    the consumer never gathers.  Flat tokens outside every span are
+    untouched in the output buffer.
+
+    Query rows are ``n_rep`` per token in both paths (a tile is a multiple
+    of SPAN_QT tokens, so its rows are a multiple of 8 without padding the
+    head group).  The flash path remains the tool for large FRESH prefills
+    with no prior KV: it needs no page walk at all."""
     tp, h, hd = q.shape
     kh = k_pages.shape[1]
     ps = k_pages.shape[2]
@@ -1024,38 +1344,53 @@ def ragged_spans_pallas(
     assert quantized == (k_pages.dtype == jnp.int8), (
         "int8 pools need scales and vice versa")
     assert tp % SPAN_QT == 0, "pad the flat token buffer to SPAN_QT"
+    wide = SPAN_QT_WIDE if qt_wide is None else qt_wide
+    assert wide % SPAN_QT == 0 and wide > SPAN_QT, wide
+    # a flat buffer shorter than one wide tile holds no wide span: such a
+    # dispatch (decode rows, a small mixed step) compiles the narrow path
+    # alone, with the narrow path's scratch
+    has_wide = tp >= wide
     wh = 32 if quantized else 8
     n_rep = h // kh
-    n_rep_p = -(-n_rep // 8) * 8
     qt = SPAN_QT
-    tile_rows = qt * n_rep_p
+    tile_rows = qt * n_rep
+    wide_rows = wide * n_rep
     n_win = (qt - 2) // wh + 2
     sm_scale = hd**-0.5
 
-    # [Tp, H, hd] -> [kh, Tp*n_rep_p, hd], token-major row groups
-    qg = q.reshape(tp, kh, n_rep, hd)
-    if n_rep_p != n_rep:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, n_rep_p - n_rep), (0, 0)))
-    qg = qg.transpose(1, 0, 2, 3).reshape(kh, tp * n_rep_p, hd)
+    # [Tp, H, hd] -> [kh, Tp*n_rep, hd], token-major row groups
+    qg = q.reshape(tp, kh, n_rep, hd).transpose(1, 0, 2, 3).reshape(
+        kh, tp * n_rep, hd)
     knew = k_new.transpose(1, 0, 2)  # [kh, Tp, hd]
     vnew = v_new.transpose(1, 0, 2)
 
     def kernel(pt_ref, len_ref, qs_ref, ql_ref, q_hbm, kn_hbm, vn_hbm,
                *rest):
         if quantized:
-            (ksc_ref, vsc_ref, k_hbm, v_hbm, o_hbm, k_out, v_out,
-             k_scr, v_scr, q_scr, o_scr, kn_scr, vn_scr,
-             acc_scr, m_scr, l_scr, k8_scr, v8_scr, sem, wsem, dsem) = rest
+            ksc_ref, vsc_ref, *rest = rest
             gks = lambda row, ki: ksc_ref[row, ki]
             gvs = lambda row, ki: vsc_ref[row, ki]
         else:
-            (k_hbm, v_hbm, o_hbm, k_out, v_out,
-             k_scr, v_scr, q_scr, o_scr, kn_scr, vn_scr,
-             acc_scr, m_scr, l_scr, k8_scr, v8_scr, sem, wsem, dsem) = rest
             gks = gvs = None
+        (k_hbm, v_hbm, o_hbm, k_out, v_out,
+         k_scr, v_scr, sem, q_scr, o_scr, kn_scr, vn_scr,
+         acc_scr, m_scr, l_scr, k8_scr, v8_scr, wsem, dsem,
+         *wide_scr) = rest
         bi = pl.program_id(0)
         ql = ql_ref[bi]
         base = len_ref[bi]
+
+        if has_wide:
+            @pl.when(ql >= wide)
+            def _wide_row():
+                _wide_span_tiles(
+                    pt_ref, qs_ref, bi, ql, base,
+                    q_hbm, kn_hbm, vn_hbm, k_out, v_out, o_hbm,
+                    k_scr, v_scr, sem, *wide_scr,
+                    wide=wide, n_rep=n_rep, kh=kh, page_size=ps,
+                    sm_scale=sm_scale, max_pos=max_pos,
+                    get_kscale=gks, get_vscale=gvs)
+
         rmw = _make_rmw(
             pt_ref, len_ref,
             lambda _row, ki: kn_scr[ki], lambda _row, ki: vn_scr[ki],
@@ -1064,8 +1399,8 @@ def ragged_spans_pallas(
             max_pos=max_pos, wh=wh, get_kscale=gks, get_vscale=gvs,
         )
 
-        @pl.when(ql > 0)
-        def _row():
+        @pl.when((ql > 0) & (ql < wide))
+        def _narrow_row():
             n_tiles = jax.lax.div(ql + qt - 1, qt)
 
             def tile(ti, carry):
@@ -1094,7 +1429,7 @@ def ragged_spans_pallas(
                     pt_ref, len_ref, q_scr, k_out, v_out, o_scr,
                     k_scr, v_scr, acc_scr, m_scr, l_scr, sem,
                     page_size=ps, sm_scale=sm_scale, kh=kh,
-                    n_rep_p=n_rep_p, n_tokens=qt, max_pos=max_pos,
+                    n_rep_p=n_rep, n_tokens=qt, max_pos=max_pos,
                     row=bi, length=tile_len,
                     get_kscale=gks, get_vscale=gvs,
                 )
@@ -1115,6 +1450,42 @@ def ragged_spans_pallas(
             pl.BlockSpec((b, kh, hd), lambda bi, *_: (0, 0, 0)),
         ]
         operands += [kscale.astype(jnp.float32), vscale.astype(jnp.float32)]
+    f32 = jnp.float32
+    scratch = [
+        pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages
+        pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),             # page fetches
+        # narrow tiles
+        pltpu.VMEM((kh, tile_rows, hd), q.dtype),    # q tile
+        pltpu.VMEM((kh, tile_rows, hd), q.dtype),    # o tile
+        pltpu.VMEM((kh, qt, hd), k_new.dtype),       # new-token K tile
+        pltpu.VMEM((kh, qt, hd), v_new.dtype),
+        pltpu.VMEM((kh, tile_rows, hd), f32),
+        pltpu.VMEM((kh, tile_rows, 128), f32),
+        pltpu.VMEM((kh, tile_rows, 128), f32),
+        pltpu.VMEM((n_win, kh, wh, hd), k_pages.dtype),
+        pltpu.VMEM((n_win, kh, wh, hd), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((n_win, 2)),         # RMW windows
+        pltpu.SemaphoreType.DMA((4,)),               # q/kn/vn loads, o store
+    ]
+    if has_wide:  # in ``_wide_span_tiles``' own order
+        scratch += [
+            pltpu.VMEM((2, kh, wide_rows, hd), q.dtype),
+            pltpu.VMEM((2, kh, wide, hd), k_new.dtype),
+            pltpu.VMEM((2, kh, wide, hd), v_new.dtype),
+            pltpu.VMEM((kh, wide_rows, hd), q.dtype),
+            pltpu.VMEM((kh, wide_rows, hd), f32),
+            pltpu.VMEM((kh, wide_rows, 128), f32),
+            pltpu.VMEM((kh, wide_rows, 128), f32),
+            pltpu.SemaphoreType.DMA((2, 2)),         # page write-backs
+            pltpu.SemaphoreType.DMA((2, 3)),         # q/kn/vn loads
+            pltpu.SemaphoreType.DMA((1,)),           # o store
+        ]
+        if quantized:
+            scratch.append(pltpu.VMEM((kh, wide_rows, hd), f32))  # q ⊙ scale
+    vmem_bytes = sum(
+        s.inner_aval.size * s.inner_aval.dtype.itemsize
+        for s in scratch if s.memory_space == pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b,),
@@ -1131,38 +1502,27 @@ def ragged_spans_pallas(
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages
-            pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
-            pltpu.VMEM((kh, tile_rows, hd), q.dtype),    # q tile
-            pltpu.VMEM((kh, tile_rows, hd), q.dtype),    # o tile
-            pltpu.VMEM((kh, qt, hd), k_new.dtype),       # new-token K tile
-            pltpu.VMEM((kh, qt, hd), v_new.dtype),
-            pltpu.VMEM((kh, tile_rows, hd), jnp.float32),
-            pltpu.VMEM((kh, tile_rows, 128), jnp.float32),
-            pltpu.VMEM((kh, tile_rows, 128), jnp.float32),
-            pltpu.VMEM((n_win, kh, wh, hd), k_pages.dtype),
-            pltpu.VMEM((n_win, kh, wh, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((n_win, 2)),
-            pltpu.SemaphoreType.DMA((4,)),  # q/kn/vn loads + o store
-        ],
+        scratch_shapes=scratch,
     )
     pool_at = 4 + len(operands)  # k_pages index among ALL (flat) args
     out, k_pages, v_pages = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((kh, tp * n_rep_p, hd), q.dtype),
+            jax.ShapeDtypeStruct((kh, tp * n_rep, hd), q.dtype),
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
         input_output_aliases={pool_at: 1, pool_at + 1: 2},
+        # the wide tile's scratch passes the compiler's default scoped
+        # limit; leave room beside it for the walk's own temporaries
+        compiler_params=tpu_compiler_params(
+            vmem_limit_bytes=vmem_bytes + (24 << 20)),
         interpret=interpret,
     )(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
       q_starts.astype(jnp.int32), q_lens.astype(jnp.int32),
       *operands, k_pages, v_pages)
-    out = out.reshape(kh, tp, n_rep_p, hd)[:, :, :n_rep]
+    out = out.reshape(kh, tp, n_rep, hd)
     return out.transpose(1, 0, 2, 3).reshape(tp, h, hd), k_pages, v_pages
 
 

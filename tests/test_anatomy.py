@@ -208,12 +208,43 @@ def test_dispatch_record_table_and_flat_counters(an):
     assert rep["decode"]["ctx_tokens"] == 150
     assert an.counters() == {
         "prefill_dispatches": 1, "prefill_query_tokens": 150,
-        "prefill_token_slots": 256, "cold_dispatches": 0,
+        "prefill_token_slots": 256, "rpa_wide_tokens": 0,
+        "rpa_kv_page_reads": 0, "cold_dispatches": 0,
         "cold_seconds": 0.0}
     assert an.audit(prefill_tokens=150) == []
     with pytest.raises(ValueError):
         an.dispatch("warp", ("warp",), rows=0, row_slots=0, q_tokens=0,
                     prompt_tokens=0, q_slots=0, ctx_tokens=0, cold=False)
+
+
+def test_span_walk_fields_sum_and_audit(an):
+    """A span dispatch's ``wide_tokens`` / ``kv_page_reads`` (what the
+    ragged span kernel will do with it) land under its key, sum into the
+    flat ``rpa_*`` counters, stay 0 on every other program, and the audit
+    holds ``wide_tokens <= q_tokens`` per key."""
+    with an.dispatch("rpa", ("rpa", 2048, 16), rows=2, row_slots=4,
+                     q_tokens=1300, prompt_tokens=1300, q_slots=2048,
+                     ctx_tokens=1024, cold=False, wide_tokens=1251,
+                     kv_page_reads=53):
+        pass
+    with an.dispatch("rpa", ("rpa", 16, 4), rows=2, row_slots=4,
+                     q_tokens=2, prompt_tokens=0, q_slots=16,
+                     ctx_tokens=600, cold=False, kv_page_reads=5):
+        pass
+    with an.dispatch("decode", ("decode", 4, 4), rows=3, row_slots=4,
+                     q_tokens=0, prompt_tokens=0, q_slots=16,
+                     ctx_tokens=150, cold=False):
+        pass
+    rep = an.report()["programs"]
+    assert rep["rpa"]["wide_tokens"] == 1251
+    assert rep["rpa"]["kv_page_reads"] == 58
+    assert rep["rpa"]["keys"]["rpa:16:4"]["wide_tokens"] == 0
+    assert rep["decode"]["kv_page_reads"] == 0
+    c = an.counters()
+    assert (c["rpa_wide_tokens"], c["rpa_kv_page_reads"]) == (1251, 58)
+    assert an.audit() == []
+    an._table[("rpa", ("rpa", 16, 4))]["wide_tokens"] = 3
+    assert any("wide_tokens 3 > q_tokens 2" in v for v in an.audit())
 
 
 def test_programs_and_buckets_window_off_a_snapshot(an):
@@ -510,6 +541,10 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
             assert rec["prompt_tokens"] <= rec["q_tokens"] or (
                 name not in PROMPT_PROGRAMS)
             assert rec["cold"] <= rec["dispatches"]
+            assert rec["wide_tokens"] <= rec["q_tokens"], (name, rec)
+            # only a span dispatch that runs the span KERNEL walks pages
+            assert (rec["kv_page_reads"] > 0) == (
+                path == "prefix_hit_spans" and name == "rpa"), (name, rec)
             for f in RECORD_FIELDS:
                 assert rec[f] == sum(k[f] for k in rec["keys"].values())
         if path == "prefix_hit_spans":
@@ -517,6 +552,9 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
             assert programs["rpa"]["ctx_tokens"] > 0
             assert programs["rpa"]["prompt_tokens"] > 0
         m1 = sched.metrics
+        for f in ("wide_tokens", "kv_page_reads"):
+            assert m1["rpa_" + f] - m0["rpa_" + f] == programs.get(
+                "rpa", {}).get(f, 0)
         prompt = [r for n, r in programs.items() if n in PROMPT_PROGRAMS]
         assert m1["prefill_dispatches"] - m0["prefill_dispatches"] == sum(
             r["dispatches"] for r in prompt)

@@ -32,23 +32,25 @@ from lmrs_tpu.config import EngineConfig, ModelConfig
 from lmrs_tpu.engine.api import GenerationRequest
 from lmrs_tpu.engine.jax_engine import JaxEngine
 from lmrs_tpu.ops.paged_attention import (
+    SPAN_QT,
     pack_spans,
     paged_decode_pallas_fused,
     paged_decode_pallas_multi,
     ragged_spans_pallas,
     ragged_spans_xla,
+    span_walk_counts,
 )
 
 # --------------------------------------------------------------- ops level
 
 
 def _span_fixture(seed, q_lens, h=8, kh=4, hd=128, ps=16, n_pages=32,
-                  width=3):
+                  width=3, floor=16):
     """Flat span buffers + per-row pools/tables.  Every flat row gets
     random q/k/v — including the alignment-padding rows — so parity also
     proves the padding is masked, not merely zero."""
     b = len(q_lens)
-    qs, total = pack_spans(np.asarray(q_lens, np.int32))
+    qs, total = pack_spans(np.asarray(q_lens, np.int32), floor=floor)
     rng = jax.random.split(jax.random.PRNGKey(seed), 5)
     qf = jax.random.normal(rng[0], (total, h, hd), jnp.float32)
     knf = jax.random.normal(rng[1], (total, kh, hd), jnp.float32)
@@ -208,6 +210,145 @@ def test_rpa_mixed_spans_int8_parity():
     upto = bases + np.asarray(q_lens)
     _assert_pool_parity(k_out, k_ref, tables, upto, ps)
     _assert_pool_parity(v_out, v_ref, tables, upto, ps)
+
+
+# the wide tile, shrunk for the interpreter through the kernel's own static
+# argument: W tokens a tile where the module constant is SPAN_QT_WIDE
+_W = 32
+_WIDE_CASES = {
+    # span lengths around the tile, behind a base that is not page-aligned
+    # (one flat-buffer size for all of them: one compile in the interpreter)
+    "len-1": dict(q_lens=[1], bases=[21]),
+    "len-W-1": dict(q_lens=[_W - 1], bases=[21]),
+    "len-W": dict(q_lens=[_W], bases=[21]),
+    "len-W+1": dict(q_lens=[_W + 1], bases=[21]),
+    "len-2W+5": dict(q_lens=[2 * _W + 5], bases=[21]),
+    "len-2W+5-fresh": dict(q_lens=[2 * _W + 5], bases=[0]),
+    "len-2W+5-nrep4": dict(q_lens=[2 * _W + 5], bases=[21], n_rep=4),
+    # a page larger than the tile (the 512-token pages of the 8B presets)
+    "len-2W+5-ps64": dict(q_lens=[2 * _W + 5], bases=[70], ps=64, width=3),
+    # decode rows, an inactive row, one long span and a one-tile span
+    "mixed": dict(q_lens=[1, 0, 2 * _W + 5, 1, _W], bases=[20, 0, 7, 0, 48]),
+    # the position cap cuts the second wide tile (positions 10..79, cap 64)
+    "max-pos": dict(q_lens=[70, 3], bases=[10, 62], max_pos=64),
+    "bf16": dict(q_lens=[1, 0, 2 * _W + 5, _W - 1], bases=[20, 0, 7, 3],
+                 pool="bf16"),
+    "int8": dict(q_lens=[1, 0, 2 * _W + 5, _W - 1], bases=[20, 0, 7, 3],
+                 ps=32, width=4, pool="int8"),
+    "int8-nrep4-max-pos": dict(q_lens=[2 * _W + 5, 2], bases=[40, 100],
+                               ps=32, width=4, n_rep=4, pool="int8",
+                               max_pos=96),
+}
+
+
+@pytest.mark.parametrize("case", list(_WIDE_CASES), ids=list(_WIDE_CASES))
+def test_rpa_wide_tile_parity(case):
+    """The wide query tile against ``ragged_spans_xla``: a span of at
+    least W tokens is walked W queries a page pass, a shorter one in
+    SPAN_QT tiles, and a dispatch mixes both.  In-span outputs agree,
+    pools agree over every row's valid prefix, and a wide row leaves its
+    whole page window as the reference has it: the wide path writes real
+    tokens below the position cap only, no padding garbage."""
+    c = dict(ps=16, width=8, n_rep=2, pool="f32", max_pos=None)
+    c.update(_WIDE_CASES[case])
+    q_lens, ps, width = c["q_lens"], c["ps"], c["width"]
+    bases = np.asarray(c["bases"], np.int32)
+    b, kh, hd = len(q_lens), 2, 128
+    n_pages = 1 + b * width
+    qs, total, qf, knf, vnf, kp, vp, _, row_flat = _span_fixture(
+        11, q_lens, h=kh * c["n_rep"], kh=kh, hd=hd, ps=ps,
+        n_pages=n_pages, width=width,
+        floor=2 * _W + 8)  # (under _W the wide path would not compile in)
+    rng = np.random.default_rng(11)
+    tables = jnp.asarray(
+        rng.permutation(n_pages - 1).reshape(b, width) + 1, jnp.int32)
+    kw, xkw, tol = {}, {}, 2e-5
+    ref_q, ref_pools = qf, None
+    if c["pool"] == "bf16":
+        # the engine's own dtypes: bf16 q and new K/V against a bf16 pool
+        qf, knf, vnf, kp, vp = (x.astype(jnp.bfloat16)
+                                for x in (qf, knf, vnf, kp, vp))
+        # attention reference in f32 over the same bf16 numbers (the XLA
+        # twin rounds its bf16 logits; the kernel keeps them f32)
+        ref_q, tol = qf.astype(jnp.float32), 1e-2
+        ref_pools = (kp.astype(jnp.float32), vp.astype(jnp.float32))
+    elif c["pool"] == "int8":
+        kp = jnp.asarray(rng.integers(-127, 128, kp.shape), jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, vp.shape), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.01, 0.05, (b, kh, hd)), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.01, 0.05, (b, kh, hd)), jnp.float32)
+        kw, xkw = dict(kscale=ks, vscale=vs), dict(kv_scales=(ks, vs))
+    spans = (jnp.asarray(bases), jnp.asarray(qs),
+             jnp.asarray(q_lens, jnp.int32))
+
+    got, k_out, v_out = ragged_spans_pallas(
+        qf, knf, vnf, kp, vp, tables, *spans, interpret=True,
+        max_pos=c["max_pos"], qt_wide=_W, **kw)
+    want, k_ref, v_ref = ragged_spans_xla(
+        qf, knf, vnf, kp, vp, tables, *spans, jnp.asarray(row_flat),
+        max_pos=c["max_pos"], **xkw)
+    if ref_pools is not None:
+        want, _, _ = ragged_spans_xla(
+            ref_q, knf.astype(jnp.float32), vnf.astype(jnp.float32),
+            *ref_pools, tables, *spans, jnp.asarray(row_flat),
+            max_pos=c["max_pos"])
+
+    in_span = row_flat < b
+    np.testing.assert_allclose(
+        np.asarray(got.astype(jnp.float32))[in_span],
+        np.asarray(want.astype(jnp.float32))[in_span], rtol=tol, atol=tol)
+    cap = width * ps if c["max_pos"] is None else min(width * ps,
+                                                      c["max_pos"])
+    # narrow rows: their valid prefix; wide rows: the whole window
+    upto = np.where(np.asarray(q_lens) >= _W, width * ps,
+                    np.minimum(bases + np.asarray(q_lens), cap))
+    _assert_pool_parity(k_out, k_ref, tables, upto, ps)
+    _assert_pool_parity(v_out, v_ref, tables, upto, ps)
+
+
+def _brute_walk_counts(q_lens, bases, ps, w_pages, max_pos, wide):
+    """``span_walk_counts`` the slow way: every tile of every row, one at
+    a time, by the kernel's rule as its docstring states it."""
+    cap = w_pages * ps if max_pos is None else min(w_pages * ps, max_pos)
+    wide_tokens = reads = 0
+    for ql, base in zip(q_lens, bases):
+        if ql >= wide:
+            wide_tokens += ql
+            ql8 = -(-ql // SPAN_QT) * SPAN_QT
+            for i in range(-(-ql8 // wide)):
+                t0 = min(i * wide, ql8 - wide)
+                end = min(base + t0 + min(wide, ql - t0), cap)
+                reads += len(range(0, end, ps))
+        else:
+            for i in range(-(-ql // SPAN_QT)):
+                reads += min(len(range(0, base + (i + 1) * SPAN_QT, ps)),
+                             w_pages)
+    return wide_tokens, reads
+
+
+@pytest.mark.parametrize("wide,ps,w_pages,max_pos", [
+    (256, 128, 16, 2048), (128, 128, 16, None), (32, 16, 8, 100)])
+def test_span_walk_counts_match_brute_force(wide, ps, w_pages, max_pos):
+    """The host rule behind the dispatch record's ``wide_tokens`` /
+    ``kv_page_reads`` against a tile-by-tile count over the same spans,
+    and the cell's own arithmetic: a 1,251-token span behind 512 cached
+    tokens is 5 wide tiles walking 6+8+10+12+14 pages where 8-token tiles
+    walked about 1,480."""
+    rng = np.random.default_rng(wide)
+    span = w_pages * ps
+    for _ in range(20):
+        q_lens = rng.integers(0, span // 2, 12)
+        q_lens[rng.integers(0, 12, 3)] = [0, 1, wide]
+        bases = rng.integers(0, span // 2, 12)
+        got = span_walk_counts(q_lens, bases, ps, w_pages, max_pos,
+                               qt_wide=wide)
+        assert got == _brute_walk_counts(
+            q_lens.tolist(), bases.tolist(), ps, w_pages, max_pos, wide)
+        assert got[0] <= q_lens.sum()
+    assert span_walk_counts([1251], [512], 128, 16, 2048,
+                            qt_wide=256) == (1251, 50)
+    narrow = span_walk_counts([1251], [512], 128, 16, 2048, qt_wide=4096)
+    assert narrow[0] == 0 and 1400 < narrow[1] < 1500
 
 
 # --------------------------------------------------------- scheduler level

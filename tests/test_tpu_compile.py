@@ -153,17 +153,28 @@ def test_multi_token_verify_compiles(one_chip, shape, pool_dtype):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("ps,pool_dtype", [
-    pytest.param(512, jnp.bfloat16, id="bf16-ps512"),
-    pytest.param(512, jnp.int8, id="int8-ps512"),
-    pytest.param(128, jnp.int8, id="int8-ps128"),
-])
-@pytest.mark.parametrize("shape", SHAPES)
-def test_ragged_spans_compile(one_chip, shape, ps, pool_dtype):
+# (heads, kv heads, head_dim), flat tokens, rows, table pages, page size, pool
+RAGGED_SPAN_CASES = [
+    pytest.param(shape, 512, 24, 2048 // ps, ps, dt, id=f"{sid}-{did}")
+    for shape, sid in [(LLAMA8B, "llama3-8b"), (GEMMA2B, "gemma-2b")]
+    for ps, dt, did in [(512, jnp.bfloat16, "bf16-ps512"),
+                        (512, jnp.int8, "int8-ps512"),
+                        (128, jnp.int8, "int8-ps128")]
+] + [
+    # internlm2-offline's own span program (``rpa:32768:16``) and its int8
+    # twin at 32/8 heads: the wide tile's VMEM shows here, not in a window
+    pytest.param((16, 8, 128), 32768, 24, 16, 128, jnp.bfloat16,
+                 id="internlm2-offline-bf16"),
+    pytest.param((32, 8, 128), 32768, 24, 16, 128, jnp.int8,
+                 id="cell-shape-32h-int8"),
+]
+
+
+@pytest.mark.parametrize("shape,tp,b,w,ps,pool_dtype", RAGGED_SPAN_CASES)
+def test_ragged_spans_compile(one_chip, shape, tp, b, w, ps, pool_dtype):
     from lmrs_tpu.ops.paged_attention import ragged_spans_pallas
 
     h, k, hd = shape
-    b, tp, w = 24, 512, 2048 // ps
     bf = jnp.bfloat16
     shapes = [((tp, h, hd), bf), ((tp, k, hd), bf), ((tp, k, hd), bf),
               ((1 + b * w, k, ps, hd), pool_dtype),
@@ -175,7 +186,7 @@ def test_ragged_spans_compile(one_chip, shape, ps, pool_dtype):
 
     def fn(q, kn, vn, kp, vp, tables, lens, qs, ql, ks=None, vs=None):
         return ragged_spans_pallas(q, kn, vn, kp, vp, tables, lens, qs, ql,
-                                   kscale=ks, vscale=vs)
+                                   kscale=ks, vscale=vs, max_pos=w * ps)
 
     _, hlo = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in hlo
