@@ -1,5 +1,5 @@
-"""The copied FLOP and byte functions agree with lmrs_tpu/utils/perf_model.py
-on both configurations today.
+"""The copied FLOP and byte functions, as the dense family hands them to the
+readers, agree with lmrs_tpu/utils/perf_model.py on both configurations today.
 
     JAX_PLATFORMS=cpu python benchmarks/checks/check_flops.py
 """
@@ -14,17 +14,14 @@ sys.path[:0] = [str(HERE.parent), str(HERE)]
 
 
 def main() -> int:
-    import flops
     import run as bench_run
     from lmrs_tpu.utils import perf_model as pm
 
     bad = 0
     for name in ("mistral-7b-v0.3", "internlm2-1.8b"):
-        m = bench_run.model_sizes(
-            bench_run.read_json(HERE / "configs" / f"{name}.json"))
-        cfg, _, _ = bench_run.make_configs(name, m, {
-            "max_seq_len": 2048, "max_tokens": 128, "max_batch_slots": 24,
-            "num_pages": 1, "prefill_chunk": 4096, "decode_block": 128})
+        flops, m = bench_run.families.of_config(
+            HERE / "configs" / f"{name}.json")
+        cfg = flops.model_config(name, m, {"max_seq_len": 2048})
         kvq, wq = m["kv"] == "int8", m["weights"] == "int8"
         pairs = {
             "matmul_params": (flops.matmul_params(m), pm.matmul_params(cfg)),

@@ -15,7 +15,8 @@ last line has to say ``correct: false``:
   under the limit (at this size int8 weights read 0.01-0.11 against the
   engine's 0.005-0.02, too close to stand as the control);
 * the timed path broken underneath: one served token altered where the
-  engine hands its results over; the norm weights the engine is given
+  engine hands its results over, in every request and in one slot's alone;
+  the norm weights the engine is given
   zeroed (a norm weight that is skipped); the int8 scales it is given
   rolled by one output channel (a scale taken from another channel), on the
   sharded int8 rehearsal configuration.
@@ -65,9 +66,11 @@ def test_control_comes_out_not_correct_and_the_engine_correct():
         assert ctrl["correct"] is False, ctrl["checks"]
 
 
-def _alter_one_token(monkeypatch_target):
+def _alter_one_token(monkeypatch_target, first_only: bool = False):
     """Wrap JaxEngine.generate_batch: the third served token of every
-    request comes out as another id, in the result and in the stream."""
+    request (``first_only``: of the first request of each call alone, one
+    slot among the batch's) comes out as another id, in the result and in
+    the stream."""
     from dataclasses import replace
 
     from tokenizer import ID_BASE
@@ -79,10 +82,14 @@ def _alter_one_token(monkeypatch_target):
 
     def broken(self, requests, on_result=None, on_tokens=None):
         seen: dict[int, int] = {}
+        only = requests[0].request_id if first_only else None
+
+        def hit(rid) -> bool:
+            return only is None or rid == only
 
         def tokens(rid, delta):
             at = seen.get(rid, 0)
-            if at <= 2 < at + len(delta):
+            if at <= 2 < at + len(delta) and hit(rid):
                 i = 2 - at
                 delta = delta[:i] + other(delta[i]) + delta[i + 1:]
             seen[rid] = at + len(delta)
@@ -94,7 +101,7 @@ def _alter_one_token(monkeypatch_target):
         def alter(res):
             t = res.text
             return replace(res, text=t[:2] + other(t[2]) + t[3:]) \
-                if len(t) > 2 else res
+                if len(t) > 2 and hit(res.request_id) else res
 
         kw = {}
         if on_tokens is not None:
@@ -142,15 +149,17 @@ def _scales_rolled(params):
     return jax.tree_util.tree_map_with_path(roll, params)
 
 
-def _alter_tokens():
+def _alter_tokens(first_only: bool = False):
     from lmrs_tpu.engine.jax_engine import JaxEngine
 
-    real = _alter_one_token(JaxEngine)
+    real = _alter_one_token(JaxEngine, first_only)
     return lambda: setattr(JaxEngine, "generate_batch", real)
 
 
 FAULTS = {
     "token_altered": ("tiny.offline-jobs", _alter_tokens),
+    "token_altered_in_one_slot": ("tiny.offline-jobs",
+                                  lambda: _alter_tokens(first_only=True)),
     "norm_weight_skipped": ("tiny.offline-jobs",
                             lambda: _with_params(_norms_skipped)),
     "int8_scale_of_another_channel": ("tiny-tp2.offline-jobs",
@@ -178,6 +187,10 @@ def test_altered_token_fails_the_run():
     _broken_run("token_altered")
 
 
+def test_token_altered_in_one_slot_fails_the_run():
+    _broken_run("token_altered_in_one_slot")
+
+
 def test_skipped_norm_weight_fails_the_run():
     _broken_run("norm_weight_skipped")
 
@@ -189,6 +202,7 @@ def test_scale_of_another_channel_fails_the_sharded_int8_run():
 if __name__ == "__main__":
     test_control_comes_out_not_correct_and_the_engine_correct()
     test_altered_token_fails_the_run()
+    test_token_altered_in_one_slot_fails_the_run()
     test_skipped_norm_weight_fails_the_run()
     test_scale_of_another_channel_fails_the_sharded_int8_run()
     print("test_checks: passed")

@@ -2,9 +2,11 @@
 
     python benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Everything that belongs to one configuration, one traffic mix, one per-layer
-metric or one cell's limits is a file found by name (README.md): the harness
-below knows none of them.  It builds the engine from the configuration file
+Everything that belongs to one configuration, one model family, one traffic
+mix, one per-layer metric or one cell's limits is a file found by name
+(README.md): the harness below knows none of them.  It builds the engine from
+the configuration file, its family (families/__init__.py: the sizes, the
+program's model configuration, the weights, the plain reference, the counts)
 and the traffic file, makes the weights on the device from the seed, lets
 the traffic's generator warm up and drive the window through the product's
 entry, reads the peak memory, frees the engine's pool, and only then runs
@@ -19,7 +21,6 @@ T_PROCESS = time.time()  # set-up is counted from here
 
 import argparse
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -30,6 +31,12 @@ from types import SimpleNamespace
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
+if str(HERE) not in sys.path:
+    sys.path.insert(0, str(HERE))
+
+import families  # noqa: E402  (benchmarks/families/: found beside this file)
+from families import load_module  # noqa: E402,F401  (the checks take it from here)
+
 EXIT_NO_CHIP = 3
 EXIT_BAD_TREE = 4
 
@@ -39,42 +46,8 @@ def log(msg: str) -> None:
           flush=True)
 
 
-def load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[name] = mod
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def read_json(path: Path) -> dict:
     return json.loads(path.read_text())
-
-
-def model_sizes(cfg: dict) -> dict:
-    """The sizes the harness, the weights, the reference and the FLOP
-    functions read, from a configuration file's published keys."""
-    heads = cfg["num_attention_heads"]
-    return {
-        "dim": cfg["hidden_size"],
-        "n_layers": cfg["num_hidden_layers"],
-        "n_heads": heads,
-        "n_kv_heads": cfg["num_key_value_heads"],
-        "head_dim": cfg.get("head_dim") or cfg["hidden_size"] // heads,
-        "hidden_dim": cfg["intermediate_size"],
-        "vocab_size": cfg["vocab_size"],
-        "rope_theta": float(cfg["rope_theta"]),
-        "norm_eps": float(cfg["rms_norm_eps"]),
-        "tie": bool(cfg.get("tie_word_embeddings", False)),
-        "weights": cfg["engine"]["weights"],
-        "kv": cfg["engine"]["kv"],
-        "page_size": cfg["engine"]["page_size"],
-        # optional: fields of the program's EngineConfig that belong to the
-        # configuration (prefix_cache, host_kv, kv_disk, scheduler ...), and
-        # the mesh a sharded configuration runs on ({"tp": 4})
-        "settings": dict(cfg["engine"].get("settings", {})),
-        "mesh": dict(cfg["engine"].get("mesh", {})),
-    }
 
 
 def find_cell(name: str) -> tuple[dict, dict, bool]:
@@ -150,24 +123,19 @@ class CompileCounter:
         return self.requests, self.hits
 
 
-def make_configs(name: str, m: dict, eng: dict, quantize=None):
-    """The program's ModelConfig, EngineConfig and MeshConfig (None on one
-    chip) from a configuration's sizes (model_sizes) and a traffic file's
-    ``engine`` geometry.  ``quantize`` switches the program's own
-    lower-precision weight path on: only the control does that."""
-    from lmrs_tpu.config import EngineConfig, MeshConfig, ModelConfig
+def make_configs(fam, name: str, m: dict, eng: dict, quantize=None):
+    """The program's ModelConfig (the family's), EngineConfig and MeshConfig
+    (None on one chip) from a configuration's sizes (the family's ``sizes``)
+    and a traffic file's ``engine`` geometry.  ``quantize`` switches the
+    program's own lower-precision weight path on: only the control does
+    that."""
+    from lmrs_tpu.config import EngineConfig, MeshConfig
 
-    model_cfg = ModelConfig(
-        name=name, vocab_size=m["vocab_size"], dim=m["dim"],
-        n_layers=m["n_layers"], n_heads=m["n_heads"],
-        n_kv_heads=m["n_kv_heads"], hidden_dim=m["hidden_dim"],
-        max_seq_len=eng["max_seq_len"], rope_theta=m["rope_theta"],
-        norm_eps=m["norm_eps"], tie_embeddings=m["tie"], dtype="bfloat16",
-        head_dim=m["head_dim"])
+    model_cfg = fam.model_config(name, m, eng)
     settings = {
         "scheduler": "continuous", "prefix_cache": True, "host_kv": True,
         "kv_disk": False,
-        # weights arrive already in their served type (weights.py): the
+        # the family's weights arrive already in their served type: the
         # engine must not quantise them again
         "quantize": quantize,
         "kv_quantize": "int8" if m["kv"] == "int8" else None,
@@ -183,37 +151,23 @@ def make_configs(name: str, m: dict, eng: dict, quantize=None):
     return model_cfg, engine_cfg, mesh_cfg
 
 
-def weight_shardings(ctx):
-    """Where each leaf of the weights goes on the configuration's mesh: the
-    program's own layout (parallel/sharding.py), so the engine finds every
-    shard where it would have put it.  None on one chip."""
-    if ctx.mesh_cfg is None:
-        return None
-    from lmrs_tpu.ops.quant import match_quantized_specs
-    from lmrs_tpu.parallel.mesh import build_mesh
-    from lmrs_tpu.parallel.sharding import param_specs, specs_to_shardings
-
-    mesh = build_mesh(ctx.mesh_cfg)
-    specs = match_quantized_specs(
-        param_specs(ctx.model["tie"], False),
-        ctx.weights_mod.param_shapes(ctx.model))
-    return specs_to_shardings(specs, mesh)
-
-
 def build_engine(ctx, quantize=None):
     """Weights on the device(s) from the seed, and the engine around them."""
     import jax
 
     from lmrs_tpu.engine.jax_engine import JaxEngine
 
-    m, eng = ctx.model, ctx.traffic["engine"]
+    m, eng, fam = ctx.model, ctx.traffic["engine"], ctx.family
     ctx.model_cfg, ctx.engine_cfg, ctx.mesh_cfg = make_configs(
-        ctx.cell["config"], m, eng, quantize)
+        fam, ctx.cell["config"], m, eng, quantize)
     t0 = time.time()
-    ctx.params = ctx.weights_mod.make_params(m, ctx.seed,
-                                             weight_shardings(ctx))
+    # on a mesh every leaf is drawn where the program's own layout puts it;
+    # on one chip there is nothing to place
+    placed = (families.shardings(fam, m, ctx.mesh_cfg)
+              if ctx.mesh_cfg is not None else None)
+    ctx.params = fam.make_params(m, ctx.seed, placed)
     jax.block_until_ready(ctx.params)
-    log(f"weights on device: {ctx.weights_mod.weight_bytes(ctx.params) / 1e9:.2f} GB "
+    log(f"weights on device: {fam.weight_bytes(ctx.params) / 1e9:.2f} GB "
         f"in {time.time() - t0:.1f}s")
     t0 = time.time()
     ctx.engine = JaxEngine(ctx.engine_cfg, ctx.model_cfg, ctx.mesh_cfg,
@@ -225,11 +179,10 @@ def build_engine(ctx, quantize=None):
 
 
 def free_engine(ctx) -> None:
-    """Shut the engine down and free its page pool now; the weights stay,
-    they are the benchmark's and the reference reads them."""
+    """Shut the engine down and free its cache now; the weights stay, they
+    are the benchmark's and the reference reads them."""
     ctx.engine.shutdown()
-    sched = ctx.sched
-    for buf in (sched.cache.k, sched.cache.v, sched.kscale, sched.vscale):
+    for buf in ctx.family.cache_buffers(ctx.sched):
         if buf is not None:
             buf.delete()
     ctx.engine._scheduler = ctx.engine._runner = None
@@ -258,8 +211,9 @@ def _flipped_msq(g) -> float:
 
 
 GAP_STATS = {
-    # the numbers a limits file may hold a limit for, each over the gaps
-    # of every served token of the sample
+    # the numbers a limits file may hold a limit for, each over the gaps of
+    # every compared token of the sample; a family may bring more, or its own
+    # reading of these names (families/__init__.py, ``gap_stats``)
     "logit_gap_max": lambda g: float(g.max()),
     "flipped_gap_msq": _flipped_msq,
 }
@@ -272,41 +226,50 @@ def decide_correct(ctx, window: dict, control: str | None = None,
     ``gaps`` every served token's gap, request by request.  With
     ``control`` (int8, fp8, int4) the reference at that precision stands in
     the program's place: at every position of the same prompts and tokens
-    the gap is that of the token the lower precision puts first."""
+    the gap is that of the token the lower precision puts first.  What is
+    compared is the family's: a token whose gap it gives as NaN is left out
+    (by a rule on the reference alone, which the family states), and its
+    ``gap_stats``, where it has one, joins or replaces ``GAP_STATS``."""
     import numpy as np
 
+    own_stats = getattr(ctx.family, "gap_stats", None)
+    stats = {**GAP_STATS, **(own_stats(ctx.model) if own_stats else {})}
     limits = read_json(HERE / "limits" / f"{ctx.limits_name}.json")
     ctx.traffic["sample_requests"] = sample_requests or limits.get(
         "sample_requests", ctx.traffic["sample_requests"])
     sample = ctx.gen.sample(ctx, window)
     t0 = time.time()
-    per_request, mismatched = [], 0
+    per_request, mismatched, left_out = [], 0, 0
     for s in sample:
         if len(s.prompt_ids) != s.reported_prompt_tokens:
             mismatched += 1
         if not s.served_ids:
             continue
         if control is None:
-            gaps = ctx.reference.served_gap(ctx.params, ctx.model,
-                                            s.prompt_ids, s.served_ids)
+            gaps = ctx.family.served_gap(ctx.params, ctx.model,
+                                         s.prompt_ids, s.served_ids)
         else:
-            gaps = ctx.reference.control_gap(ctx.params, ctx.model,
-                                             s.prompt_ids, s.served_ids,
-                                             control)
+            gaps = ctx.family.control_gap(ctx.params, ctx.model,
+                                          s.prompt_ids, s.served_ids, control)
+        gaps = np.asarray(gaps)
+        left_out += int(np.isnan(gaps).sum())
+        gaps = gaps[~np.isnan(gaps)]
         per_request.append(gaps)
         log(f"reference: request of {len(s.prompt_ids)} + {len(s.served_ids)} "
-            f"tokens ({s.kind}): widest gap {float(np.max(gaps)):.4f}, "
+            f"tokens ({s.kind}): widest gap {float(gaps.max(initial=0)):.4f}, "
             f"greedy-equal {int((gaps == 0).sum())}/{len(gaps)}")
-    all_gaps = (np.concatenate(per_request) if per_request
-                else np.zeros((1,), np.float32))
     n_tok = sum(len(g) for g in per_request)
+    all_gaps = (np.concatenate(per_request) if n_tok
+                else np.zeros((1,), np.float32))
     log(f"reference{' (control ' + control + ')' if control else ''} over "
-        f"{len(sample)} requests, {n_tok} served tokens, in "
-        f"{time.time() - t0:.1f}s; not the reference's own choice: "
+        f"{len(sample)} requests, {n_tok} served tokens"
+        + (f" ({left_out} more left out by the family's rule)" if left_out
+           else "")
+        + f", in {time.time() - t0:.1f}s; not the reference's own choice: "
         f"{int((all_gaps > 0).sum())} tokens; "
-        + ", ".join(f"{k} {fn(all_gaps):.6f}" for k, fn in GAP_STATS.items()))
+        + ", ".join(f"{k} {fn(all_gaps):.6f}" for k, fn in stats.items()))
     checks = {k: {"value": fn(all_gaps), "limit": limits[k]}
-              for k, fn in GAP_STATS.items() if k in limits}
+              for k, fn in stats.items() if k in limits}
     if not checks:
         raise SystemExit(f"limits/{ctx.limits_name}.json holds no limit")
     checks.update({
@@ -337,7 +300,7 @@ def open_cell(workload: str, seed: int, seconds: float, trace: bool):
         print("benchmarks/run.py: no lmrs_tpu/ beside benchmarks/: this is "
               "not a checkout of the system under test", file=sys.stderr)
         return EXIT_BAD_TREE
-    for d in (HERE / "layer_metrics", HERE, ROOT):
+    for d in (HERE / "layer_metrics", ROOT):
         if str(d) not in sys.path:
             sys.path.insert(0, str(d))
     cell, bench, rehearsal = find_cell(workload)
@@ -378,7 +341,7 @@ def open_cell(workload: str, seed: int, seconds: float, trace: bool):
                       if c["name"] == cell["config"]), None)
     cfg_file = (ROOT / cfg_entry["file"] if cfg_entry
                 else HERE / "configs" / f"{cell['config']}.json")
-    config = read_json(cfg_file)
+    family, model = families.of_config(cfg_file)
     traffic = read_json(HERE / "traffic" / f"{cell['traffic']}.json")
     if rehearsal and "rehearsal" in traffic:
         # the tiny shapes of the CPU rehearsal, kept beside the real ones
@@ -387,17 +350,14 @@ def open_cell(workload: str, seed: int, seconds: float, trace: bool):
                 traffic[k] = {**traffic[k], **v}
             else:
                 traffic[k] = v
-    model = model_sizes(config)
     tok_mod = load_module(HERE / "tokenizer.py", "bench_tokenizer")
     ctx = SimpleNamespace(
         cell=cell, bench=bench, rehearsal=rehearsal,
         seed=seed, seconds=seconds, trace=trace,
-        model=model, traffic=traffic, device=device, on_chip=on_chip,
+        family=family, model=model, traffic=traffic, device=device,
+        on_chip=on_chip,
         peaks=peaks_tab.get(device["kind"]), log=log, tok_mod=tok_mod,
         tok=tok_mod.IdTokenizer(model["vocab_size"]),
-        weights_mod=load_module(HERE / "weights.py", "bench_weights"),
-        reference=load_module(HERE / "reference.py", "bench_reference"),
-        flops=load_module(HERE / "flops.py", "bench_flops"),
         Recorder=Recorder,
         facts={})
     ctx.limits_name = cell.get("limits", cell["name"])
@@ -514,7 +474,7 @@ def main(argv=None) -> int:
         facts = ctx.facts
         facts.update(model=model, peaks=ctx.peaks, window_s=window_s,
                      counters=delta, anatomy=marks["anatomy"],
-                     report=marks["report"], window=window, flops=ctx.flops,
+                     report=marks["report"], window=window, flops=ctx.family,
                      slots=traffic["engine"]["max_batch_slots"])
         if on_chip:
             reduce_mod = load_module(HERE / "trace_reduce.py",
