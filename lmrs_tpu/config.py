@@ -91,11 +91,81 @@ class ModelConfig:
     n_experts_per_token: int = 2
     expert_capacity_factor: float = 1.25
     router_aux_coef: float = 0.01  # load-balance loss weight in training
+    # What ``LatentModelConfig`` (below) adds, at the values a dense model
+    # has: plain class attributes, NOT dataclass fields, so that a dense
+    # ``ModelConfig`` is field for field what it was (its ``asdict``, its
+    # ``replace``), and the static ``if cfg.kv_lora_rank`` / ``if
+    # cfg.n_routed_experts`` branches read 0 on it.
+    q_lora_rank = kv_lora_rank = 0
+    qk_nope_head_dim = qk_rope_head_dim = v_head_dim = 0
+    rope_factor, rope_orig_max_pos = 1.0, 0
+    rope_beta_fast, rope_beta_slow = 32.0, 1.0
+    rope_mscale, rope_mscale_all_dim = 1.0, 0.0
+    n_routed_experts = n_shared_experts = 0
+    routed_scaling_factor = 1.0
+    expert_first = n_experts_held = 0
+    n_dense_layers = dense_hidden_dim = 0
 
     @property
     def hd(self) -> int:
-        """Per-head dimension; ``head_dim`` overrides the dim/n_heads default."""
+        """Per-head dimension; ``head_dim`` overrides the dim/n_heads default.
+        Under latent attention: a head's query/key width."""
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of one latent cache row: latent + rotary values, padded
+        to a multiple of 128 (the pad stays zero)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def n_routed_layers(self) -> int:
+        return (self.n_layers - self.n_dense_layers
+                if self.n_routed_experts else 0)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_routed_experts
+
+
+@dataclass
+class LatentModelConfig(ModelConfig):
+    """A latent-attention model over routed experts (models/latent.py): the
+    DeepSeek-V3 block.  ``hidden_dim`` is one expert's width and
+    ``n_experts_per_token`` the experts a token chooses."""
+
+    # Latent attention (MLA, models/latent.py): ``kv_lora_rank`` > 0 selects
+    # it.  A head's queries and keys are ``qk_nope_head_dim`` values from
+    # the latent plus ``qk_rope_head_dim`` rotary ones shared by all heads;
+    # the cache holds one [kv_lora_rank + qk_rope_head_dim] row a token.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (ops/rope.py); factor 1 is plain rope
+    rope_factor: float = 1.0
+    rope_orig_max_pos: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # Routed experts behind a sigmoid router with a selection bias
+    # (ops/moe.routed_experts; ``hidden_dim`` is one expert's width,
+    # ``n_experts_per_token`` the experts chosen): the router is
+    # ``n_routed_experts`` wide, this engine holds ``n_experts_held`` of them
+    # (0: all) from ``expert_first`` on and computes their part of a layer.
+    # The first ``n_dense_layers`` layers keep a dense FFN of
+    # ``dense_hidden_dim``.
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    expert_first: int = 0
+    n_experts_held: int = 0
+    n_dense_layers: int = 0
+    dense_hidden_dim: int = 0
 
 
 @dataclass
@@ -537,6 +607,33 @@ def model_preset(name: str) -> ModelConfig:
             vocab_size=512, dim=128, n_layers=2, n_heads=4, n_kv_heads=2,
             hidden_dim=256, max_seq_len=2048,
         ),
+        "kimi-k2.6": dict(
+            # moonshotai/Kimi-K2.6 config.json (model_type kimi_k2, the
+            # DeepSeek-V3 block) as published: 1.04T parameters, no chip
+            # holds it; benchmarks/configs/kimi-k2.6.json is one chip's
+            # share of it (fewer layers, 12 experts held, a vocabulary slice)
+            vocab_size=163840, dim=7168, n_layers=61, n_heads=64,
+            n_kv_heads=64, hidden_dim=2048, dense_hidden_dim=18432,
+            max_seq_len=8192, rope_theta=50000.0, tie_embeddings=False,
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, rope_factor=64.0,
+            rope_orig_max_pos=4096, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+            n_dense_layers=1, n_routed_experts=384, n_shared_experts=1,
+            n_experts_per_token=8, routed_scaling_factor=2.827,
+        ),
+        "tiny-latent": dict(
+            # the same block at test size: one dense layer, two routed
+            # layers that hold experts 4-7 of 16; the latent is 128 wide so
+            # that the decode kernel's lane-aligned slices hold
+            vocab_size=512, dim=64, n_layers=3, n_heads=4, n_kv_heads=4,
+            hidden_dim=32, dense_hidden_dim=96, max_seq_len=256,
+            rope_theta=10000.0, tie_embeddings=False, q_lora_rank=48,
+            kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=16,
+            v_head_dim=32, rope_factor=4.0, rope_orig_max_pos=64,
+            rope_mscale=1.0, rope_mscale_all_dim=1.0, n_dense_layers=1,
+            n_routed_experts=16, n_shared_experts=1, n_experts_per_token=4,
+            routed_scaling_factor=2.5, expert_first=4, n_experts_held=4,
+        ),
         "mixtral-8x7b": dict(
             vocab_size=32000, dim=4096, n_layers=32, n_heads=32, n_kv_heads=8,
             hidden_dim=14336, max_seq_len=8192, rope_theta=1e6,
@@ -545,4 +642,5 @@ def model_preset(name: str) -> ModelConfig:
     }
     if name not in presets:
         raise ValueError(f"unknown model preset {name!r}; have {sorted(presets)}")
-    return ModelConfig(name=name, **presets[name])
+    cls = LatentModelConfig if "kv_lora_rank" in presets[name] else ModelConfig
+    return cls(name=name, **presets[name])

@@ -142,6 +142,12 @@ class JaxEngine:
             from lmrs_tpu.parallel.mesh import build_mesh
 
             self._mesh = build_mesh(mesh_cfg, devices)
+        if model_cfg.kv_lora_rank and engine_cfg.scheduler == "continuous":
+            # before any weight is placed: what a latent cache cannot be
+            # combined with is refused by name (scheduler.py)
+            from lmrs_tpu.engine.scheduler import ContinuousScheduler
+
+            ContinuousScheduler._refuse_for_latent(engine_cfg, self._mesh)
         key = jax.random.PRNGKey(engine_cfg.seed)
         t0 = time.time()
         quantized = False

@@ -150,6 +150,11 @@ class PagedKVCache:
     step (models/transformer.forward_paged).  A slot's logical KV position
     maps to (page_table[pos // ps], pos % ps); tables hold LOGICAL page ids
     (< P) and are globalized per layer inside the forward.
+
+    Under latent attention (``ModelConfig.kv_lora_rank``) there is ONE pool:
+    ``k`` is [L*P, 1, page_size, latent_width], a token's row the normed
+    latent beside the shared rotary key (models/latent.py), and ``v`` is
+    None; every program carries the pair through as it carries K and V.
     """
 
     def __init__(self, model_cfg: ModelConfig, num_pages: int, page_size: int,
@@ -165,7 +170,22 @@ class PagedKVCache:
         dt = jnp.dtype(kv_dtype) if kv_dtype else jnp.dtype(model_cfg.dtype)
         shape = (model_cfg.n_layers * num_pages, model_cfg.n_kv_heads,
                  page_size, hd)
-        if mesh is not None:
+        self.latent = bool(model_cfg.kv_lora_rank)
+        if self.latent:
+            if kv_dtype or (mesh is not None and mesh.devices.size > 1):
+                raise ValueError(
+                    "latent KV cache (kv_lora_rank > 0): no int8 pages and "
+                    "no mesh of several devices (the latent row has no "
+                    "per-head scales and no kv-head axis to shard)")
+            shape = (model_cfg.n_layers * num_pages, 1, page_size,
+                     model_cfg.latent_width)
+            pinned = None
+            if mesh is not None:  # a replica's one device
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                pinned = NamedSharding(mesh, P())
+            self.k, self.v = jnp.zeros(shape, dt, device=pinned), None
+        elif mesh is not None:
             # tensor-parallel serving: pages shard on the kv-head axis,
             # matching the wk/wv head sharding — each shard's attention and
             # page writes stay local, no cross-chip KV traffic.  tp=1 still
@@ -194,7 +214,7 @@ class PagedKVCache:
         logger.info(
             "paged KV cache: %d pages x %d tokens (%.1f MiB)",
             num_pages, page_size,
-            2 * np.prod(shape) * dt.itemsize / 2**20,
+            (1 if self.latent else 2) * np.prod(shape) * dt.itemsize / 2**20,
         )
 
     def reallocate(self) -> None:
@@ -203,7 +223,16 @@ class PagedKVCache:
         old buffers may already be consumed, leaving self.k/v unusable.
         Only valid while no sequence is live (content is discarded)."""
         self.k = jnp.zeros(self.k.shape, self.k.dtype, device=self.k.sharding)
-        self.v = jnp.zeros(self.v.shape, self.v.dtype, device=self.v.sharding)
+        if self.v is not None:
+            self.v = jnp.zeros(self.v.shape, self.v.dtype,
+                               device=self.v.sharding)
+
+    def _no_latent(self, op: str) -> None:
+        if self.latent:
+            raise NotImplementedError(
+                f"latent KV cache: {op} (page export/import: prefix-cache "
+                "spill, handoff, migration) is not built for the one-pool "
+                "layout")
 
     def pages_needed(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
@@ -281,6 +310,7 @@ class PagedKVCache:
         separately).  The sequence itself is untouched: the caller keeps
         the pages pinned until the importer acks (scheduler pin class).
         """
+        self._no_latent("export_sequence")
         faults.fire("handoff.export")
         n = self.pages_needed(max(1, length))
         if n > len(seq.pages):
@@ -314,6 +344,7 @@ class PagedKVCache:
         ``export_sequence`` (one host sync), minus the
         sequence framing: the prefix cache's radix node carries the token
         labels, so the payload is just raw page content + dtype."""
+        self._no_latent("export_pages")
         phys = jnp.asarray(self._phys_ids(pages))
         k, v = (np.asarray(a)
                 for a in jax.device_get((self.k[phys], self.v[phys])))
@@ -337,6 +368,7 @@ class PagedKVCache:
         (``LMRS_HOST_KV_SYNC`` A/B fallback).  Geometry/dtype mismatches
         raise ``ValueError`` — same rejection discipline as
         ``import_sequence``; the caller re-prefills."""
+        self._no_latent("import_pages")
         n = len(pages)
         if payload.get("dtype") != str(self.k.dtype):
             raise ValueError(
@@ -377,6 +409,7 @@ class PagedKVCache:
         under pool pressure (back-pressure: the importer retries, never
         corrupts).  On any failure after allocation the pages are freed —
         a failed import must not leak."""
+        self._no_latent("import_sequence")
         faults.fire("handoff.import")
         kh, ps, hd = (int(x) for x in self.k.shape[1:])
         want = {"page_size": self.page_size, "n_layers": self.n_layers,

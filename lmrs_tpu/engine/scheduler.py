@@ -159,6 +159,15 @@ class ContinuousScheduler:
             # 32-row-aligned windows that never straddle a page
             raise ValueError(f"kv_quantize=int8 needs page_size % 32 == 0 "
                              f"(got {ps})")
+        # A latent cache (models/latent.py) is one pool with no kv-head
+        # axis: what is not built for it is refused here, by name
+        self._latent = bool(model_cfg.kv_lora_rank)
+        if self._latent:
+            self._refuse_for_latent(engine_cfg, mesh)
+        # routed models hand back the held experts' token counts from
+        # every program (``_moe_take``)
+        self._moe_on = bool(model_cfg.n_routed_experts)
+        self._moe_pending: list[tuple[str, tuple, object]] = []
         self.cache = PagedKVCache(model_cfg, num_pages, ps, max_pages_per_slot,
                                   mesh=mesh,
                                   kv_dtype="int8" if self._kv_quant else None)
@@ -194,7 +203,8 @@ class ContinuousScheduler:
         # run on real tokens only instead of ~pow2-bucket padding per prompt
         # (measured ~43% padded q rows at the bench shape).  LMRS_PACK_PREFILL=0
         # restores per-prompt prefill for A/B measurement.
-        self._pack_prefill = env_bool("LMRS_PACK_PREFILL", True)
+        self._pack_prefill = (env_bool("LMRS_PACK_PREFILL", True)
+                              and not self._latent)
         # int8 KV composes with packing since r4 (VERDICT r3 item 3): the
         # packed program computes per-SEGMENT scales and scatters them into
         # each segment's slot row — no gate needed
@@ -286,11 +296,15 @@ class ContinuousScheduler:
         # owns its slot's scales exactly like a fresh prefill) and
         # spec x mixed (decode rows carry verify spans in-graph, so spec
         # no longer yields during prefill windows).
-        self._rpa = env_bool("LMRS_RPA", True)
+        self._rpa = env_bool("LMRS_RPA", True) and not self._latent
+        if self._latent:
+            logger.info("latent KV cache: packed prefill, mixed steps and "
+                        "the span program are off (fresh prefill, chunked "
+                        "continuation and decode blocks serve it)")
         self._rpa_fns: dict[tuple, object] = {}
         self._mixed = (engine_cfg.mixed_batch and env_bool("LMRS_MIXED", True)
                        and (self._rpa or not self._kv_quant)
-                       and not self._use_ring)
+                       and not self._use_ring and not self._latent)
         self.mixed_token_budget = max(32, engine_cfg.mixed_token_budget)
         self._mixed_fns: dict[tuple[int, int], object] = {}
         # Tree speculation on the span family (ISSUE 19): the linear draft
@@ -683,6 +697,8 @@ class ContinuousScheduler:
         # call a no-op, outputs and wire byte-identical.
         self._an = maybe_anatomy(self.registry,
                                  metrics_cb=lambda: self.metrics)
+        if self._moe_on:
+            self._an.has_moe = True  # the moe_* counters, from the start
         # LMRS_PROFILE_ON_SLOW_STEP: a decode block slower than the
         # threshold (warm shapes only) triggers ONE jax.profiler capture
         # per process into LMRS_PROFILE_DIR — the "why was that step
@@ -1108,10 +1124,70 @@ class ContinuousScheduler:
             # kv-head-sharded so each shard's walk is local.  The fused
             # write RMWs an 8-row-aligned DMA window, which only stays
             # inside the page when the page size is a multiple of 8.
+            if self.model_cfg.kv_lora_rank:
+                # the latent decode kernel (ops/mla_attention.py) slices
+                # the latent off a page at a lane boundary
+                return ((on_tpu() or self._interpret)
+                        and self.model_cfg.kv_lora_rank % 128 == 0
+                        and self.cfg.page_size % 16 == 0)
             return ((on_tpu() or self._interpret)
                     and self.model_cfg.hd % 128 == 0
                     and self.cfg.page_size % 8 == 0 and self._tp_only_mesh())
         return False
+
+    @staticmethod
+    def _refuse_for_latent(engine_cfg: EngineConfig, mesh) -> None:
+        """What a latent KV cache cannot be combined with yet, each by its
+        name: served wrongly is worse than not served."""
+        tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+        sp = 1 if mesh is None else mesh.shape.get("sp", 1)
+        for bad, what in (
+            (engine_cfg.prefix_cache and env_bool("LMRS_PREFIX_CACHE", True),
+             "prefix_cache (a hit continues through the span program, "
+             "which reads K and V pools)"),
+            (engine_cfg.kv_quantize, "kv_quantize (int8 KV pages: per-head "
+                                     "scales, and the latent has no head)"),
+            (engine_cfg.quantize, "quantize (int8 weights: the latent and "
+                                  "expert projections have no int8 path)"),
+            (tp > 1, "tp > 1 (pages shard by kv head; the latent has none)"),
+            (sp > 1, "sp > 1 (ring prefill writes K and V shards)"),
+            (mesh is not None and mesh.devices.size > 1,
+             "a mesh of more than one device"),
+            (engine_cfg.speculate_k, "speculate_k (the verify kernels read "
+                                     "K and V pools)"),
+            (engine_cfg.scheduler != "continuous",
+             f"scheduler={engine_cfg.scheduler!r} (only the continuous "
+             "scheduler carries the latent pool)"),
+        ):
+            if bad:
+                raise ValueError(
+                    f"latent KV cache (kv_lora_rank > 0) does not support "
+                    f"{what}; turn it off for this model")
+
+    def _moe_take(self, program: str, key: tuple, out: tuple) -> tuple:
+        """A routed model's programs return the held experts' counts last
+        ([routed layers, experts_held + 2], ops/moe.py): kept on the device
+        until the next fetch brings them back with the tokens."""
+        if not self._moe_on:
+            return out
+        self._moe_pending.append((program, key, out[-1]))
+        return out[:-1]
+
+    def _moe_note(self, pending: list, fetched) -> None:
+        held = self.model_cfg.experts_held
+        for (program, key, _), st in zip(pending, fetched):
+            st = np.asarray(st)
+            self._an.note_moe(
+                program, key, pairs=int(st[:, :held].sum()),
+                tokens_max=int(st[:, held].sum()),
+                extra_passes=int(st[:, held + 1].sum()), held=held)
+
+    def _moe_drain(self) -> None:
+        """Counts that no decode fetch picked up (a run whose last
+        dispatch was a prefill)."""
+        if self._moe_pending:
+            pending, self._moe_pending = self._moe_pending, []
+            self._moe_note(pending, jax.device_get([p[2] for p in pending]))
 
     def _single_device(self) -> bool:
         return self.mesh is None or self.mesh.devices.size == 1
@@ -1821,6 +1897,14 @@ class ContinuousScheduler:
             # clamped (same reason as _timed_get) — doubly important here:
             # this runs in a finally, where a raise would mask the real error
             self._c_run_seconds.inc(max(0.0, time.time() - t_run))
+            if self._moe_pending:
+                # routed-layer counts no decode fetch brought back; in a
+                # finally, so a failed device must not mask the real error
+                try:
+                    self._moe_drain()
+                except Exception:
+                    logger.exception("routed-layer counts lost at run end")
+                    self._moe_pending = []
             # an iteration a fault left open contributes NOTHING to the
             # anatomy totals (iter_abort discards) — conservation survives
             # the chaos arms by construction; no-op after a clean close
@@ -2814,7 +2898,8 @@ class ContinuousScheduler:
         from lmrs_tpu.utils.perf_model import time_chain
         from lmrs_tpu.utils.platform import on_tpu
 
-        if not (self._use_ragged and on_tpu() and self._single_device()):
+        if (not (self._use_ragged and on_tpu() and self._single_device())
+                or self._latent):  # the probe times the K/V decode kernel
             return {}
         from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
 
@@ -4301,7 +4386,9 @@ class ContinuousScheduler:
                       if fresh
                       else self._get_prefill_window_fn(s_bucket, w))
                 tok0, self.cache.k, self.cache.v, \
-                    self.kscale, self.vscale = fn(*args)
+                    self.kscale, self.vscale = self._moe_take(
+                        "prefill" if fresh else "prefill_chunk", key_,
+                        fn(*args))
             self._note_ran_ok(key_)
             rows = [(b, row) for row, (b, _, _, _, is_final) in enumerate(items)
                     if is_final]
@@ -4542,6 +4629,7 @@ class ContinuousScheduler:
         mesh_ = self._kernel_mesh()
         interp = self._interpret
         kv_q = bool(self._kv_quant)
+        moe = self._moe_on
         if use_ring and s_bucket % self._sp:
             raise ValueError(
                 f"ring prefill bucket {s_bucket} not divisible by "
@@ -4565,10 +4653,13 @@ class ContinuousScheduler:
                 last_pos=length - 1,  # LM head on the sampled row only
                 kv_scales=(kscale, vscale) if kv_q else None,
                 scale_rows=scale_rows,
+                token_valid=(positions < length[:, None]) if moe else None,
             )
             logits, k_pages, v_pages = out[:3]
             kscale, vscale = out[3] if kv_q else (None, None)
             tok0 = sample_logits(logits[:, 0], key, temp, tk, tp)
+            if moe:
+                return tok0, k_pages, v_pages, kscale, vscale, out[-1]
             return tok0, k_pages, v_pages, kscale, vscale
 
         logger.info("compiling paged prefill: bucket=%d (flash=%s ring=%s)",
@@ -4585,6 +4676,7 @@ class ContinuousScheduler:
         cfg = self.model_cfg
         rope_max = self.max_len
         kv_q = bool(self._kv_quant)
+        moe = self._moe_on
 
         @partial(jax.jit, donate_argnums=(1, 2, 3, 4) if kv_q else (1, 2))
         def prefill_chunk(params, k_pages, v_pages, kscale, vscale,
@@ -4600,10 +4692,14 @@ class ContinuousScheduler:
                 last_pos=length - 1,  # local row index within this chunk
                 kv_scales=(kscale, vscale) if kv_q else None,
                 scale_rows=scale_rows,
+                token_valid=((positions - start[:, None] < length[:, None])
+                             if moe else None),
             )
             logits, k_pages, v_pages = out[:3]
             kscale, vscale = out[3] if kv_q else (None, None)
             tok0 = sample_logits(logits[:, 0], key, temp, tk, tp)
+            if moe:
+                return tok0, k_pages, v_pages, kscale, vscale, out[-1]
             return tok0, k_pages, v_pages, kscale, vscale
 
         logger.info("compiling chunked prefill: bucket=%d window=%d pages",
@@ -4740,10 +4836,16 @@ class ContinuousScheduler:
                 ctx_tokens=attr_live_tokens, cold=not decode_warm) as disp:
             out = self._get_decode_fn(w)(*args)
         self._note_ran_ok(key_)
-        toks, n_valid, self.cache.k, self.cache.v = out
+        toks, n_valid, self.cache.k, self.cache.v = self._moe_take(
+            "decode", key_, out)
+        moe, self._moe_pending = self._moe_pending, []
         with self._an.seg("fetch"):
             toks, n_valid, *tok0s = self._timed_get(  # one transfer
-                (toks, n_valid, *[t for t, _ in pending]))
+                (toks, n_valid, *[t for t, _ in pending],
+                 *[m[2] for m in moe]))
+        if moe:  # the routed layers' counts rode the same transfer
+            self._moe_note(moe, tok0s[len(pending):])
+            tok0s = tok0s[:len(pending)]
         toks, n_valid = np.asarray(toks), np.asarray(n_valid)
         disp.emitted(int(n_valid.sum()))
         t_done = time.time()
@@ -4789,12 +4891,13 @@ class ContinuousScheduler:
         row_group = self._row_group
 
         kv_q = bool(self._kv_quant)
+        moe = self._moe_on
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def decode(params, k_pages, v_pages, kscale, vscale, scale_rows,
                    last_tok, kv_lens, table, active, key, temps, tk, tp):
             def step(carry, _):
-                k_pages, v_pages, tok, lens, done, key = carry
+                k_pages, v_pages, tok, lens, done, key = carry[:6]
                 pos = jnp.minimum(lens, max_len - 1)[:, None]
                 out = forward_paged(
                     params, cfg, tok[:, None], pos, k_pages, v_pages, table,
@@ -4804,6 +4907,7 @@ class ContinuousScheduler:
                     kv_scales=(kscale, vscale) if kv_q else None,
                     scale_rows=scale_rows if kv_q else None,
                     decode_row_group=row_group,
+                    token_valid=(~done)[:, None] if moe else None,
                 )
                 logits, k_pages, v_pages = out[:3]
                 key, sub = jax.random.split(key)
@@ -4815,13 +4919,23 @@ class ContinuousScheduler:
                 nxt = jnp.where(done, eos_id, nxt)
                 newly_done = jnp.logical_or(done, nxt == eos_id)
                 lens = jnp.where(done, lens, lens + 1)
-                return (k_pages, v_pages, nxt, lens, newly_done, key), (nxt, ~done)
+                nxt_carry = (k_pages, v_pages, nxt, lens, newly_done, key)
+                if moe:  # the block's counts: every step's, added up
+                    nxt_carry += (carry[6] + out[-1],)
+                return nxt_carry, (nxt, ~done)
 
             carry = (k_pages, v_pages, last_tok, kv_lens, ~active, key)
-            (k_pages, v_pages, _, _, _, _), (toks, valid) = jax.lax.scan(
+            if moe:
+                carry += (jnp.zeros((cfg.n_routed_layers,
+                                     cfg.experts_held + 2), jnp.int32),)
+            carry, (toks, valid) = jax.lax.scan(
                 step, carry, None, length=n_steps)
+            k_pages, v_pages = carry[:2]
             toks = jnp.transpose(toks)
             valid = jnp.transpose(valid)
+            if moe:
+                return (toks, jnp.sum(valid, axis=1), k_pages, v_pages,
+                        carry[6])
             return toks, jnp.sum(valid, axis=1), k_pages, v_pages
 
         logger.info("compiling paged decode: B=%d steps=%d window=%d pages "

@@ -42,6 +42,10 @@ def _dtype(cfg: ModelConfig):
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random-init params (truncated-normal fan-in scaling), stacked layers."""
+    if cfg.kv_lora_rank:  # latent attention: its own tree (models/latent.py)
+        from lmrs_tpu.models import latent
+
+        return latent.init_params(cfg, key)
     dt = _dtype(cfg)
     hd = cfg.hd
     k_embed, k_layers, k_head = jax.random.split(key, 3)
@@ -220,6 +224,15 @@ def forward(
     if kv_length is not None and attn_fn is not None:
         raise ValueError("attn_fn does not apply kv_length masking; "
                          "pad-free batches only on the ring-attention path")
+    if cfg.kv_lora_rank:
+        if cache is not None or attn_fn is not None or return_aux or remat:
+            raise NotImplementedError(
+                "latent attention (kv_lora_rank > 0) has the plain forward "
+                "and the paged one: no dense KV cache, ring attention, aux "
+                "loss or remat")
+        from lmrs_tpu.models import latent
+
+        return latent.forward(params, cfg, tokens, positions, kv_length)
     dt = _dtype(cfg)
     b, s = tokens.shape
     hd = cfg.hd
@@ -343,6 +356,9 @@ def forward_paged(
                                  # causal rule.  Routes to the XLA span twin
                                  # (the Pallas ancestor variant is chip debt,
                                  # docs/PERF.md).
+    token_valid: jnp.ndarray | None = None,  # [B, S] bool: tokens that carry
+                                 # work (not padding, not an idle row); read
+                                 # by routed layers only (ops/moe.py)
 ) -> tuple:
     """Forward pass against a paged KV cache (engine/kv_cache.PagedKVCache).
 
@@ -402,6 +418,22 @@ def forward_paged(
     )
     from lmrs_tpu.ops.quant import (kv_dequant, kv_quant, kv_quant_tokens,
                                     kv_scale_from)
+
+    if cfg.kv_lora_rank:
+        # latent attention: one latent pool and its own two attention forms
+        # (models/latent.py); the engine refuses at start what is named here
+        if (kv_scales is not None or spans is not None or multi_decode
+                or segment_ids is not None or use_ring or mesh is not None):
+            raise NotImplementedError(
+                "latent cache: no int8 KV, span or packed program, "
+                "speculative verify, ring prefill or mesh")
+        from lmrs_tpu.models import latent
+
+        return latent.forward_paged(
+            params, cfg, tokens, positions, k_pages, page_tables, kv_lens,
+            rope_max, use_ragged_kernel=use_ragged_kernel,
+            window_prefill=window_prefill, use_flash=use_flash,
+            interpret=interpret, last_pos=last_pos, token_valid=token_valid)
 
     if kv_scales is not None:
         # int8 KV: packed prefill composes (per-SEGMENT scales, r4 — each
@@ -511,6 +543,14 @@ def forward_paged(
                     q[0], k[0], v[0], kp_all, vp_all, g_tables, kv_lens,
                     span_starts, span_lens, row_flat,
                     max_pos=rope_max, kv_scales=ss, anc_masks=span_anc)
+            if cfg.n_experts:
+                # tokens outside every span (the bucket's padding) leave
+                # the span attention non-finite, and a routed layer mixes
+                # every token of a call in one product (0 x NaN = NaN in
+                # every expert slot): make them zero.  Routed models only:
+                # a dense FFN keeps rows apart, and the pass is not free
+                in_span = row_flat < span_starts.shape[0]
+                attn = jnp.where(in_span[:, None, None], attn, 0)
             return _finish_layer(lp, x, attn[None], kp_all, vp_all,
                                  ksc, vsc)
 

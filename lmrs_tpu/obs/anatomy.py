@@ -76,6 +76,11 @@ RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
                                   "ctx_tokens", "wide_tokens",
                                   "kv_page_reads", "cold")
 
+# what a routed model's dispatches add to their record once their counts
+# are back (``note_moe``); absent from a dense model's records and report
+MOE_FIELDS: tuple[str, ...] = ("moe_routed_pairs", "moe_expert_tokens_max",
+                               "moe_expert_tokens_mean", "moe_extra_passes")
+
 # iteration step classes (the decode_split/serving_latency split axis)
 CLASSES: tuple[str, ...] = ("plain", "mixed", "spec", "prefill")
 
@@ -265,6 +270,10 @@ class StepAnatomy:
                       "prefill_token_slots": 0, "rpa_wide_tokens": 0,
                       "rpa_kv_page_reads": 0, "cold_dispatches": 0,
                       "cold_seconds": 0.0}
+        # routed models only (the scheduler sets ``has_moe`` for one): a
+        # dense model's counters and report stay as they were
+        self._moe_flat = dict.fromkeys(MOE_FIELDS, 0)
+        self.has_moe = False
 
         c, g, h = (registry.counter, registry.gauge, registry.histogram)
         self._c_iters = c("lmrs_anatomy_iterations_total",
@@ -450,12 +459,29 @@ class StepAnatomy:
         if r["program"] == "rpa":
             self._c_b_compile.inc(seconds)
 
+    def note_moe(self, program: str, key: tuple, *, pairs: int,
+                 tokens_max: int, extra_passes: int, held: int) -> None:
+        """The routed layers' counts of dispatches on ``(program, key)``,
+        known once a fetch has brought them back: token-expert pairs that
+        landed on the ``held`` experts here, the busiest held expert's
+        tokens and the mean over the held experts (each summed over the
+        routed layers and, in a decode block, its steps), and grouped-
+        product passes beyond the first."""
+        rec = self._table[(program, tuple(key))]
+        for f, v in zip(MOE_FIELDS, (pairs, tokens_max, pairs / held,
+                                     extra_passes)):
+            rec[f] = rec.get(f, 0) + v
+            self._moe_flat[f] += v
+
     def counters(self) -> dict:
         """The flat sums ``ContinuousScheduler.metrics`` carries: prefill
         dispatches, their real query positions and the positions their
         operands held (the prompt programs), the span kernel's wide-tile
         tokens and page fetches (the ``rpa`` program), cold dispatches and
-        their wall (all programs)."""
+        their wall (all programs); for a routed model, the ``MOE_FIELDS``
+        sums over all programs."""
+        if self.has_moe:
+            return {**self._flat, **self._moe_flat}
         return dict(self._flat)
 
     # --------------------------------------------------------------- reading
@@ -596,12 +622,13 @@ def _programs_report(table: dict, before: dict) -> dict:
         d = {f: rec[f] - b.get(f, 0) for f in RECORD_FIELDS}
         if not d["dispatches"]:
             continue
+        moe = {f: rec[f] - b.get(f, 0) for f in MOE_FIELDS if f in rec}
         d["cold_ms"] = (rec["cold_s"] - b.get("cold_s", 0.0)) * 1e3
         tot = programs.setdefault(program, {
             **dict.fromkeys(RECORD_FIELDS, 0), "cold_ms": 0.0, "keys": {}})
-        for f, v in d.items():
-            tot[f] += v
-        tot["keys"][_key_str(key)] = d
+        for f, v in {**d, **moe}.items():
+            tot[f] = tot.get(f, 0) + v
+        tot["keys"][_key_str(key)] = {**d, **moe}
     for tot in programs.values():
         for rec in (tot, *tot["keys"].values()):
             rec["cold_ms"] = round(rec["cold_ms"], 1)
@@ -768,6 +795,9 @@ class NullAnatomy:
 
     def dispatch(self, program: str, key: tuple, **record):
         return _NULL_SEG
+
+    def note_moe(self, program: str, key: tuple, **counts) -> None:
+        pass
 
     def counters(self) -> dict:
         return {}

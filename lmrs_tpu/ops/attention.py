@@ -30,8 +30,11 @@ def attention(
     q_positions: jnp.ndarray,  # [B, Sq] absolute position of each query
     kv_length: jnp.ndarray | None = None,  # [B] valid KV prefix length
     logit_softcap: float | None = None,
+    scale: float | None = None,  # softmax scale; None: hd ** -0.5
 ) -> jnp.ndarray:
-    """Causal attention over a (possibly padded) KV buffer.
+    """Causal attention over a (possibly padded) KV buffer.  ``v`` may be
+    narrower than ``q`` and ``k`` (latent attention): the output takes
+    ``v``'s width.
 
     Masking rule: query at absolute position p attends KV slots [0, p], and
     only slots < kv_length are valid.  Works for both prefill (Sq == Skv,
@@ -42,7 +45,8 @@ def attention(
     k = _repeat_kv(k, h // kh)
     v = _repeat_kv(v, h // kh)
 
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
     # [B, H, Sq, Skv]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if logit_softcap is not None:  # Gemma-2 style softcap

@@ -129,23 +129,25 @@ def _flash_kernel(
 
 @functools.partial(
     jax.jit, static_argnames=("q_block", "kv_block", "interpret",
-                              "skip_padded_q")
+                              "skip_padded_q", "sm_scale")
 )
 def flash_attention(
     q: jnp.ndarray,          # [B, Sq, H, hd]
     k: jnp.ndarray,          # [B, Skv, K, hd]
-    v: jnp.ndarray,          # [B, Skv, K, hd]
+    v: jnp.ndarray,          # [B, Skv, K, hd_v]
     lengths: jnp.ndarray | None = None,  # [B] valid kv length
     q_block: int = _DEFAULT_BLOCK,
     kv_block: int = _DEFAULT_BLOCK,
     interpret: bool = False,
     skip_padded_q: bool = True,
     segment_ids: jnp.ndarray | None = None,  # [B, S] packed-prompt segments
+    sm_scale: float | None = None,  # softmax scale; None: hd ** -0.5
 ) -> jnp.ndarray:
     """Causal flash attention over fresh (position-0-based) sequences.
 
     Requires Sq == Skv (self-attention prefill / training).  Returns
-    [B, Sq, H, hd] in q.dtype.  With ``skip_padded_q`` (default), rows at
+    [B, Sq, H, hd_v] in q.dtype; ``hd_v`` is ``v``'s own head width, which
+    latent attention makes narrower than the queries' and keys'.  With ``skip_padded_q`` (default), rows at
     positions >= lengths[b] are exactly zero — their blocks are predicated
     off entirely (a bucketed prompt would otherwise burn MXU time computing
     attention for garbage rows); pass False to compute them anyway.
@@ -156,7 +158,7 @@ def flash_attention(
     per-segment causality (segments are contiguous).
     """
     b, sq, h, hd = q.shape
-    skv, kh = k.shape[1], k.shape[2]
+    skv, kh, hd_v = k.shape[1], k.shape[2], v.shape[3]
     assert sq == skv, "flash_attention is for self-attention prefill"
     n_rep = h // kh
     if lengths is None:
@@ -186,7 +188,8 @@ def flash_attention(
     grid = (b, h, sq_p // q_block, skv_p // kv_block)
     kernel = functools.partial(
         _flash_kernel, q_block=q_block, kv_block=kv_block,
-        sm_scale=hd ** -0.5, skip_padded_q=skip_padded_q, has_segs=has_segs,
+        sm_scale=hd ** -0.5 if sm_scale is None else sm_scale,
+        skip_padded_q=skip_padded_q, has_segs=has_segs,
     )
     in_specs = [
         # whole [B] array in SMEM (rank-1 blocking is restricted on real
@@ -196,7 +199,7 @@ def flash_attention(
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, kv_block, hd),
                      lambda bi, hi, qi, ki: (bi, hi // n_rep, ki, 0)),
-        pl.BlockSpec((1, 1, kv_block, hd),
+        pl.BlockSpec((1, 1, kv_block, hd_v),
                      lambda bi, hi, qi, ki: (bi, hi // n_rep, ki, 0)),
     ]
     operands = [lengths.astype(jnp.int32), qt, kt, vt]
@@ -211,13 +214,13 @@ def flash_attention(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, q_block, hd),
+        out_specs=pl.BlockSpec((1, 1, q_block, hd_v),
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, hd_v), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((q_block, 128), jnp.float32),
             pltpu.VMEM((q_block, 128), jnp.float32),
-            pltpu.VMEM((q_block, hd), jnp.float32),
+            pltpu.VMEM((q_block, hd_v), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),

@@ -65,6 +65,8 @@ def chip_spec() -> ChipSpec | None:
 def matmul_params(cfg: ModelConfig) -> int:
     """Parameters that participate in per-token matmuls (embedding lookup
     excluded; the LM head included — tied or not, it is a [D, V] matmul)."""
+    if cfg.kv_lora_rank:
+        return _latent_matmul_params(cfg)
     d, hd = cfg.dim, cfg.hd
     per_layer = (
         d * cfg.n_heads * hd          # wq
@@ -77,6 +79,36 @@ def matmul_params(cfg: ModelConfig) -> int:
     else:
         per_layer += 3 * d * cfg.hidden_dim
     return cfg.n_layers * per_layer + d * cfg.vocab_size
+
+
+def _latent_matmul_params(cfg: ModelConfig) -> int:
+    """``matmul_params`` of a latent-attention model (models/latent.py):
+    what one token multiplies HERE: the attention projections, a dense
+    layer's FFN, and in a routed layer the router, the shared expert and
+    its expected pairs on the experts this engine holds."""
+    d, h = cfg.dim, cfg.n_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    attn = (d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
+            + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+            + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + h * cfg.v_head_dim * d)
+    n_routed = cfg.n_routed_layers
+    dense = 3 * d * (cfg.dense_hidden_dim or cfg.hidden_dim)
+    expert = 3 * d * cfg.hidden_dim
+    routed = (d * cfg.n_routed_experts + cfg.n_shared_experts * expert
+              + cfg.n_experts_per_token * cfg.experts_held
+              / max(cfg.n_routed_experts, 1) * expert)
+    return int(cfg.n_layers * attn + (cfg.n_layers - n_routed) * dense
+               + n_routed * routed + d * cfg.vocab_size)
+
+
+def _attn_width(cfg: ModelConfig) -> float:
+    """Per-head multiply-adds a (query, key) pair costs, over 2: QK^T width
+    plus PV width, halved (the dense model's ``hd``)."""
+    if cfg.kv_lora_rank:
+        return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                + cfg.v_head_dim) / 2.0
+    return cfg.hd
 
 
 def prefill_flops(cfg: ModelConfig, n_tokens: int,
@@ -98,7 +130,7 @@ def prefill_flops(cfg: ModelConfig, n_tokens: int,
         * d * cfg.vocab_size
     fl += 2.0 * cfg.n_layers * (float(n_tokens) ** 2
                                 + 2.0 * kv_start * n_tokens) \
-        * cfg.hd * cfg.n_heads
+        * _attn_width(cfg) * cfg.n_heads
     return fl
 
 
@@ -117,6 +149,9 @@ def kv_bytes_per_token(cfg: ModelConfig) -> float:
     """KV-cache bytes per cached token (K + V, all layers, all kv heads)."""
     import jax.numpy as jnp
 
+    if cfg.kv_lora_rank:  # one latent row a layer, as stored
+        return (cfg.n_layers * cfg.latent_width
+                * jnp.dtype(cfg.dtype).itemsize)
     return (2 * cfg.n_layers * cfg.n_kv_heads * cfg.hd
             * jnp.dtype(cfg.dtype).itemsize)
 

@@ -221,6 +221,62 @@ def test_pool_write_needs_no_pool_sized_temporary(one_chip, pool_dtype):
     assert ma.temp_size_in_bytes < pool_bytes // 8, ma.temp_size_in_bytes
 
 
+def test_flash_prefill_compiles_at_latent_attention_widths(one_chip):
+    """MLA's expanded prefill at the published widths: 64 heads, queries
+    and keys 192 wide (no multiple of 128), values 128 wide."""
+    from lmrs_tpu.ops.flash_attention import flash_attention
+
+    bf = jnp.bfloat16
+
+    def fn(q, kk, v, lengths):
+        return flash_attention(q, kk, v, lengths, sm_scale=0.14468)
+
+    compiled, hlo = _compile(
+        fn, one_chip, ((2, 2048, 64, 192), bf), ((2, 2048, 64, 192), bf),
+        ((2, 2048, 64, 128), bf), ((2,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    assert compiled.output_shardings is not None
+
+
+def test_latent_decode_compiles_and_its_pool_write_stays_in_place(one_chip):
+    """The latent decode step's attention at the published widths (64
+    heads, latent 512 + 64 rotary lanes in 640, 16 rows of 2k tokens), as
+    models/latent.py spells it: the new token's row written by
+    ``scatter_kv_rows``, then the kernel reading the pool, layer after
+    layer over a donated scan carry.  The kernel must lower (Mosaic), and
+    the write beside a reading kernel must not cost a copy of the pool."""
+    from lmrs_tpu.ops.mla_attention import mla_paged_decode_pallas
+    from lmrs_tpu.ops.paged_attention import scatter_kv_rows
+
+    n_layers, pages, ps, c, b, h, w = 6, 257, 128, 640, 16, 64, 16
+    bf = jnp.bfloat16
+
+    def prog(pool, q, rows, tables, lens):
+        def body(carry, li):
+            pool, acc = carry
+            page = (li * pages + tables[:, :1])
+            pool = scatter_kv_rows(pool, page, (lens % ps)[:, None],
+                                   rows[:, None, None, :])
+            o = mla_paged_decode_pallas(q, pool, li * pages + tables, lens,
+                                        rank=512, sm_scale=0.14468)
+            return (pool, acc + o), None
+
+        (pool, acc), _ = jax.lax.scan(
+            body, (pool, jnp.zeros((b, h, 512), bf)), jnp.arange(n_layers))
+        return pool, acc
+
+    args = [jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+            for shp, dt in (((n_layers * pages, 1, ps, c), bf),
+                            ((b, h, c), bf), ((b, c), bf),
+                            ((b, w), jnp.int32), ((b,), jnp.int32))]
+    compiled = jax.jit(prog, donate_argnums=(0,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    pool_bytes = n_layers * pages * ps * c * 2
+    assert ma.alias_size_in_bytes >= pool_bytes  # donated in place
+    assert ma.temp_size_in_bytes < pool_bytes // 8, ma.temp_size_in_bytes
+
+
 # ------------------------------------------------ the new rules, no TPU needed
 
 
