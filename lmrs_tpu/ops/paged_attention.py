@@ -10,35 +10,31 @@ Two implementations with one contract:
   bound from SMEM — DMA-ing K/V pages HBM→VMEM and folding them into an
   online softmax.  Decode cost is proportional to the tokens actually in
   the cache (the Ragged Paged Attention idea, PAPERS.md), which is the
-  whole point of paging: decode is HBM-bandwidth-bound and the bandwidth
-  spent is exactly the live KV bytes.
+  whole point of paging: the bytes a decode step must move are exactly the
+  live KV bytes.
 
-The kv-head axis is folded INTO each program as a statically-unrolled loop
-(round 3; previously grid=(B, K)): one program per batch row walks all
-kv heads' pages through one double-buffered DMA pipeline that crosses head
-boundaries.  At bench shape this cuts programs/step 8× (3,456 → 432 per
-model step) — the round-2 decode fixed cost was diagnosed as program +
-small-DMA launch latency, not bandwidth (docs/PERF.md round 2: 9.39 ms
-fitted fixed cost vs a 2.49 ms weight-stream floor).
+Cache layout: [P_total, K, page_size, hd], PAGE-major: one page's ALL kv
+heads are a single contiguous [K, page_size, hd] DMA, and the kv-head axis
+is folded INTO each program as a statically-unrolled loop over the buffered
+block, so one double-buffered DMA pipeline serves every head of a row.
+P_total flattens the layer axis into the page axis —
+engine/kv_cache.PagedKVCache — and callers pass GLOBAL page ids.
 
-The BATCH axis folds the same way with ``row_group > 1`` (round 6, the
-multi-row page walk): one program walks a GROUP of G rows through the
-shared pipeline — grid=(B/G,) — priming row r+1's first page and running
-its RMW cycle inside row r's compute bubbles (``_make_group_kernel``).
-The per-program fixed cost that grid=(B,) pays per ROW is paid per GROUP;
-at the 8B bench shape ~2.8 ms of the decode step was this per-row cost
-(24 rows × 32 layers × 3.6 µs — docs/PERF.md r5 intercept decomposition),
-which G-row programs divide by up to G.  Callers pass a host-side
+The BATCH axis folds the same way with ``row_group > 1`` (the multi-row
+page walk): one program walks a GROUP of G rows through the shared pipeline
+— grid=(B/G,) — priming row r+1's first page and running its RMW cycle
+inside row r's compute bubbles (``_make_group_kernel``), so launch, scratch
+init and the pipeline's prime are paid per group.  Callers pass a host-side
 length-balanced row order (``balanced_row_order``) so one straggler row
-cannot serialize a whole group.  ``row_group=1`` (the LMRS_MULTIROW=0
-kill switch) is byte-for-byte the previous per-row grid.
+cannot serialize a whole group.  ``row_group=1`` is the per-row grid, one
+program a row.
 
-Cache layout: [P_total, K, page_size, hd] (PAGE-major, round 3: one page's
-ALL kv heads are a single contiguous [K, page_size, hd] DMA — the
-head-major layout issued kh separate per-head page DMAs, and the decode
-fixed-cost split measured the walk DMA-issue-bound, not bandwidth-bound;
-docs/PERF.md round 3).  P_total flattens the layer axis into the page axis
-— engine/kv_cache.PagedKVCache — and callers pass GLOBAL page ids.
+What the walk costs on the v5e is the longest chain of latencies one page
+step strings together, not the page's bytes: with float32 operands on both
+sides of its two products and the heads folded one after another a step
+took the same time on bf16 and on int8 pages.  ``_fold_page`` multiplies in
+the pool's stored type, keeps the softmax state lane-replicated and folds a
+page in three passes over the heads (the readings: PERF.md section 6, PR 30).
 """
 
 from __future__ import annotations
@@ -81,8 +77,8 @@ def balanced_row_order(lengths, row_group: int) -> np.ndarray:
     # identity fast path: one group, or uniform lengths (the common
     # equal-chunk map workload) — balancing is a no-op, and returning
     # identity lets the scheduler skip the reorder entirely (it also
-    # keeps sampled rows' draws aligned with the LMRS_MULTIROW=0 A/B
-    # control when there was nothing to balance)
+    # keeps sampled rows' draws aligned with the per-row grid's when
+    # there was nothing to balance)
     if n_groups == 1 or (b and (lengths == lengths[0]).all()):
         return np.arange(b, dtype=np.int64)
     order = np.argsort(-lengths, kind="stable")
@@ -180,6 +176,122 @@ def paged_decode_xla(
 # ------------------------------------------------------------ Pallas kernel
 
 
+# bf16 parts an f32 left-side operand is split into: 8 significant bits
+# each, so three carry all 24 and the split rounds nothing
+_SPLIT = 3
+
+
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` value at width ``n``: its first
+    ``n`` lanes, or copies side by side where ``n`` is a multiple of 128.
+    Either way whole vregs: no cross-lane move."""
+    if n <= 128:
+        return x[:, :n]
+    assert n % 128 == 0, n
+    return jnp.concatenate([x] * (n // 128), axis=1)
+
+
+def _mxu_dtype(pool_dtype):
+    """The type a fold's products run in, read off the pool: an f32 pool
+    (the CPU tests') multiplies in f32, bf16 pages are the MXU operand as
+    they lie in VMEM, int8 pages convert to bf16 (exact: |x| <= 127)."""
+    return jnp.float32 if pool_dtype == jnp.float32 else jnp.bfloat16
+
+
+def _left_rows(x, mxu):
+    """``x`` [rows, n] as the left side of a product against a page tile of
+    type ``mxu``.  In f32, or already bf16 (q on a bf16 pool), it is ``x``.
+    An f32 ``x`` against a bf16 tile (the scaled query of an int8 pool, the
+    probabilities) is split into ``_SPLIT`` bf16 parts by masking mantissa
+    bits — hi, what is left of x - hi, and so on: their sum is ``x`` — and
+    the parts are STACKED as further rows, padded to the bf16 tile's 16.
+    The page tile is the MXU's stationary operand, loaded once per product
+    in one pass whatever the rows that ride on it; ``_part_sum`` adds the
+    parts' results in f32, so the product differs from an f32 x f32 one by
+    summation order alone.  Returns the left side and its number of parts
+    (1: not split)."""
+    if mxu == jnp.float32 or x.dtype == mxu:
+        return x.astype(mxu), 1
+    rest = x.astype(jnp.float32)
+    parts = []
+    for _ in range(_SPLIT - 1):
+        hi = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(rest, jnp.int32) & -65536,
+            jnp.float32)
+        parts.append(hi)
+        rest = rest - hi
+    parts.append(rest)
+    pad = -(_SPLIT * x.shape[0]) % 16
+    if pad:
+        parts.append(jnp.zeros((pad, x.shape[1]), jnp.float32))
+    return jnp.concatenate(parts, axis=0).astype(mxu), _SPLIT
+
+
+def _part_sum(r, rows: int, parts: int):
+    """A product's ``rows`` result rows from those of its left side's
+    ``parts`` stacked parts (``_left_rows``): added smallest first."""
+    out = r[(parts - 1) * rows:parts * rows]
+    for i in reversed(range(parts - 1)):
+        out = out + r[i * rows:(i + 1) * rows]
+    return out
+
+
+def _softmax_step(s, m_ref, l_ref, *, guard: bool):
+    """One page of an online softmax: masked scores ``s`` [rows, ps] against
+    the running max / sum ``m_ref`` / ``l_ref`` ([rows, 128] f32, every lane
+    of a row the same number), kept LANE-REPLICATED end to end: read whole,
+    combined whole, stored whole.  Reading lane 0 and broadcasting it back
+    costs four cross-lane permutes per 8 rows per page beside the two
+    reductions (PERF.md section 6, PR 26 and PR 30).  Returns the page's
+    probabilities ``pw`` [rows, ps] and the accumulator's rescale ``alpha``
+    [rows, 128].  ``guard`` zeroes ``pw`` where a row has seen no valid
+    position yet (its max is still NEG_INF, so ``exp(s - m)`` would be 1)."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    m_wide = _lanes(m_new, s.shape[1])
+    pw = jnp.exp(s - m_wide)
+    if guard:
+        pw = jnp.where(m_wide > NEG_INF * 0.5, pw, 0.0)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(pw, axis=1, keepdims=True)
+    m_ref[...] = m_new
+    return pw, alpha
+
+
+def _fold_page(qs, k_page, v_page, masked, sm_scale, acc_scr, m_scr, l_scr):
+    """Fold one page, ALL its kv heads, into a walk's online softmax.
+    ``qs[ki]`` is head ki's ``_left_rows`` pair; ``k_page`` / ``v_page`` the
+    page's [kh, ps, hd] blocks as stored; acc/m/l the walk's state with its
+    leading kh axis.  Both products run in the pool's ``_mxu_dtype`` with
+    f32 accumulation; the all-masked guard is on (an inactive row, a verify
+    row past ``max_pos``).
+
+    In three passes over the heads — every head's scores, every head's
+    softmax step, every head's values — and not head by head: an MXU takes
+    its products in program order, so head by head the next head's scores
+    queue behind this head's values product, which waits for this head's
+    softmax, and a page step becomes one chain of products and lane
+    reductions through the heads whatever else the units could overlap
+    (the compiler's critical path either way: PERF.md section 6, PR 30)."""
+    kh, rows, hd = acc_scr.shape
+    mxu = _mxu_dtype(k_page.dtype)
+    scores = []
+    for ki in range(kh):
+        q, parts = qs[ki]
+        s = _part_sum(jax.lax.dot_general(
+            q, k_page[ki].astype(mxu), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), rows, parts) * sm_scale
+        scores.append(jnp.where(masked, s, NEG_INF))  # [rows, ps]
+    steps = [_softmax_step(scores[ki], m_scr.at[ki], l_scr.at[ki],
+                           guard=True) for ki in range(kh)]
+    for ki, (pw, alpha) in enumerate(steps):
+        pw, parts = _left_rows(pw, mxu)
+        pv = _part_sum(jax.lax.dot_general(
+            pw, v_page[ki].astype(mxu), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32), rows, parts)
+        acc_scr[ki] = acc_scr[ki] * _lanes(alpha, hd) + pv
+
+
 def _ragged_decode_all_heads(
     # scalar prefetch
     page_tables_ref,  # SMEM [B, W]
@@ -221,13 +333,14 @@ def _ragged_decode_all_heads(
                         # (base + tiles-so-far * QT), not the row's total
 ):
     """Walk ONE batch row's live pages through a double-buffered DMA
-    pipeline — PAGE-major (round 3): each loop step DMAs one page's ALL kv
-    heads as a single [K, ps, hd] copy and unrolls the head compute over
-    the buffered block.  The head-major predecessor issued kh separate
-    per-head page DMAs; the decode fixed-cost split measured the walk
-    DMA-issue-bound (docs/PERF.md round 3), so fewer/bigger copies is the
-    lever.  Every head keeps its own online-softmax state (acc/m/l gain a
-    leading kh axis, statically indexed).
+    pipeline: each loop step DMAs one page's ALL kv heads as a single
+    [K, ps, hd] copy (the page-major layout) and unrolls the heads' folds
+    over the buffered block, page p+1 streaming while page p computes.
+    Every head keeps its own online-softmax state (acc/m/l gain a leading
+    kh axis, statically indexed; m and l lane-replicated).
+
+    What a page step costs beyond its DMA is ``_fold_page``'s business
+    (module docstring; PERF.md section 6, PR 30).
 
     With ``n_tokens > 1`` (ragged speculative verify) the q rows group as
     [token j][query head group]: token j sits at absolute position
@@ -262,14 +375,15 @@ def _ragged_decode_all_heads(
     m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
     l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-    # per-head q, pre-scaled for int8 pools: q·(s⊙k8) = (q⊙s)·k8
+    # per-head q as the scores product's left side, once per walk; for int8
+    # pools pre-scaled: q·(s⊙k8) = (q⊙s)·k8
+    rows = q_ref.shape[1]
     qs = []
     for ki in range(kh):
-        q = q_ref[ki].astype(jnp.float32)  # [rows, hd]
+        q = q_ref[ki]  # [rows, hd]
         if get_kscale is not None:
-            q = q * get_kscale(b, ki)[None, :]
-        qs.append(q)
-    rows = qs[0].shape[0]
+            q = q.astype(jnp.float32) * get_kscale(b, ki)[None, :]
+        qs.append(_left_rows(q, _mxu_dtype(k_scr.dtype)))
 
     def body(p, _):
         slot = jax.lax.rem(p, 2)
@@ -302,25 +416,8 @@ def _ragged_decode_all_heads(
                 limit = jnp.minimum(limit, max_pos)
         masked = pos < limit
 
-        for ki in range(kh):
-            k = k_scr[slot, ki].astype(jnp.float32)  # [ps, hd]
-            s = jax.lax.dot_general(
-                qs[ki], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # [rows, ps]
-            s = jnp.where(masked, s, NEG_INF)
-            m_prev = m_scr[ki, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            pw = jnp.exp(s - m_new)
-            pw = jnp.where(m_new > NEG_INF * 0.5, pw, 0.0)
-            l_scr[ki] = jnp.broadcast_to(
-                alpha * l_scr[ki, :, :1] + jnp.sum(pw, axis=1, keepdims=True),
-                l_scr.shape[1:])
-            vv = v_scr[slot, ki].astype(jnp.float32)
-            acc_scr[ki] = acc_scr[ki] * alpha + jax.lax.dot_general(
-                pw, vv, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[ki] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        _fold_page(qs, k_scr.at[slot], v_scr.at[slot], masked, sm_scale,
+                   acc_scr, m_scr, l_scr)
         return _
 
     jax.lax.fori_loop(0, n_pages, body, None)
@@ -334,7 +431,7 @@ def _ragged_decode_all_heads(
     @pl.when(n_pages > 0)
     def _write():
         for ki in range(kh):
-            l = l_scr[ki, :, :1]
+            l = _lanes(l_scr[ki], acc_scr.shape[-1])
             out = acc_scr[ki] / jnp.where(l > 0, l, 1.0)
             if get_vscale is not None:
                 # per-channel V scale on the output axis: pw·(s⊙v8) =
@@ -375,10 +472,9 @@ def _make_rmw(
     The positions are consecutive, so they cover at most
     ``n_win = (T-2)//8 + 2`` aligned 8-row windows, and page_size % 8 == 0
     means no window straddles a page — each window is ONE read-blend-write
-    RMW covering ALL kv heads (a single strided [K, wh, hd] copy each way;
-    round 5 — the per-(head, window) copies before it were 2·K tiny DMA
-    issues per direction, the dominant share of the measured ~6 µs/row
-    decode fixed cost), reads all issued before any blend so they overlap.
+    RMW covering ALL kv heads (a single strided [K, wh, hd] copy each way,
+    not 2·K tiny per-head copies), reads all issued before any blend so
+    they overlap.
 
     ``max_pos`` (static): tokens at positions >= it are NOT written — the
     max-seq-len cap for draft tokens that overhang the end of the cache
@@ -553,11 +649,12 @@ def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
     paid once per group, and the cross-row software pipeline runs at ROW
     granularity inside the program: while row r computes, row r+1's RMW
     windows read/blend/write and its first page prefetches into row r's
-    compute bubbles.  This generalizes the per-row fused kernel's
-    cross-iteration trick (which already measured 3.6 µs/row fused vs 5.2
-    walk-only — the pipeline pays; docs/PERF.md round 5) from grid
-    iterations to unrolled in-program rows, where no program boundary sits
-    between them.
+    compute bubbles.  This is the per-row fused kernel's cross-iteration
+    trick moved from grid iterations to unrolled in-program rows, where no
+    program boundary sits between them.  Both dense cells of the benchmark
+    dispatch it at ``g`` 4 (24 rows, 13-15 pages a live row): there the
+    page steps, not the rows' fixed costs, are the kernel's time (PERF.md
+    section 6, PR 30).
 
     Shared by the single-token fused decode (``n_tokens == 1``) and the
     speculative multi-token verify (``n_tokens > 1``): the RMW machinery
@@ -678,15 +775,14 @@ def paged_decode_pallas_multi(
     kscale: jnp.ndarray | None = None,  # [B, K, hd] f32: int8 pools — the
     vscale: jnp.ndarray | None = None,  # per-(slot, head, channel) scales
     row_group: int = 1,        # rows per program (multi-row page walk);
-                               # 1 = the per-row grid (LMRS_MULTIROW=0)
+                               # 1 = the per-row grid
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Ragged multi-token verify: the speculative-decoding analog of
     ``paged_decode_pallas_fused``.  One program per batch row writes all T
     new tokens' K/V into their pages in place and attends each token's
     query rows to the live pages with strict per-token causality — ONE
-    ragged page walk for the whole [B, T] verify step, replacing the
-    full page-window gather that made round-2 speculation 12x slower
-    (docs/PERF.md; VERDICT r2 item 3).
+    ragged page walk for the whole [B, T] verify step in place of a full
+    page-window gather.
 
     Near the max-seq-len boundary the caller passes the UNclamped length
     (base = kv_lens - T is then always the true first-token position) and
@@ -694,7 +790,7 @@ def paged_decode_pallas_multi(
     attended — a clamped length would instead slide the whole write span
     backwards over real cache entries.
 
-    With ``kscale``/``vscale`` the pools are int8 (VERDICT r4 item 4): the
+    With ``kscale``/``vscale`` the pools are int8: the
     RMW quantizes the draft tokens' rows with the slot's frozen
     per-channel scales, windows widen to the int8 sublane tile (32), and
     the walk folds K's dequant into every token's q rows and V's into the
@@ -1019,43 +1115,23 @@ def span_walk_counts(q_lens, kv_lens, page_size: int, table_pages: int,
     return wide_tokens, reads
 
 
-def _lanes(x, n: int):
-    """A lane-replicated ``[rows, 128]`` value at width ``n``: its first
-    ``n`` lanes, or copies side by side where ``n`` is a multiple of 128.
-    Either way whole vregs: no cross-lane move."""
-    if n <= 128:
-        return x[:, :n]
-    assert n % 128 == 0, n
-    return jnp.concatenate([x] * (n // 128), axis=1)
-
-
 def _fold_page_wide(q, k, v, masked, sm_scale, acc_ref, m_ref, l_ref):
     """Fold one page of one kv head into a wide span tile's online softmax:
     scores ``q·kᵀ`` ([rows, ps], f32 accumulation whatever the operands'
-    dtype), masked, against the head's running max / sum ([rows, 128] f32,
-    every lane of a row the same number) and accumulator ([rows, hd] f32).
-    The same arithmetic as the walk of ``_ragged_decode_all_heads``, with
-    the row statistics kept LANE-REPLICATED end to end: read whole, combined
-    whole, stored whole.  Reading lane 0 and broadcasting it back (the
-    narrow walk's spelling, fine at its 8-64 rows) costs four cross-lane
-    permutes per 8 rows per page here beside the two reductions, and the
-    cross-lane unit is what bounds a 512-row tile (PERF.md, PR 26).
-    No all-masked guard: a tile's walk starts at page 0, whose position 0
-    every row sees, so the running max is finite from the first fold on."""
+    dtype), masked, against the head's lane-replicated running max / sum
+    (``_softmax_step``) and accumulator ([rows, hd] f32).  No all-masked
+    guard: a tile's walk starts at page 0, whose position 0 every row sees,
+    so the running max is finite from the first fold on.  The values are
+    still multiplied in f32 (a lead: PERF.md section 7)."""
     hd = acc_ref.shape[-1]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale  # [rows, ps]
     s = jnp.where(masked, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    pw = jnp.exp(s - _lanes(m_new, s.shape[1]))
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(pw, axis=1, keepdims=True)
+    pw, alpha = _softmax_step(s, m_ref, l_ref, guard=False)
     acc_ref[...] = acc_ref[...] * _lanes(alpha, hd) + jax.lax.dot_general(
         pw, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
 
 
 def _wide_span_tiles(
@@ -1630,26 +1706,30 @@ def paged_decode_pallas_fused(
     kscale: jnp.ndarray | None = None,  # [B, K, hd] f32: int8 pools — the
     vscale: jnp.ndarray | None = None,  # per-(slot, head, channel) scales
     row_group: int = 1,        # rows per program (multi-row page walk);
-                               # 1 = the per-row grid (LMRS_MULTIROW=0)
+                               # 1 = the per-row grid
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Write-fused ragged decode: scatter the current token's K/V into the
     page pool (in place — the pools are input/output aliased) and attend the
     live pages, in one kernel, one program per BATCH ROW (all kv heads).
-    Replaces XLA scatter + kernel: the XLA scatter on the multi-GiB pool was
-    measured copying the whole pool per decode step (no in-place aliasing
-    through the scan carry).
+    Replaces XLA scatter + kernel: an XLA scatter on the multi-GiB pool
+    copies the whole pool per decode step (no in-place aliasing through the
+    scan carry).
 
     With ``row_group > 1`` one program walks a GROUP of rows through the
     shared pipeline (``_make_group_kernel``): programs/step drop by the
-    group factor and the per-program fixed cost — the dominant share of
-    the measured ~3.6 µs/row decode attention cost at 8B (docs/PERF.md
-    round 5) — amortizes over the group.  Exact-output-equal to the
-    per-row grid; callers balance groups host-side (balanced_row_order).
+    group factor and the per-program fixed cost amortizes over the group.
+    Exact-output-equal to the per-row grid; callers balance groups
+    host-side (balanced_row_order).
 
     With ``kscale``/``vscale`` the pools are int8: pages stream as raw int8
     (half the decode bytes), K's per-channel dequant folds into q before
     the walk and V's into the accumulator after it, the RMW quantizes the
-    new token's rows, and windows are 32 rows (the int8 sublane tile)."""
+    new token's rows, and windows are 32 rows (the int8 sublane tile).
+
+    This is the decode kernel of both dense cells of the benchmark
+    (``paged_decode_roofline.offline`` reads it by the ``paged_decode`` in
+    its name); what a page step of its walk costs is ``_fold_page``'s
+    business (PERF.md section 6, PR 30)."""
     b, h, hd = q.shape
     kh = k_pages.shape[1]
     ps = k_pages.shape[2]
@@ -1798,7 +1878,7 @@ def paged_decode_pallas_fused(
             (k_hbm, v_hbm, o_ref, k_out, v_out, k_scr, v_scr, acc_scr,
              m_scr, l_scr, k8_scr, v8_scr, sem, wsem) = rest
             gks = gvs = None
-        # Cross-row software pipeline (round 3): rows' pages are DISJOINT
+        # Cross-row software pipeline: rows' pages are DISJOINT
         # (slots own their pages exclusively), so iteration b
         #   1. starts row b+1's RMW window READS (tiny DMAs that land
         #      while row b's pages stream),

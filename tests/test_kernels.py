@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import PAGED_POOLS, PAGED_TOL, paged_pools
 from lmrs_tpu.ops.attention import attention
 from lmrs_tpu.ops.flash_attention import flash_attention
 
@@ -61,70 +62,175 @@ def test_use_flash_prefill_gate():
     assert not _use_flash_prefill(2048, 128)
 
 
-def test_fused_decode_matches_scatter_plus_xla():
+# query heads per kv head in the fused-decode parity cases, by pool type
+# (MHA, GQA 2, GQA 4; test_kv_quant.py runs int8 at 2 and 4, the verify
+# cases below every type at 4 and 2).  One shape per type, so the cases of
+# a type share one trace of the interpreted kernel.
+_N_REP = {"f32": 1, "bf16": 2, "int8": 4}
+
+
+def _decode_case(pool, seed, b=4, t=None, n_rep=None, kh=2, hd=128,
+                 n_pages=12):
+    """One decode parity case over a pool of type ``pool``: float32 queries
+    (any float32: the walk splits them without rounding), new-token K/V the
+    pool holds exactly, pages of 16 tokens (32 for int8: the write's
+    window).  ``t`` new tokens a row make it a multi-token verify case."""
+    ps = 32 if pool == "int8" else 16
+    rng = np.random.default_rng(seed)
+    pools = paged_pools(pool, rng, (n_pages, kh, ps, hd), b)
+    lead = (b,) if t is None else (b, t)
+    h = kh * (n_rep or _N_REP[pool])
+    q = jnp.asarray(rng.standard_normal(lead + (h, hd)), jnp.float32)
+    k_new = pools.new(jnp.asarray(rng.standard_normal(lead + (kh, hd)),
+                                  jnp.float32))
+    v_new = pools.new(jnp.asarray(rng.standard_normal(lead + (kh, hd)),
+                                  jnp.float32))
+    return q, k_new, v_new, pools, ps
+
+
+def _scatter_new_token(pools, k_new, v_new, tables, kv_lens, rows):
+    """The XLA write of ``rows``' current token (position ``kv_lens - 1``)
+    into the stored pools: quantized with the row's scales for int8."""
+    from lmrs_tpu.ops.quant import kv_quant
+
+    ps = pools.k.shape[2]
+    rows = np.asarray(rows)
+    pos = jnp.asarray(kv_lens)[rows] - 1
+    page = jnp.take_along_axis(jnp.asarray(tables)[rows],
+                               (pos // ps)[:, None], 1)[:, 0]
+    if pools.kw:
+        k_rows = kv_quant(k_new[:, None], pools.kw["kscale"])[:, 0][rows]
+        v_rows = kv_quant(v_new[:, None], pools.kw["vscale"])[:, 0][rows]
+    else:
+        k_rows = k_new[rows].astype(pools.k.dtype)
+        v_rows = v_new[rows].astype(pools.v.dtype)
+    return (pools.k.at[page, :, pos % ps].set(k_rows),
+            pools.v.at[page, :, pos % ps].set(v_rows))
+
+
+@pytest.mark.parametrize("pool", PAGED_POOLS)
+def test_fused_decode_matches_scatter_plus_xla(pool):
     """The write-fused ragged decode kernel (interpret mode) must produce
     the same attention output AND the same pool contents as the XLA
-    scatter + gather fallback."""
-    import jax.numpy as jnp
+    scatter + gather fallback, whatever the pool's stored type: the walk
+    multiplies bf16 and int8 pages in bf16 with its float32 operands split
+    into stacked bf16 rows, and owes the float32 reference the tolerance a
+    float32 pool owes it."""
     from lmrs_tpu.ops.paged_attention import (
         paged_decode_pallas_fused,
         paged_decode_xla,
     )
 
-    b, h, kh, hd, ps, n_pages = 2, 4, 4, 128, 16, 12
-    rng = jax.random.split(jax.random.PRNGKey(0), 5)
-    k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), jnp.float32)
-    v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), jnp.float32)
-    q = jax.random.normal(rng[2], (b, h, hd), jnp.float32)
-    k_new = jax.random.normal(rng[3], (b, kh, hd), jnp.float32)
-    v_new = jax.random.normal(rng[4], (b, kh, hd), jnp.float32)
-    # row 0: 29 tokens live (pos 28 = page 1, off 12 -> RMW window start 8);
-    # row 1: 5 tokens (off 4 -> window start 0) — covers both w0 cases
-    tables = jnp.asarray([[3, 5, 7], [9, 0, 0]], jnp.int32)
-    kv_lens = jnp.asarray([29, 5], jnp.int32)
+    q, k_new, v_new, pools, ps = _decode_case(pool, 0)
+    # row 0: pos ps+12 = page 1, offset 12 (an 8-row window starts at 8);
+    # row 1: 5 tokens (window start 0); row 2: inactive, on the null page;
+    # row 3: its last page holds one token
+    tables = jnp.asarray([[3, 5, 7], [9, 0, 0], [0, 0, 0], [2, 4, 6]],
+                         jnp.int32)
+    kv_lens = jnp.asarray([ps + 13, 5, 0, 2 * ps + 1], jnp.int32)
+    act = np.asarray(kv_lens) > 0
 
-    # reference: XLA scatter of the new token, then gather-attend
-    pos = kv_lens - 1
-    page = jnp.take_along_axis(tables, (pos // ps)[:, None], 1)[:, 0]
-    off = pos % ps
-    k_ref = k_pages.at[page, :, off].set(k_new)
-    v_ref = v_pages.at[page, :, off].set(v_new)
-    want = paged_decode_xla(q, k_ref, v_ref, tables, kv_lens)
+    k_ref, v_ref = _scatter_new_token(pools, k_new, v_new, tables, kv_lens,
+                                      np.flatnonzero(act))
+    want = paged_decode_xla(q, pools.ref(k_ref), pools.ref(v_ref), tables,
+                            kv_lens, **pools.xkw)
 
     got, k_out, v_out = paged_decode_pallas_fused(
-        q, k_new, v_new, k_pages, v_pages, tables, kv_lens, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(k_out), np.asarray(k_ref))
-    np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v_ref))
+        q, k_new, v_new, pools.k, pools.v, tables, kv_lens, interpret=True,
+        **pools.kw)
+    np.testing.assert_allclose(np.asarray(got)[act], np.asarray(want)[act],
+                               rtol=PAGED_TOL, atol=PAGED_TOL)
+    assert not np.asarray(got)[~act].any()  # an inactive row reads zeros
+    # page 0 is the null page: the inactive row's write parks there
+    np.testing.assert_array_equal(np.asarray(k_out[1:]),
+                                  np.asarray(k_ref[1:]))
+    np.testing.assert_array_equal(np.asarray(v_out[1:]),
+                                  np.asarray(v_ref[1:]))
 
 
-def test_ragged_decode_clamps_stale_lengths():
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_fold_with_bf16_rounded_probabilities_fails(pool, monkeypatch):
+    """What PAGED_TOL tells apart.  One bf16 part in place of three is the
+    ``pw.astype(bf16)`` spelling of the fold (the probabilities, and the
+    float32 query, rounded to 8 bits): it misses the float32 reference by
+    50 tolerances and more (90 and 600 here) where the fold as it stands passes."""
+    import lmrs_tpu.ops.paged_attention as pa
+
+    q, k_new, v_new, pools, ps = _decode_case(pool, 0)
+    tables = jnp.asarray([[3, 5, 7], [9, 0, 0], [1, 8, 0], [2, 4, 6]],
+                         jnp.int32)
+    kv_lens = jnp.asarray([ps + 13, 5, ps + 1, 2 * ps + 1], jnp.int32)
+    k_ref, v_ref = _scatter_new_token(pools, k_new, v_new, tables, kv_lens,
+                                      range(4))
+    want = np.asarray(pa.paged_decode_xla(
+        q, pools.ref(k_ref), pools.ref(v_ref), tables, kv_lens,
+        **pools.xkw))
+
+    def worst(got):
+        return float(np.max(np.abs(np.asarray(got) - want)
+                            / (1 + np.abs(want))))
+
+    args = (q, k_new, v_new, pools.k, pools.v, tables, kv_lens)
+    assert worst(pa.paged_decode_pallas_fused(
+        *args, interpret=True, **pools.kw)[0]) <= PAGED_TOL
+    monkeypatch.setattr(pa, "_SPLIT", 1)
+    # the undecorated function: the jit's cache holds the three-part trace
+    rounded = pa.paged_decode_pallas_fused.__wrapped__(
+        *args, interpret=True, **pools.kw)[0]
+    assert worst(rounded) > 50 * PAGED_TOL
+
+
+def test_left_rows_split_is_exact():
+    """``_left_rows`` stacks a float32 operand as bf16 parts that add up to
+    it bit for bit (so nothing is rounded that an f32 product would not
+    round), pads the stack to the bf16 tile's 16 rows with zeros, hands a
+    bf16 operand and an f32 product's operand through, and ``_part_sum``
+    adds the parts' rows back."""
+    from lmrs_tpu.ops.paged_attention import _SPLIT, _left_rows, _part_sum
+
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((8, 128)).astype(np.float32)
+    x *= np.exp2(rng.integers(-30, 30, x.shape)).astype(np.float32)
+    x[0, :3] = [0.0, -0.0, 1.0]
+    stacked, n_parts = _left_rows(jnp.asarray(x), jnp.bfloat16)
+    assert stacked.dtype == jnp.bfloat16 and n_parts == _SPLIT
+    assert stacked.shape == (-(-_SPLIT * 8 // 16) * 16, 128)
+    parts = np.asarray(stacked.astype(jnp.float32))
+    np.testing.assert_array_equal(
+        np.asarray(_part_sum(jnp.asarray(parts), 8, n_parts)), x)
+    assert not parts[_SPLIT * 8:].any()
+    xb = jnp.asarray(x, jnp.bfloat16)
+    whole, n_parts = _left_rows(xb, jnp.bfloat16)
+    assert whole is xb and n_parts == 1
+    whole, n_parts = _left_rows(xb, jnp.float32)
+    assert whole.dtype == jnp.float32 and n_parts == 1
+    assert _part_sum(xb, 8, 1) is xb
+
+
+@pytest.mark.parametrize("pool", PAGED_POOLS)
+def test_ragged_decode_clamps_stale_lengths(pool):
     """Regression: a row whose kv_len exceeds its page table's width (a
     freed slot's stale length, or any degenerate input) must clamp its
     page walk and write index to the table instead of indexing SMEM out
     of bounds — on real TPUs the unclamped read DMA'd from garbage page
     ids (fixed alongside scheduler-side zeroing; see scheduler admit()/
     _maybe_finish)."""
-    import jax.numpy as jnp
-    from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
+    from lmrs_tpu.ops.paged_attention import (
+        paged_decode_pallas_fused,
+        paged_decode_xla,
+    )
 
-    b, h, kh, hd, ps, n_pages = 2, 4, 4, 128, 16, 12
-    rng = jax.random.split(jax.random.PRNGKey(1), 5)
-    k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), jnp.float32)
-    v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), jnp.float32)
-    q = jax.random.normal(rng[2], (b, h, hd), jnp.float32)
-    k_new = jax.random.normal(rng[3], (b, kh, hd), jnp.float32)
-    v_new = jax.random.normal(rng[4], (b, kh, hd), jnp.float32)
-    from lmrs_tpu.ops.paged_attention import paged_decode_xla
-
-    tables = jnp.asarray([[3, 5], [9, 0]], jnp.int32)  # width 2 = 32 tokens
-    # row 0 normal; row 1 claims 180 tokens (needs 12 pages > width 2)
-    kv_lens = jnp.asarray([20, 180], jnp.int32)
+    q, k_new, v_new, pools, ps = _decode_case(pool, 1)
+    # width 3 pages; rows 2 and 3 inactive, on the null page
+    tables = jnp.asarray([[3, 5, 0], [9, 7, 2], [0, 0, 0], [0, 0, 0]],
+                         jnp.int32)
+    # row 0 normal; row 1 claims 12 pages' worth (> width 3)
+    kv_lens = jnp.asarray([ps + 4, 11 * ps + 4, 0, 0], jnp.int32)
     clamped = jnp.minimum(kv_lens, tables.shape[1] * ps)
 
     got, k_out, v_out = paged_decode_pallas_fused(
-        q, k_new, v_new, k_pages, v_pages, tables, kv_lens, interpret=True)
+        q, k_new, v_new, pools.k, pools.v, tables, kv_lens, interpret=True,
+        **pools.kw)
 
     # reference mirrors the kernel: the degenerate row's write is SKIPPED
     # entirely (its position lies past the table span — a clipped-page
@@ -133,19 +239,20 @@ def test_ragged_decode_clamps_stale_lengths():
     # table capacity.  An unclamped kernel would re-attend its last
     # column's page for every overflow walk step, shifting row 1's softmax
     # — so output parity here genuinely discriminates fixed vs broken.
-    pos0 = int(kv_lens[0]) - 1  # row 0 only; row 1's write is skipped
-    page0, off0 = int(tables[0, pos0 // ps]), pos0 % ps
-    k_ref = k_pages.at[page0, :, off0].set(k_new[0])
-    v_ref = v_pages.at[page0, :, off0].set(v_new[0])
-    want = paged_decode_xla(q, k_ref, v_ref, tables, clamped)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-    # writes land ONLY on row 0's write page (pos 19 -> column 1 -> page
-    # 5); row 1's out-of-span write is skipped, not clipped; K and V both
-    for name, out_pool, in_pool in (("k", k_out, k_pages), ("v", v_out, v_pages)):
+    k_ref, v_ref = _scatter_new_token(pools, k_new, v_new, tables, kv_lens,
+                                      [0])  # row 1's write is skipped
+    want = paged_decode_xla(q, pools.ref(k_ref), pools.ref(v_ref), tables,
+                            clamped, **pools.xkw)
+    np.testing.assert_allclose(np.asarray(got)[:2], np.asarray(want)[:2],
+                               rtol=PAGED_TOL, atol=PAGED_TOL)
+    # writes land ONLY on row 0's write page (pos ps+3 -> column 1 -> page
+    # 5) and, for the inactive rows, on the null page; row 1's out-of-span
+    # write is skipped, not clipped; K and V both
+    for name, out_pool, in_pool in (("k", k_out, pools.k),
+                                    ("v", v_out, pools.v)):
         touched = set(np.flatnonzero(
             (np.asarray(out_pool) != np.asarray(in_pool)).any(axis=(1, 2, 3))))
-        assert touched == {5}, f"{name} wrote pages {touched}, want {{5}}"
+        assert touched - {0} == {5}, f"{name} wrote pages {touched}, want 5"
 
 
 def _tp_mesh(tp=2):
@@ -210,39 +317,43 @@ def test_flash_sharded_matches_reference():
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_multi_token_verify_matches_xla_reference():
+def _assert_pools_equal(got, want, pages=slice(None)):
+    """Stored pool against the reference's (float32 for a bf16 pool, whose
+    new rows were drawn bf16-exact)."""
+    np.testing.assert_array_equal(
+        np.asarray(got[pages].astype(want.dtype)), np.asarray(want[pages]))
+
+
+@pytest.mark.parametrize("pool", PAGED_POOLS)
+def test_multi_token_verify_matches_xla_reference(pool):
     """The ragged multi-token verify kernel (speculative decode: T
     consecutive tokens written + attended with per-token causality in one
     page walk) must match the scatter+gather XLA reference — outputs AND
-    pool contents.  Lengths chosen so the T-token span straddles a page
-    boundary and an 8-row RMW window boundary."""
-    import jax.numpy as jnp
+    pool contents, for every pool type (GQA 4).  Lengths chosen so the
+    T-token span straddles a page boundary and an 8-row RMW window
+    boundary."""
     from lmrs_tpu.ops.paged_attention import (
         paged_decode_multi_xla,
         paged_decode_pallas_multi,
     )
 
-    b, t, h, kh, hd, ps, n_pages = 3, 5, 8, 4, 128, 16, 16
-    rng = jax.random.split(jax.random.PRNGKey(3), 5)
-    k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), jnp.float32)
-    v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), jnp.float32)
-    q = jax.random.normal(rng[2], (b, t, h, hd), jnp.float32)
-    k_new = jax.random.normal(rng[3], (b, t, kh, hd), jnp.float32)
-    v_new = jax.random.normal(rng[4], (b, t, kh, hd), jnp.float32)
+    q, k_new, v_new, pools, ps = _decode_case(pool, 3, b=3, t=5, n_rep=4)
     tables = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
-    # row 0: span 13..17 straddles page 0->1; row 1: span 1..5 in-page but
-    # crosses the 8-row window at base offset 1; row 2: base offset 30
+    # row 0: span ps-3..ps+1 straddles page 0->1; row 1: span 1..5 in-page
+    # but crosses the 8-row window at base offset 1; row 2: base 2ps-2
     # straddles page AND window
-    kv_lens = jnp.asarray([18, 6, 35], jnp.int32)
+    kv_lens = jnp.asarray([ps + 2, 6, 2 * ps + 3], jnp.int32)
 
     want, k_ref, v_ref = paged_decode_multi_xla(
-        q, k_new, v_new, k_pages, v_pages, tables, kv_lens)
+        q, k_new, v_new, pools.ref(pools.k), pools.ref(pools.v), tables,
+        kv_lens, **pools.xkw)
     got, k_out, v_out = paged_decode_pallas_multi(
-        q, k_new, v_new, k_pages, v_pages, tables, kv_lens, interpret=True)
+        q, k_new, v_new, pools.k, pools.v, tables, kv_lens, interpret=True,
+        **pools.kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(k_out), np.asarray(k_ref))
-    np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v_ref))
+                               rtol=PAGED_TOL, atol=PAGED_TOL)
+    _assert_pools_equal(k_out, k_ref)
+    _assert_pools_equal(v_out, v_ref)
 
 
 def test_multi_token_verify_gqa_and_t1_degenerate():
@@ -274,46 +385,41 @@ def test_multi_token_verify_gqa_and_t1_degenerate():
         np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v_ref))
 
 
-def test_multi_token_verify_max_pos_boundary():
-    """Drafts overhanging max_pos (the max-seq-len cap) must be NEITHER
-    written (earlier real cache entries stay intact — a clamped length
-    would slide the write span backwards over them) NOR attended."""
-    import jax.numpy as jnp
+@pytest.mark.parametrize("pool", PAGED_POOLS)
+def test_multi_token_verify_max_pos_boundary(pool):
+    """Drafts overhanging max_pos (the max-seq-len cap, here at a page's
+    edge) must be NEITHER written (earlier real cache entries stay intact —
+    a clamped length would slide the write span backwards over them) NOR
+    attended."""
     from lmrs_tpu.ops.paged_attention import (
         paged_decode_multi_xla,
         paged_decode_pallas_multi,
     )
 
-    b, t, h, kh, hd, ps, n_pages = 2, 4, 4, 2, 128, 16, 8
-    max_pos = 32  # 2 pages of capacity
-    rng = jax.random.split(jax.random.PRNGKey(5), 5)
-    k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), jnp.float32)
-    v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), jnp.float32)
-    q = jax.random.normal(rng[2], (b, t, h, hd), jnp.float32)
-    k_new = jax.random.normal(rng[3], (b, t, kh, hd), jnp.float32)
-    v_new = jax.random.normal(rng[4], (b, t, kh, hd), jnp.float32)
+    q, k_new, v_new, pools, ps = _decode_case(pool, 5, b=2, t=4, n_rep=2,
+                                              kh=1, n_pages=8)
+    max_pos = 2 * ps  # 2 pages of capacity
     tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
-    # row 0: base 30 -> tokens at 30,31 valid, 32,33 overhang the cap;
+    # row 0: base 2ps-2 -> two tokens valid, two overhang the cap;
     # row 1: fully inside
-    kv_lens = jnp.asarray([34, 20], jnp.int32)  # UNclamped lengths
+    kv_lens = jnp.asarray([2 * ps + 2, ps + 4], jnp.int32)  # UNclamped
 
     want, k_ref, v_ref = paged_decode_multi_xla(
-        q, k_new, v_new, k_pages, v_pages, tables, kv_lens, max_pos=max_pos)
+        q, k_new, v_new, pools.ref(pools.k), pools.ref(pools.v), tables,
+        kv_lens, max_pos=max_pos, **pools.xkw)
     got, k_out, v_out = paged_decode_pallas_multi(
-        q, k_new, v_new, k_pages, v_pages, tables, kv_lens, interpret=True,
-        max_pos=max_pos)
+        q, k_new, v_new, pools.k, pools.v, tables, kv_lens, interpret=True,
+        max_pos=max_pos, **pools.kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+                               rtol=PAGED_TOL, atol=PAGED_TOL)
     # pool parity on the real pages (null page 0 excluded: the reference
     # parks overhang writes there by contract)
-    np.testing.assert_array_equal(np.asarray(k_out[1:5]),
-                                  np.asarray(k_ref[1:5]))
-    np.testing.assert_array_equal(np.asarray(v_out[1:5]),
-                                  np.asarray(v_ref[1:5]))
+    _assert_pools_equal(k_out, k_ref, slice(1, 5))
+    _assert_pools_equal(v_out, v_ref, slice(1, 5))
     # and the overhang really was suppressed: row 0's pre-cap cache entries
-    # at positions 28..29 (page 2, offsets 12..13) are untouched
-    np.testing.assert_array_equal(np.asarray(k_out[2, :, 12:14]),
-                                  np.asarray(k_pages[2, :, 12:14]))
+    # at positions 2ps-4..2ps-3 (page 2, offsets ps-4..ps-3) are untouched
+    np.testing.assert_array_equal(np.asarray(k_out[2, :, ps - 4:ps - 2]),
+                                  np.asarray(pools.k[2, :, ps - 4:ps - 2]))
 
 
 def test_multi_token_verify_no_window_alias_at_table_edge():
@@ -364,17 +470,19 @@ def test_multi_token_verify_no_window_alias_at_table_edge():
 # masked writes there by the same convention as inactive dispatch rows.
 
 
-def _ragged_fixture(seed, b=5, h=8, kh=4, hd=128, ps=16, n_pages=32):
+def _ragged_fixture(seed, b=5, h=4, kh=2, hd=128, ps=16, n_pages=32,
+                    dtype=jnp.float32):
     rng = jax.random.split(jax.random.PRNGKey(seed), 5)
-    k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), jnp.float32)
-    v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), jnp.float32)
-    q = jax.random.normal(rng[2], (b, h, hd), jnp.float32)
-    k_new = jax.random.normal(rng[3], (b, kh, hd), jnp.float32)
-    v_new = jax.random.normal(rng[4], (b, kh, hd), jnp.float32)
+    k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), dtype)
+    v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), dtype)
+    q = jax.random.normal(rng[2], (b, h, hd), dtype)
+    k_new = jax.random.normal(rng[3], (b, kh, hd), dtype)
+    v_new = jax.random.normal(rng[4], (b, kh, hd), dtype)
     tables = jnp.asarray(
         np.random.default_rng(seed).permutation(n_pages - 1)[: b * 3]
         .reshape(b, 3) + 1, jnp.int32)
-    # ragged: multi-page, inactive (0), single-token, page-boundary rows
+    # ragged: multi-page, inactive (0), one token past a page's edge,
+    # page-boundary, single-token rows
     kv_lens = jnp.asarray([40, 0, 17, 48, 1], jnp.int32)
     return q, k_new, v_new, k_pages, v_pages, tables, kv_lens
 
@@ -386,20 +494,26 @@ def test_multirow_walk_parity():
 
     q, _, _, kp, vp, tables, kv_lens = _ragged_fixture(0)
     want = paged_decode_pallas(q, kp, vp, tables, kv_lens, interpret=True)
-    for g in (2, 3, 5):
+    for g in (2, 3, 5):  # tails of 1 and 2 rows; one group, no tail
         got = paged_decode_pallas(q, kp, vp, tables, kv_lens,
                                   interpret=True, row_group=g)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_multirow_fused_parity_bf16():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_multirow_fused_parity_bf16(dtype):
     """Fused walk+RMW group kernel vs per-row: outputs and REAL pool pages
-    bit-identical (the cross-row RMW pipeline crossing group boundaries)."""
+    bit-identical (the cross-row RMW pipeline crossing group boundaries),
+    on a float32 pool and on the engine's own types (bf16 q, new K/V and
+    pool)."""
     from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
 
-    q, kn, vn, kp, vp, tables, kv_lens = _ragged_fixture(1)
+    q, kn, vn, kp, vp, tables, kv_lens = _ragged_fixture(1, dtype=dtype)
     want, k_ref, v_ref = paged_decode_pallas_fused(
         q, kn, vn, kp, vp, tables, kv_lens, interpret=True)
+    # 4 is the dense cells' row_group (a tail of one row behind three
+    # padded ones); 5 is one group and no tail
     for g in (2, 4, 5):
         got, k_out, v_out = paged_decode_pallas_fused(
             q, kn, vn, kp, vp, tables, kv_lens, interpret=True, row_group=g)
@@ -417,12 +531,12 @@ def test_multirow_fused_parity_int8():
     from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
 
     rng = np.random.default_rng(7)
-    B, H, K, hd, ps, P = 5, 4, 2, 128, 64, 16
+    B, H, K, hd, ps, P = 5, 4, 2, 128, 32, 16
     kq = jnp.asarray(rng.integers(-127, 128, (P, K, ps, hd)), jnp.int8)
     vq = jnp.asarray(rng.integers(-127, 128, (P, K, ps, hd)), jnp.int8)
     tables = jnp.asarray(rng.permutation(P - 1)[: B * 3].reshape(B, 3) + 1,
                          jnp.int32)
-    lens = jnp.asarray([ps * 2 + 17, 33, 0, ps * 3, 1], jnp.int32)
+    lens = jnp.asarray([ps * 2 + 17, ps + 1, 0, ps * 3, 1], jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
     kn = jnp.asarray(rng.standard_normal((B, K, hd)), jnp.float32)
     vn = jnp.asarray(rng.standard_normal((B, K, hd)), jnp.float32)
@@ -432,7 +546,7 @@ def test_multirow_fused_parity_int8():
     want, k_ref, v_ref = paged_decode_pallas_fused(
         q, kn, vn, kq, vq, tables, lens, interpret=True,
         kscale=ks, vscale=vs)
-    for g in (2, 5):
+    for g in (2, 4, 5):  # 4: the dense cells' row_group
         got, k_out, v_out = paged_decode_pallas_fused(
             q, kn, vn, kq, vq, tables, lens, interpret=True,
             kscale=ks, vscale=vs, row_group=g)
@@ -450,7 +564,7 @@ def test_multirow_multi_token_verify_parity():
     stale-length row, and a fresh (length == T) row."""
     from lmrs_tpu.ops.paged_attention import paged_decode_pallas_multi
 
-    b, t, h, kh, hd, ps, n_pages = 5, 3, 8, 4, 128, 16, 32
+    b, t, h, kh, hd, ps, n_pages = 5, 3, 4, 2, 128, 16, 32
     rng = jax.random.split(jax.random.PRNGKey(11), 5)
     k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), jnp.float32)
     v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), jnp.float32)
@@ -466,7 +580,7 @@ def test_multirow_multi_token_verify_parity():
 
     want, k_ref, v_ref = paged_decode_pallas_multi(
         q, k_new, v_new, k_pages, v_pages, tables, kv_lens, interpret=True)
-    for g in (2, 5):
+    for g in (2, 5):  # a tail of one row; one group, no tail
         got, k_out, v_out = paged_decode_pallas_multi(
             q, k_new, v_new, k_pages, v_pages, tables, kv_lens,
             interpret=True, row_group=g)
@@ -577,7 +691,7 @@ def test_multi_token_verify_out_of_span_skips_on_both_paths():
         paged_decode_pallas_multi,
     )
 
-    b, t, h, kh, hd, ps, n_pages = 2, 3, 4, 2, 128, 16, 8
+    b, t, h, kh, hd, ps, n_pages = 2, 4, 8, 2, 128, 16, 8
     rng = jax.random.split(jax.random.PRNGKey(21), 5)
     k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), jnp.float32)
     v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), jnp.float32)

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import PAGED_TOL
 
 from lmrs_tpu.config import EngineConfig, ModelConfig
 from lmrs_tpu.engine.api import GenerationRequest
@@ -146,32 +147,44 @@ def test_rpa_verify_parity_vs_multi():
     _assert_pool_parity(v_out, v_ref, tables, upto, ps)
 
 
-def test_rpa_mixed_spans_match_xla_reference():
+@pytest.mark.parametrize("pool", ["f32", "bf16"])
+def test_rpa_mixed_spans_match_xla_reference(pool):
     """A genuinely MIXED span list — decode rows, a long prefill-slice
     row whose length is not a SPAN_QT multiple, and an inactive row —
     against the scatter+gather reference (the sp>1 / CPU-fallback path):
-    in-span outputs agree and pools agree over every valid prefix."""
+    in-span outputs agree and pools agree over every valid prefix.  All
+    spans are narrow (SPAN_QT-token tiles through the decode walk): on a
+    bf16 pool the walk multiplies in bf16 with its float32 operands split
+    into stacked rows, and owes the float32 reference the same tolerance."""
     q_lens = [1, 13, 1, 0]
     bases = np.asarray([20, 7, 0, 0], np.int32)
     ps = 16
     qs, total, qf, knf, vnf, kp, vp, tables, row_flat = _span_fixture(
-        2, q_lens, ps=ps)
+        2, q_lens, kh=2, h=4, ps=ps)
+    ref_pools = kp, vp
+    if pool == "bf16":
+        # new K/V the pool holds exactly; the reference reads float32 pages
+        bf = jnp.bfloat16
+        knf, vnf = knf.astype(bf).astype(jnp.float32), vnf.astype(bf).astype(
+            jnp.float32)
+        kp, vp = kp.astype(bf), vp.astype(bf)
+        ref_pools = kp.astype(jnp.float32), vp.astype(jnp.float32)
 
     got, k_out, v_out = ragged_spans_pallas(
         qf, knf, vnf, kp, vp, tables, jnp.asarray(bases),
         jnp.asarray(qs), jnp.asarray(q_lens, jnp.int32), interpret=True)
     want, k_ref, v_ref = ragged_spans_xla(
-        qf, knf, vnf, kp, vp, tables, jnp.asarray(bases),
+        qf, knf, vnf, *ref_pools, tables, jnp.asarray(bases),
         jnp.asarray(qs), jnp.asarray(q_lens, jnp.int32),
         jnp.asarray(row_flat))
 
     in_span = row_flat < len(q_lens)
     np.testing.assert_allclose(np.asarray(got)[in_span],
                                np.asarray(want)[in_span],
-                               rtol=2e-5, atol=2e-5)
+                               rtol=PAGED_TOL, atol=PAGED_TOL)
     upto = bases + np.asarray(q_lens)
-    _assert_pool_parity(k_out, k_ref, tables, upto, ps)
-    _assert_pool_parity(v_out, v_ref, tables, upto, ps)
+    _assert_pool_parity(k_out.astype(jnp.float32), k_ref, tables, upto, ps)
+    _assert_pool_parity(v_out.astype(jnp.float32), v_ref, tables, upto, ps)
 
 
 def test_rpa_mixed_spans_int8_parity():
@@ -251,14 +264,18 @@ def test_rpa_wide_tile_parity(case):
     tokens below the position cap only, no padding garbage."""
     c = dict(ps=16, width=8, n_rep=2, pool="f32", max_pos=None)
     c.update(_WIDE_CASES[case])
-    q_lens, ps, width = c["q_lens"], c["ps"], c["width"]
-    bases = np.asarray(c["bases"], np.int32)
+    ps, width = c["ps"], c["width"]
+    # five rows whatever the case (the spare ones inactive): the cases of
+    # one geometry share one trace of the interpreted kernel
+    q_lens = c["q_lens"] + [0] * (5 - len(c["q_lens"]))
+    bases = np.asarray(c["bases"] + [0] * (5 - len(c["bases"])), np.int32)
     b, kh, hd = len(q_lens), 2, 128
     n_pages = 1 + b * width
     qs, total, qf, knf, vnf, kp, vp, _, row_flat = _span_fixture(
         11, q_lens, h=kh * c["n_rep"], kh=kh, hd=hd, ps=ps,
         n_pages=n_pages, width=width,
-        floor=2 * _W + 8)  # (under _W the wide path would not compile in)
+        floor=4 * _W - 8)  # the longest case's: one flat size for all
+                           # (under _W the wide path would not compile in)
     rng = np.random.default_rng(11)
     tables = jnp.asarray(
         rng.permutation(n_pages - 1).reshape(b, width) + 1, jnp.int32)

@@ -120,8 +120,22 @@ FUSED_CASES = [
 ]
 
 
-@pytest.mark.parametrize("ps,row_group,pool_dtype", FUSED_CASES)
-@pytest.mark.parametrize("shape", SHAPES)
+# the dense cells' decode kernels as dispatched (BENCHMARK.json: 16/8 and
+# 32/8 heads x 128, pages of 128 tokens, 24 rows in groups of 4), on bf16
+# and on int8 pages: Mosaic's verdict on the fold's stacked bf16 left side
+INTERNLM2, MISTRAL7B = (16, 8, 128), (32, 8, 128)
+CELL_CASES = [
+    pytest.param(shape, 128, 4, dt, id=f"{sid}-{did}-ps128-g4")
+    for shape, sid in [(INTERNLM2, "internlm2"), (MISTRAL7B, "mistral7b")]
+    for dt, did in [(jnp.bfloat16, "bf16"), (jnp.int8, "int8")]
+    if (shape, dt) != (LLAMA8B, jnp.int8)  # FUSED_CASES' int8-ps128-g4
+]
+
+
+@pytest.mark.parametrize(
+    "shape,ps,row_group,pool_dtype",
+    [pytest.param(*shape.values, *case.values, id=f"{shape.id}-{case.id}")
+     for shape in SHAPES for case in FUSED_CASES] + CELL_CASES)
 def test_fused_decode_compiles(one_chip, shape, ps, row_group, pool_dtype):
     from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
 
@@ -138,18 +152,28 @@ def test_fused_decode_compiles(one_chip, shape, ps, row_group, pool_dtype):
     assert quant == ("s8[" in hlo)
 
 
-@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
-                         ids=["bf16", "int8"])
-@pytest.mark.parametrize("shape", SHAPES)
-def test_multi_token_verify_compiles(one_chip, shape, pool_dtype):
+@pytest.mark.parametrize(
+    "shape,ps,row_group,pool_dtype",
+    [pytest.param(*shape.values, 512, 1, dt, id=f"{shape.id}-{did}")
+     for dt, did in [(jnp.bfloat16, "bf16"), (jnp.int8, "int8")]
+     for shape in SHAPES] + [
+        # the cells' geometry: a verify step's 5 x 8 query rows a kv head,
+        # stacked as three bf16 parts (120 rows) on int8 pages
+        pytest.param(INTERNLM2, 128, 4, jnp.bfloat16,
+                     id="internlm2-bf16-ps128-g4"),
+        pytest.param(MISTRAL7B, 128, 4, jnp.int8,
+                     id="mistral7b-int8-ps128-g4")])
+def test_multi_token_verify_compiles(one_chip, shape, ps, row_group,
+                                     pool_dtype):
     from lmrs_tpu.ops.paged_attention import paged_decode_pallas_multi
 
     def fn(q, kn, vn, kp, vp, tables, lens, ks=None, vs=None):
         return paged_decode_pallas_multi(q, kn, vn, kp, vp, tables, lens,
-                                         kscale=ks, vscale=vs)
+                                         kscale=ks, vscale=vs,
+                                         row_group=row_group)
 
-    _, hlo = _compile(fn, one_chip,
-                      *_decode_shapes(shape, 24, 512, 4, pool_dtype, t=5))
+    _, hlo = _compile(fn, one_chip, *_decode_shapes(
+        shape, 24, ps, 2048 // ps, pool_dtype, t=5))
     assert "tpu_custom_call" in hlo
 
 
