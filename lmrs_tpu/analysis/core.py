@@ -82,9 +82,9 @@ class Module:
         return False
 
 
-# default scan surface: the production package plus the bench/driver
-# scripts (tests are exercised BY the analyzer, not scanned by it)
-_DEFAULT_GLOBS = ("lmrs_tpu/**/*.py", "bench.py", "scripts/*.py")
+# default scan surface: the production package plus the driver scripts
+# (tests are exercised BY the analyzer, not scanned by it)
+_DEFAULT_GLOBS = ("lmrs_tpu/**/*.py", "scripts/*.py")
 _EXCLUDE_PARTS = ("__pycache__",)
 
 

@@ -562,9 +562,9 @@ def model_preset(name: str) -> ModelConfig:
         "bench-1b": dict(
             # ~1.03B params, Llama-3 proportions at 1B scale (GQA 16q/8kv,
             # head_dim 128 engages the ragged decode kernel), byte vocab so
-            # the bench needs no downloaded tokenizer.  The scale exists so
-            # bench.py measures the MXU/HBM, not the host link (a 45M model
-            # under-utilizes the chip ~20x; VERDICT r1).
+            # it needs no downloaded tokenizer.  The scale exists so a run
+            # measures the MXU/HBM, not the host link (a 45M model
+            # under-utilizes the chip ~20x).
             vocab_size=512, dim=2048, n_layers=18, n_heads=16, n_kv_heads=8,
             hidden_dim=7168, max_seq_len=2048, rope_theta=500000.0,
             tie_embeddings=True,
@@ -598,12 +598,12 @@ def model_preset(name: str) -> ModelConfig:
             hidden_dim=256, max_seq_len=1024, dtype="float32",
         ),
         "bench-smoke": dict(
-            # CPU smoke of the bench HARNESS itself (LMRS_BENCH_MODEL=
-            # bench-smoke): tiny compute but bench-1b's max_seq_len, so the
-            # bench's chunk budget (1400 + context + template < 1920
-            # truncation line) holds and the exact same scheduler shapes
-            # compile — in seconds on a CPU, not minutes ("tiny" inherits
-            # max_seq_len 8192, whose packed/decode shapes thrash CPU XLA).
+            # CPU smoke at bench-1b's scheduler shapes: tiny compute but
+            # bench-1b's max_seq_len, so a 1400-token chunk budget (+ context
+            # + template < 1920 truncation line) holds and the exact same
+            # scheduler shapes compile — in seconds on a CPU, not minutes
+            # ("tiny" inherits max_seq_len 8192, whose packed/decode shapes
+            # thrash CPU XLA).
             vocab_size=512, dim=128, n_layers=2, n_heads=4, n_kv_heads=2,
             hidden_dim=256, max_seq_len=2048,
         ),

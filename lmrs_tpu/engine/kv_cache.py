@@ -219,8 +219,8 @@ class PagedKVCache:
 
     def reallocate(self) -> None:
         """Fresh zeroed pools with the same shape/dtype/sharding.  Recovery
-        hook for a failed DONATED dispatch chain (roofline_microbench): the
-        old buffers may already be consumed, leaving self.k/v unusable.
+        hook for a failed DONATED dispatch chain (the scheduler's run-loop
+        recovery): the old buffers may already be consumed, leaving self.k/v unusable.
         Only valid while no sequence is live (content is discarded)."""
         self.k = jnp.zeros(self.k.shape, self.k.dtype, device=self.k.sharding)
         if self.v is not None:

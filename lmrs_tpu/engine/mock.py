@@ -129,13 +129,10 @@ class MockEngine:
         self._mixed_dispatches = 0  # guarded-by: _mixed_lock
         self._mixed_piggybacked = 0  # guarded-by: _mixed_lock
         self._mixed_fill_sum = 0.0  # guarded-by: _mixed_lock
-        # Ragged-span (RPA) knob parity: the jax scheduler routes every
-        # mixed/continuation dispatch through one span-program family
-        # when LMRS_RPA is on.  The mock mirrors the knob and the
-        # accounting block (span tokens, distinct pow2 compile shapes)
-        # so deviceless CI can assert the metrics surface and the
-        # LMRS_RPA=0 kill switch end-to-end; text is untouched.
-        self.rpa = env_bool("LMRS_RPA", True) and self.mixed_batch
+        # Ragged-span parity: the jax scheduler routes every mixed
+        # dispatch through one span-program family.  The mock mirrors the
+        # accounting block (span tokens, distinct pow2 compile shapes) so
+        # deviceless CI can assert the metrics surface; text is untouched.
         self._rpa_span_tokens = 0      # guarded-by: _mixed_lock
         self._rpa_dispatches = 0       # guarded-by: _mixed_lock
         self._rpa_shapes: set = set()  # guarded-by: _mixed_lock
@@ -153,7 +150,7 @@ class MockEngine:
         while (self.spec_width > 1
                and 1 + self.spec_k * (self.spec_width + 1) > 32):
             self.spec_width -= 1
-        self.spec_tree = (self.spec_k > 0 and self.rpa
+        self.spec_tree = (self.spec_k > 0 and self.mixed_batch
                           and 1 + self.spec_k * (self.spec_width + 1) <= 32
                           and env_bool("LMRS_SPEC_TREE", True))
         self.spec_adaptive = (self.spec_tree
@@ -372,15 +369,14 @@ class MockEngine:
                     self._mixed_piggybacked += c
                     self._mixed_fill_sum += min(
                         (n_decode + c) / self.mixed_token_budget, 1.0)
-                    if self.rpa:
-                        total = n_decode + c
-                        self._rpa_dispatches += 1
-                        self._rpa_span_tokens += total
-                        # same pow2 bucket family the scheduler compiles
-                        # (one shared definition — utils/perf_model)
-                        bucket = pow2_bucket(total, 16)
-                        self._rpa_shapes.add(bucket)
-                        self._note_rpa_bucket(bucket, total)
+                    total = n_decode + c
+                    self._rpa_dispatches += 1
+                    self._rpa_span_tokens += total
+                    # same pow2 bucket family the scheduler compiles
+                    # (one shared definition — utils/perf_model)
+                    bucket = pow2_bucket(total, 16)
+                    self._rpa_shapes.add(bucket)
+                    self._note_rpa_bucket(bucket, total)
                     # each emulated slice is one "mixed" iteration:
                     # dispatch carries the span, fetch the decode tokens
                     self._note_anatomy("mixed",
@@ -741,7 +737,7 @@ class MockEngine:
                           len(self._rpa_shapes))
         if rd:
             out["rpa"] = {
-                "enabled": self.rpa,
+                "enabled": self.mixed_batch,
                 "dispatches": rd,
                 "span_tokens": rt,
                 "compile_shapes": rs,

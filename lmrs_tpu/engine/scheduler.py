@@ -267,46 +267,33 @@ class ContinuousScheduler:
             pc_on = False
         # SARATHI-style mixed batches (config.EngineConfig.mixed_batch):
         # while any slot is mid-prefill AND any slot is decoding, each
-        # step dispatches ONE fused multi-token batch — every live decode
-        # row carries one real token, one prefilling slot carries a prompt
-        # slice clipped to `mixed_token_budget - decode_tokens` — through
-        # the ragged multi-token path (paged_decode_pallas_multi /
-        # paged_decode_multi_xla; the row-group kernels already
-        # parametrize per-row token counts).  Decode cadence never pauses
-        # for an admission and prefill rides the decode step's spare
-        # FLOPs.  LMRS_MIXED=0 is the kill switch (exact alternating
-        # dispatch, the LMRS_PACK_PREFILL A/B convention).  Gated off:
-        #  * int8 KV — a mixed chunk dispatches through the frozen-scale
-        #    decode path and could never OWN its slot's prefill scales;
-        #  * sp>1 meshes — ring prefill replaced chunking, so there is no
-        #    prompt slice to piggyback.
-        # Speculation yields during mixed steps: decode rows advance one
-        # token per step (drafting needs the device history buffer
-        # appended in-scan; mixed steps re-seed it instead) and full spec
-        # blocks resume once the admission wave's prefill drains — greedy
-        # outputs are identical either way (exact-distribution verify).
-        # Ragged span dispatch (RPA, ISSUE 16): ONE kernel family where
-        # every dispatch is a list of (row, query-span) pairs — decode is
+        # step dispatches ONE ragged-span batch — every live decode row
+        # carries its span (one token, or a verify span under
+        # speculation), one prefilling slot carries a prompt slice clipped
+        # to `mixed_token_budget - decode_tokens`.  Decode cadence never
+        # pauses for an admission and prefill rides the decode step's
+        # spare FLOPs.  LMRS_MIXED=0 is the kill switch (exact alternating
+        # dispatch, the LMRS_PACK_PREFILL A/B convention).  Gated off
+        # under sp>1 meshes — ring prefill replaced chunking, so there is
+        # no prompt slice to piggyback — and over a latent cache.
+        # Ragged span dispatch (ISSUE 16): ONE kernel family where every
+        # dispatch is a list of (row, query-span) pairs — decode is
         # q_len=1 rows, verify q_len=k+1 rows, a mixed step decode rows
         # plus one prefill-slice row, continuation chunks long-span rows.
         # Compile buckets collapse to (pow2 total-query-tokens, pow2 page
-        # window).  LMRS_RPA=0 restores every legacy path byte-for-byte.
-        # RPA lifts two of the gates above: int8 KV x mixed (per-row
-        # frozen scales ride the span descriptor — a fresh-start slice
-        # owns its slot's scales exactly like a fresh prefill) and
-        # spec x mixed (decode rows carry verify spans in-graph, so spec
-        # no longer yields during prefill windows).
-        self._rpa = env_bool("LMRS_RPA", True) and not self._latent
+        # window).  int8 KV mixes (per-row frozen scales ride the span
+        # descriptor — a fresh-start slice owns its slot's scales exactly
+        # like a fresh prefill) and so does speculation (decode rows carry
+        # verify spans in-graph).  The span program serves every K/V
+        # cache; a latent cache has no span program.
         if self._latent:
             logger.info("latent KV cache: packed prefill, mixed steps and "
                         "the span program are off (fresh prefill, chunked "
                         "continuation and decode blocks serve it)")
         self._rpa_fns: dict[tuple, object] = {}
         self._mixed = (engine_cfg.mixed_batch and env_bool("LMRS_MIXED", True)
-                       and (self._rpa or not self._kv_quant)
                        and not self._use_ring and not self._latent)
         self.mixed_token_budget = max(32, engine_cfg.mixed_token_budget)
-        self._mixed_fns: dict[tuple[int, int], object] = {}
         # Tree speculation on the span family (ISSUE 19): the linear draft
         # becomes LMRS_SPEC_TREE_WIDTH root-branching chains drafted
         # in-graph from the device history buffer and verified in ONE
@@ -323,7 +310,7 @@ class ContinuousScheduler:
         while (self._spec_width > 1
                and 1 + self.spec_k * (self._spec_width + 1) > 32):
             self._spec_width -= 1
-        self._spec_tree = (bool(self.spec_k) and self._rpa and self._mixed
+        self._spec_tree = (bool(self.spec_k) and self._mixed
                            and 1 + self.spec_k * (self._spec_width + 1) <= 32
                            and env_bool("LMRS_SPEC_TREE", True))
         # adaptive per-request depth: a windowed acceptance EMA per slot
@@ -542,8 +529,7 @@ class ContinuousScheduler:
         # ragged span dispatch: real query tokens per RPA dispatch (the
         # padding complement of the pow2 total-token bucket), and the
         # headline compile-zoo number — distinct (bucket, window) program
-        # shapes built so far (the legacy per-phase matrix this replaces
-        # compiled decode + spec + mixed + chunk families separately)
+        # shapes built so far
         self._h_rpa_span = h("lmrs_rpa_span_tokens",
                              buckets=POW2_TOKEN_BUCKETS,
                              help="real query-span tokens per ragged span "
@@ -975,72 +961,53 @@ class ContinuousScheduler:
                if self._prefix_cache is not None else {}),
         }
 
-    def _mixed_report(self, before: dict | None = None) -> dict:
-        """Mixed-batch block of metrics_report() / bench detail / the
-        serving A/B harness: whether mixed dispatch is armed, how many
-        fused steps ran, budget fill, and the prompt tokens that rode
-        decode steps.  With ``before`` (a ``metrics`` snapshot) the work
-        fields are WINDOWED to the delta since the snapshot — the one
-        implementation of the windowed fill formula, so bench and the
-        A/B harness can never drift apart."""
+    def _mixed_report(self) -> dict:
+        """Mixed-batch block of metrics_report(): whether mixed dispatch is
+        armed, how many fused steps ran, budget fill, and the prompt tokens
+        that rode decode steps."""
         m = self.metrics
-        b = before or {}
-        disp = m["mixed_dispatches"] - b.get("mixed_dispatches", 0)
-        fill = m["mixed_fill_sum"] - b.get("mixed_fill_sum", 0.0)
+        disp = m["mixed_dispatches"]
         return {
             "enabled": self._mixed,
             "token_budget": self.mixed_token_budget,
             "dispatches": disp,
-            "fill_ratio": round(fill / disp, 3) if disp else 0.0,
-            "prefill_tokens_piggybacked": (
-                m["prefill_tokens_piggybacked"]
-                - b.get("prefill_tokens_piggybacked", 0)),
+            "fill_ratio": (round(m["mixed_fill_sum"] / disp, 3)
+                           if disp else 0.0),
+            "prefill_tokens_piggybacked": m["prefill_tokens_piggybacked"],
         }
 
-    def _rpa_report(self, before: dict | None = None) -> dict:
-        """Ragged-span block of metrics_report() / bench detail: whether
-        RPA dispatch is armed, how many span dispatches ran, the real
-        query tokens they carried, and the HEADLINE number — distinct
-        compiled program shapes (the legacy per-phase matrix compiled
-        decode + spec + mixed + chunk families; the span family is
-        (pow2 tokens, pow2 window) only).  Same windowed-``before``
-        convention as ``_mixed_report``; compile shapes stay cumulative —
-        a zoo is a lifetime property, not a window one."""
+    def _rpa_report(self) -> dict:
+        """Ragged-span block of metrics_report(): whether the cache has a
+        span program (every K/V cache does), how many span dispatches
+        ran, the real query tokens they carried, and the HEADLINE number
+        — distinct compiled program shapes (the span family is (pow2
+        tokens, pow2 window) only)."""
         m = self.metrics
-        b = before or {}
         return {
-            "enabled": self._rpa,
-            "dispatches": (m["rpa_dispatches"]
-                           - b.get("rpa_dispatches", 0)),
-            "span_tokens": int(m["rpa_span_tokens"]
-                               - b.get("rpa_span_tokens", 0.0)),
+            "enabled": not self._latent,
+            "dispatches": m["rpa_dispatches"],
+            "span_tokens": int(m["rpa_span_tokens"]),
             "compile_shapes": m["rpa_compile_shapes"],
         }
 
-    def _spec_tree_report(self, before: dict | None = None) -> dict:
-        """Tree-speculation block of metrics_report() / bench detail /
-        the decode_split tree arm: whether the tree path is armed, how
-        many tree-span dispatches ran, mean drafted nodes and accepted
-        depth per row, and accepted tokens per dispatched row (the
-        perf_sentry ``spec_tree.accept_per_step`` trajectory metric).
-        Same windowed-``before`` convention as ``_mixed_report``."""
+    def _spec_tree_report(self) -> dict:
+        """Tree-speculation block of metrics_report(): whether the tree
+        path is armed, how many tree-span dispatches ran, mean drafted
+        nodes and accepted depth per row, and accepted tokens per
+        dispatched row."""
         m = self.metrics
-        b = before or {}
-        disp = m["spec_tree_dispatches"] - b.get("spec_tree_dispatches", 0)
-        rows = m["spec_tree_rows"] - b.get("spec_tree_rows", 0)
-        nodes = m["spec_tree_nodes_sum"] - b.get("spec_tree_nodes_sum", 0.0)
-        depth = (m["spec_accept_depth_sum"]
-                 - b.get("spec_accept_depth_sum", 0.0))
-        acc = (m["spec_accepted_tokens"]
-               - b.get("spec_accepted_tokens", 0))
+        rows = m["spec_tree_rows"]
         return {
             "enabled": self._spec_tree,
             "width": self._spec_width,
             "adaptive": self._spec_adaptive,
-            "dispatches": disp,
-            "mean_nodes": round(nodes / rows, 3) if rows else 0.0,
-            "mean_accept_depth": round(depth / rows, 3) if rows else 0.0,
-            "accept_per_step": round(acc / rows, 3) if rows else 0.0,
+            "dispatches": m["spec_tree_dispatches"],
+            "mean_nodes": (round(m["spec_tree_nodes_sum"] / rows, 3)
+                           if rows else 0.0),
+            "mean_accept_depth": (round(m["spec_accept_depth_sum"] / rows, 3)
+                                  if rows else 0.0),
+            "accept_per_step": (round(m["spec_accepted_tokens"] / rows, 3)
+                                if rows else 0.0),
         }
 
     def _prefix_cache_report(self) -> dict:
@@ -1063,28 +1030,20 @@ class ContinuousScheduler:
             "evicted_pages": s["evicted_pages"],
         }
 
-    def _host_kv_report(self, before: dict | None = None) -> dict:
-        """Host-RAM spill tier block of metrics_report() / bench detail:
-        whether the tier is armed, its budget and occupancy, and the
-        spill/prefetch work counters.  With ``before`` (a ``metrics`` snapshot) the work
-        fields are WINDOWED to the delta since the snapshot — one
-        implementation for bench and the report, same convention as
-        ``_mixed_report``."""
+    def _host_kv_report(self) -> dict:
+        """Host-RAM spill tier block of metrics_report(): whether the tier
+        is armed, its budget and occupancy, and the spill/prefetch work
+        counters."""
         pc = self._prefix_cache
         armed = pc is not None and pc.pool is not None
         m = self.metrics
-        b = before or {}
         out = {
             "enabled": armed,
             "budget_gb": round(self.cfg.host_kv_gb, 3) if armed else 0.0,
-            "spilled_hits": (m["prefix_spilled_hits"]
-                             - b.get("prefix_spilled_hits", 0)),
-            "tokens_prefetched": (m["prefix_tokens_prefetched"]
-                                  - b.get("prefix_tokens_prefetched", 0)),
-            "spill_pages": (m["prefix_spill_pages"]
-                            - b.get("prefix_spill_pages", 0)),
-            "prefetch_pages": (m["prefix_prefetch_pages"]
-                               - b.get("prefix_prefetch_pages", 0)),
+            "spilled_hits": m["prefix_spilled_hits"],
+            "tokens_prefetched": m["prefix_tokens_prefetched"],
+            "spill_pages": m["prefix_spill_pages"],
+            "prefetch_pages": m["prefix_prefetch_pages"],
         }
         if armed:
             out["spilled_pages_resident"] = pc.spilled_pages()
@@ -2719,258 +2678,6 @@ class ContinuousScheduler:
             max_new = 1
         return ids, max_new
 
-    # ---------------------------------------------------- roofline probe
-
-    def roofline_microbench(self, prefill_reps: int = 8,
-                            decode_reps: int = 4) -> dict:
-        """Device-level prefill MFU + decode HBM utilization on the live
-        engine (bench.py detail block; VERDICT r1 item 1).
-
-        Lives here, next to the compiled programs it measures, so the
-        dispatch-tuple contract stays in one file.  Chains R dispatches
-        through the donated KV pools (each call consumes the previous
-        call's pools) and fetches ONE dependent value at the end, so the
-        fixed dispatch + fetch cost amortizes over the chain; the fetch
-        round trip is measured separately and subtracted.  The pool must
-        be idle (no live slots).
-
-        On ANY failure the pools are reallocated before re-raising: a
-        mid-chain error leaves ``cache.k/v`` pointing at donated buffers,
-        and without recovery every later dispatch — including the caller's
-        primary workload — would fail on them.
-        """
-        try:
-            return self._roofline_microbench(prefill_reps, decode_reps)
-        except Exception:
-            self.cache.reallocate()
-            raise
-
-    def _roofline_microbench(self, prefill_reps: int,
-                             decode_reps: int) -> dict:
-        from lmrs_tpu.utils.perf_model import (
-            chip_spec, decode_step_bytes, kv_bytes_per_token, prefill_flops,
-            weight_bytes,
-        )
-
-        cfg_m = self.model_cfg
-        spec = chip_spec()
-        if spec is None:
-            raise RuntimeError(
-                "roofline_microbench: no peaks known for device kind "
-                f"{jax.devices()[0].device_kind!r}")
-        # drop retained prefix-cache pages: the decode probe sizes itself to
-        # the FREE pool, and a warm cache would silently shrink the roofline
-        # point (the cache rebuilds on the next real run)
-        if self._prefix_cache is not None:
-            self._prefix_cache.clear()
-        # median trivial dependent fetch = host<->device round trip
-        x = jnp.zeros((8,), jnp.float32)
-        np.asarray(jax.device_get(x + 1))  # warm the tiny program
-        rtts = []
-        for _ in range(3):
-            t0 = time.time()
-            np.asarray(jax.device_get(x + 1))
-            rtts.append(time.time() - t0)
-        rtt = sorted(rtts)[1]
-        out: dict = {"chip": spec.kind,
-                     "host_rtt_ms": round(rtt * 1e3, 1)}
-
-        # ---- prefill: one [1, S] fresh dispatch at the full bucket ------
-        S = self.max_len
-        fn = self._get_prefill_fn(
-            S, use_ring=self._use_ring and S >= self._ring_min)
-        rng = np.random.default_rng(0)
-        tokens = jnp.asarray(rng.integers(1, 255, (1, S), dtype=np.int32))
-        seq = self.cache.open_sequence(S)
-        try:
-            table = jnp.asarray(self.cache.page_table_array([seq]))
-            ones = jnp.ones((1,), jnp.float32)
-            args = (tokens, jnp.zeros((1,), jnp.int32),
-                    jnp.full((1,), S, jnp.int32),
-                    jnp.full((1,), seq.capacity(self.cache.page_size),
-                             jnp.int32),
-                    table, jax.random.PRNGKey(7), ones,
-                    jnp.zeros((1,), jnp.int32), ones)
-            k, v = self.cache.k, self.cache.v
-            # scale_rows = B: the probe's scale scatter is dropped (its rows
-            # are not real slots), but the donated buffers must be carried
-            srow = jnp.full((1,), self.B, jnp.int32)
-            tok0, k, v, self.kscale, self.vscale = fn(
-                self.params, k, v, self.kscale, self.vscale, srow, *args)
-            np.asarray(jax.device_get(tok0))
-            t0 = time.time()
-            for _ in range(prefill_reps):
-                tok0, k, v, self.kscale, self.vscale = fn(
-                    self.params, k, v, self.kscale, self.vscale, srow, *args)
-            np.asarray(jax.device_get(tok0))
-            per_prefill = max((time.time() - t0 - rtt) / prefill_reps, 1e-9)
-            self.cache.k, self.cache.v = k, v
-        finally:
-            self.cache.close_sequence(seq)
-
-        # head_tokens=1: fresh prefill gathers the last row before the LM
-        # head (forward_paged last_pos), so the full-vocab head is not run
-        fl = prefill_flops(cfg_m, S, head_tokens=1)
-        out["prefill_tokens_per_sec"] = round(S / per_prefill, 1)
-        out["model_flops_utilization"] = round(
-            fl / per_prefill / spec.peak_flops, 4)
-        out["prefill_ms"] = round(per_prefill * 1e3, 2)
-
-        # ---- decode: full-width batched steps at steady-state context ---
-        # Sized to the AVAILABLE pool (ADVICE r2): opening B full-length
-        # sequences raises OutOfPages on any budget-sized pool (num_pages>1);
-        # the probe measures steady-state bandwidth, which scales with live
-        # tokens, so a smaller per-slot context is still a valid roofline
-        # point — step_bytes below uses the same live-token total.  When the
-        # pool can't back even one page per slot, the extra rows run masked
-        # on the null page (length 0) rather than raising.
-        B = self.B
-        free = self.cache.allocator.free_count
-        if free == 0:
-            # probing an exhausted pool would raise OutOfPages on the very
-            # first open_sequence; an all-masked decode measures nothing,
-            # so report the skip instead of crashing the detail block
-            out["decode_probe_skipped"] = "no free KV pages"
-            return out
-        rows = min(B, free)
-        per_slot = max(1, min(self.cache.max_pages_per_slot, free // rows))
-        live = min(int(S * 0.75), per_slot * self.cache.page_size)
-        seqs = [self.cache.open_sequence(live) for _ in range(rows)]
-        try:
-            w = self.cache.max_pages_per_slot
-            onesB = jnp.ones((B,), jnp.float32)
-            row_live = np.zeros((B,), np.int32)
-            row_live[:rows] = live
-            table_rows = list(seqs) + [None] * (B - rows)  # null-page rows
-            dargs = (jnp.asarray(rng.integers(1, 255, (B,), dtype=np.int32)),
-                     jnp.asarray(row_live),
-                     jnp.asarray(self.cache.page_table_array(table_rows)[:, :w]),
-                     jnp.asarray(row_live > 0), jax.random.PRNGKey(8), onesB,
-                     jnp.zeros((B,), jnp.int32), onesB)
-            dfn = self._get_decode_fn(w)
-            k, v = self.cache.k, self.cache.v
-            srowsd = jnp.arange(self.B, dtype=jnp.int32)
-            toks, n_valid, k, v = dfn(
-                self.params, k, v, self.kscale, self.vscale, srowsd,
-                *dargs)  # warm
-            np.asarray(jax.device_get(n_valid))
-            t0 = time.time()
-            for _ in range(decode_reps):
-                toks, n_valid, k, v = dfn(
-                    self.params, k, v, self.kscale, self.vscale, srowsd,
-                    *dargs)
-            np.asarray(jax.device_get(n_valid))
-            wall = time.time() - t0 - rtt
-            self.cache.k, self.cache.v = k, v
-        finally:
-            for s_ in seqs:
-                self.cache.close_sequence(s_)
-
-        per_step = max(wall / (decode_reps * self.decode_block), 1e-9)
-        step_bytes = decode_step_bytes(cfg_m, rows * live,
-                                       quantized=bool(self.cfg.quantize),
-                                       kv_quantized=bool(self._kv_quant))
-        out["decode_tokens_per_sec"] = round(rows / per_step, 1)
-        out["decode_step_ms"] = round(per_step * 1e3, 3)
-        out["hbm_bw_utilization"] = round(
-            step_bytes / per_step / spec.peak_hbm_bw, 4)
-        out["decode_step_gb"] = round(step_bytes / 1e9, 2)
-        out["weight_gb"] = round(weight_bytes(cfg_m) / 1e9, 2)
-        out["kv_kb_per_token"] = round(kv_bytes_per_token(cfg_m) / 1e3, 1)
-        return out
-
-    def rowcost_microbench(self, lo: int = 64, hi: int = 256,
-                           reps: int = 3) -> dict:
-        """Per-row fixed cost of the ragged decode attention at this
-        engine's exact shape (kv heads, head dim, page size, slot count),
-        grouped vs per-row — the bench-detail attribution for the
-        multi-row page walk.  One attention layer's fused kernel chained
-        inside a jitted ``fori_loop`` (output feeds the next q, pools ride
-        the carry), timed via the shared RTT-cancelling chain method
-        (utils/perf_model.time_chain — the same implementation
-        decode_rowcost.py uses, so the two probes' us/row numbers stay
-        comparable).
-
-        Probes standalone bf16 pools (one live page per row), never the
-        engine's own cache: it can run between waves without disturbing
-        live state.  Returns {} off-TPU or under a multi-device mesh —
-        interpret-mode chains would measure the emulator."""
-        from lmrs_tpu.utils.perf_model import time_chain
-        from lmrs_tpu.utils.platform import on_tpu
-
-        if (not (self._use_ragged and on_tpu() and self._single_device())
-                or self._latent):  # the probe times the K/V decode kernel
-            return {}
-        from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
-
-        cfg_m = self.model_cfg
-        kh, hd, ps = cfg_m.n_kv_heads, cfg_m.hd, self.cfg.page_size
-        B = self.B
-        rng = np.random.default_rng(0)
-        q0 = jnp.asarray(rng.standard_normal((B, cfg_m.n_heads, hd)),
-                         jnp.bfloat16)
-        kn = jnp.asarray(rng.standard_normal((B, kh, hd)), jnp.bfloat16)
-        vn = jnp.asarray(rng.standard_normal((B, kh, hd)), jnp.bfloat16)
-        kp0 = jnp.asarray(rng.standard_normal((B + 1, kh, ps, hd)),
-                          jnp.bfloat16)
-        vp0 = jnp.asarray(rng.standard_normal((B + 1, kh, ps, hd)),
-                          jnp.bfloat16)
-        pt = jnp.asarray((1 + np.arange(B))[:, None], jnp.int32)
-        kl = jnp.full((B,), min(64, ps), jnp.int32)
-
-        def make_chain(iters: int, g: int):
-            @jax.jit
-            def chain(q, kp, vp):
-                def body(_, carry):
-                    q, kp, vp = carry
-                    out, kp, vp = paged_decode_pallas_fused(
-                        q, kn, vn, kp, vp, pt, kl, row_group=g)
-                    return (out.astype(q.dtype), kp, vp)
-
-                return jax.lax.fori_loop(0, iters, body, (q, kp, vp))
-
-            return lambda: chain(q0, kp0, vp0)[0]
-
-        out: dict = {"decode_row_group": self._row_group}
-        arms = {"per_row": 1}
-        if self._row_group > 1:
-            arms["grouped"] = self._row_group
-        for name, g in arms.items():
-            per_kernel = time_chain(
-                lambda iters, g=g: make_chain(iters, g), lo, hi, reps)
-            out[f"decode_row_us_{name}"] = round(per_kernel / B * 1e6, 3)
-        if self._rpa:
-            # unified span kernel, q_len=1 rows — the per-row number
-            # perf_sentry tracks against the retired fused path
-            # (decode_row_us_rpa: a regression here fails the report arm)
-            from lmrs_tpu.ops.paged_attention import ragged_spans_pallas
-            q_starts_np, total = pack_spans(np.ones((B,), np.int32))
-            qf0 = jnp.asarray(rng.standard_normal(
-                (total, cfg_m.n_heads, hd)), jnp.bfloat16)
-            knf = jnp.asarray(rng.standard_normal((total, kh, hd)),
-                              jnp.bfloat16)
-            vnf = jnp.asarray(rng.standard_normal((total, kh, hd)),
-                              jnp.bfloat16)
-            qs = jnp.asarray(q_starts_np)
-            ql = jnp.ones((B,), jnp.int32)
-
-            def make_chain_rpa(iters: int):
-                @jax.jit
-                def chain(q, kp, vp):
-                    def body(_, carry):
-                        q, kp, vp = carry
-                        o, kp, vp = ragged_spans_pallas(
-                            q, knf, vnf, kp, vp, pt, kl, qs, ql)
-                        return (o.astype(q.dtype), kp, vp)
-
-                    return jax.lax.fori_loop(0, iters, body, (q, kp, vp))
-
-                return lambda: chain(qf0, kp0, vp0)[0]
-
-            per_kernel = time_chain(make_chain_rpa, lo, hi, reps)
-            out["decode_row_us_rpa"] = round(per_kernel / B * 1e6, 3)
-        return out
-
     # ------------------------------------------- page growth / preemption
 
     def _ensure_decode_capacity(self, slots, queue, kv_lens, last_tok,
@@ -3309,277 +3016,23 @@ class ContinuousScheduler:
     def _mixed_iteration(self, slots, queue, results, fresh, kv_lens,
                          last_tok, active, temps, top_k, top_p, t_enq,
                          last_block_t):
-        """One SARATHI mixed step: every live decode row advances ONE
-        token and one prefilling slot's next prompt slice (clipped to
-        ``mixed_token_budget - decode_tokens``) rides the SAME fused
-        multi-token dispatch — decode cadence continues through the
-        admission.  Returns ``(handled, last_block_t)``; ``handled=False``
-        (nothing to mix, or the budget left no room for a slice) falls
-        back to the alternating path with no state disturbed beyond
-        capacity growth.
-
-        Speculation note: decode rows advance un-speculated during mixed
-        steps (the device history buffer is re-seeded per advanced row so
-        full spec blocks resume cleanly once the prefill drains); greedy
-        outputs are unchanged either way — exact-distribution verify
-        emits exactly the greedy tokens."""
+        """One SARATHI mixed step: every live decode row advances and one
+        prefilling slot's next prompt slice (clipped to
+        ``mixed_token_budget - decode_tokens``) rides the SAME span
+        dispatch (``_rpa_mixed_iteration``) — decode cadence continues
+        through the admission.  Returns ``(handled, last_block_t)``;
+        ``handled=False`` (nothing to mix, or the budget left no room for
+        a slice) falls back to the alternating path with no state
+        disturbed beyond capacity growth."""
         pf = self._pick_mixed_prefill(slots)
         has_decode = any(
             slots[b] is not None and active[b]
             and slots[b].phase == "decode" for b in range(self.B))
         if pf is None or not has_decode:
             return False, last_block_t
-        if self._rpa:
-            # ragged span dispatch (LMRS_RPA, the default): the mixed step
-            # is a span list through the unified kernel — and under
-            # speculation the decode rows carry verify spans, so spec no
-            # longer yields during prefill windows
-            return self._rpa_mixed_iteration(
-                pf, slots, queue, results, fresh, kv_lens, last_tok,
-                active, temps, top_k, top_p, t_enq, last_block_t)
-
-        def rearm(stalled):
-            for b in stalled:  # stalled rows rejoin the next dispatch
-                if slots[b] is not None:
-                    active[b] = True
-
-        # grow decode rows by the ONE token this step appends; under pool
-        # pressure the youngest decode slot preempts, exactly as a block
-        # dispatch would (prefill-phase slots are never victims)
-        stalled = self._ensure_decode_capacity(slots, queue, kv_lens,
-                                               last_tok, active,
-                                               extra_tokens=1)
-        rows = [b for b in range(self.B)
-                if slots[b] is not None and active[b]
-                and slots[b].phase == "decode"]
-        budget_left = self.mixed_token_budget - len(rows)
-        if not rows or budget_left < 16:
-            # every decode row stalled (alternating path owns the stall
-            # recovery) or the live rows already exhaust the budget
-            # (budget misconfigured below the slot count): alternate this
-            # step rather than dispatch a degenerate slice
-            rearm(stalled)
-            return False, last_block_t
-
-        st_pf = slots[pf]
-        pos = st_pf.prefill_pos
-        c = min(len(st_pf.prompt_ids) - pos, budget_left,
-                self.prefill_chunk)
-        t_bucket = min(_pow2_bucket(c, 16), self.max_len)
-        c = min(c, t_bucket)  # pow2 bucket >= c whenever max_len is pow2
-        is_final = pos + c >= len(st_pf.prompt_ids)
-
-        # [B, T] operands: decode rows carry their pending token at index
-        # 0, the prefill row its slice at 0..C-1.  Padding tokens write at
-        # positions past each row's live length — the row's own not-yet-
-        # reached positions (overwritten by the next real token at that
-        # position) or, past its allocated pages/table span, the null page
-        # — and the per-token causal limit (position < base + j + 1)
-        # masks them from every real query, so no ragged per-row width is
-        # needed.  Rows carrying no work keep lens 0: the kernel's
-        # n_pages==0 fast path zeroes their output without a walk.
-        T = t_bucket
-        tokens = np.zeros((self.B, T), np.int32)
-        base = np.zeros((self.B,), np.int32)
-        lens_inc = np.zeros((self.B,), np.int32)
-        last_idx = np.zeros((self.B,), np.int32)
-        table_rows = [None] * self.B
-        max_pages = 1
-        live_tokens = 0
-        for b in rows:
-            st = slots[b]
-            tokens[b, 0] = last_tok[b]
-            base[b] = st.kv_len
-            lens_inc[b] = st.kv_len + T
-            table_rows[b] = st.seq
-            live_tokens += st.kv_len
-            max_pages = max(max_pages,
-                            self.cache.pages_needed(st.kv_len + 1))
-        tokens[pf, :c] = st_pf.prompt_ids[pos: pos + c]
-        base[pf] = pos
-        lens_inc[pf] = pos + T
-        last_idx[pf] = c - 1
-        table_rows[pf] = st_pf.seq
-        max_pages = max(max_pages, self.cache.pages_needed(pos + c))
-        w = min(_pow2_bucket(max_pages, 4), self.cache.max_pages_per_slot)
-        table = self.cache.page_table_array(table_rows)
-
-        self._h_occupancy.observe(len(rows) / self.B)
-        self._c_decode_dispatches.inc()
-        self._h_mixed_fill.observe(
-            (len(rows) + c) / self.mixed_token_budget)
-        self._c_piggybacked.inc(c)
-        self._c_prefill_tokens.inc(c)
-        self._h_prefill_batch.observe(c)
-        if (self._row_group > 1 and self._use_ragged
-                and self._kernel_mesh() is None):
-            # same convention as the spec block: rows dispatch in slot
-            # order (no balanced permutation — the mixed shape is B-wide
-            # and the prefill row pins its slot anyway)
-            g = self._row_group
-            self._h_group_occupancy.observe(
-                (len(rows) + 1) / (-(-self.B // g) * g))
-        now = time.time()
-        if last_block_t is not None:
-            self._h_block_gap.observe(now - last_block_t)
-            self._slo.observe_gap(now - last_block_t)
-        last_block_t = now
-        flops = self._perf.prefill_flops(c, kv_start=pos)
-        st_pf.prefill_pos = pos + c
-
-        self._key, sub = jax.random.split(self._key)
-        args = (self.params, self.cache.k, self.cache.v,
-                jnp.asarray(tokens), jnp.asarray(base),
-                jnp.asarray(lens_inc), jnp.asarray(last_idx),
-                jnp.asarray(table[:, :w]), sub, jnp.asarray(temps),
-                jnp.asarray(top_k), jnp.asarray(top_p))
-        key_ = ("mixed", T, w)
-        warm = key_ in self._ran_ok
-        if not warm:
-            self._wd_grace_cold()
-        t_disp = time.time()
-        with self._an.dispatch(
-                "mixed", key_, rows=len(rows) + 1, row_slots=self.B,
-                q_tokens=len(rows) + c, prompt_tokens=c,
-                q_slots=self.B * T, ctx_tokens=live_tokens + pos,
-                cold=not warm):
-            nxt, self.cache.k, self.cache.v = \
-                self._get_mixed_fn(T, w)(*args)
-        self._note_ran_ok(key_)
-        with self._an.seg("fetch"):
-            nxt = np.asarray(self._timed_get(nxt))
-        t_done = time.time()
-
-        # exact-split attribution: the fused step's per-row token counts
-        # are known, so no decode-share estimate is involved (note_block's
-        # EMA decomposition stays for the sequenced-prefill block path)
-        with self._an.seg("finish"):
-            extra_flops, cold_pf = self._consume_prefill_attr()
-            nb = self._perf.note_mixed_step(
-                t_disp, t_done, len(rows), live_tokens,
-                flops + extra_flops, warm=warm and not cold_pf)
-            self._attr_last_gb = round(nb / 1e9, 3)
-            if self._cost.enabled:
-                # fused-step ledger note: every decode row advanced
-                # exactly one token; the piggybacked slice joins the
-                # pending prefill rows (the ISSUE's exact per-row split,
-                # no estimates)
-                dcost, pcost = self._roofline_phase_costs(
-                    nb, flops + extra_flops)
-                self._cost.note_step(
-                    max(0.0, t_done - t_disp),
-                    decode_rows=[(slots[b].req, 1,
-                                  len(slots[b].seq.pages))
-                                 for b in rows],
-                    prefill_rows=(self._consume_prefill_cost()
-                                  + [(st_pf.req, c, flops)]),
-                    decode_cost_s=dcost, prefill_cost_s=pcost)
-
-            for b in rows:
-                st = slots[b]
-                tok = int(nxt[b])
-                st.generated.append(tok)
-                st.kv_len += 1
-                kv_lens[b] = st.kv_len
-                last_tok[b] = tok
-                self._c_decode_tokens.inc(1)
-                if self._tr:
-                    self._tr.instant("decode_block", ts=now,
-                                     tid=self._tid(st.req),
-                                     args={"tokens": 1})
-                self._maybe_finish(b, slots, results, active, fresh,
-                                   kv_lens, last_tok)
-                if self.spec_k:
-                    self._spec_stale.add(b)
-            if is_final:
-                # the slice completed the prompt: enter decode with the
-                # first token this very step sampled (index C-1 = the
-                # last prompt token's row — the fresh-prefill sampling
-                # contract)
-                st = st_pf
-                st.phase = "decode"
-                st.t_decode_start = time.time()
-                if self._tr:
-                    self._tr.complete("prefill", st.t_admit,
-                                      st.t_decode_start,
-                                      tid=self._tid(st.req),
-                                      args={"prompt_tokens":
-                                            len(st.prompt_ids)})
-                st.kv_len = len(st.prompt_ids)
-                kv_lens[pf] = st.kv_len
-                active[pf] = True
-                self._cache_insert(st)
-                tok0 = int(nxt[pf])
-                st.generated.append(tok0)
-                self._note_first_token(st, t_enq)
-                last_tok[pf] = tok0
-                if self.spec_k:
-                    self._spec_stale.add(pf)
-                self._maybe_finish(pf, slots, results, active, fresh,
-                                   kv_lens, last_tok)
-            if self._tr:
-                self._tr.complete("decode_block", now, time.time(),
-                                  args={"active": len(rows),
-                                        "tokens": len(rows),
-                                        "hbm_gb": self._attr_last_gb,
-                                        "mixed": True,
-                                        "prefill_tokens": c})
-            rearm(stalled)
-        return True, last_block_t
-
-    def _get_mixed_fn(self, t: int, w: int):
-        """Fused mixed-step program: one [B, T] multi-token dispatch where
-        decode rows carry ONE real token (index 0) and the piggybacked
-        prefill row its slice (indices 0..C-1), through the ragged
-        multi-token row-group path — the kernel already parametrizes
-        per-row token counts via per-token causal limits, so decode and
-        prefill rows differ only in how many of their T positions are
-        real.  Samples one token per row at its host-provided last real
-        index (the LM head runs on that row only — at real vocabularies a
-        full [B, T, V] head would be the packing win given back).
-        Compiled per (slice bucket, page window): the bounded mixed shape
-        zoo (log2 slice buckets x log2 windows)."""
-        key_ = (t, w)
-        if key_ in self._mixed_fns:
-            return self._mixed_fns[key_]
-        cfg = self.model_cfg
-        max_len = self.max_len
-        rope_max = self.max_len
-        # same gate as the spec verify fn: the multi-token kernel has no
-        # shard_map wrapper, so under a real multi-device mesh the XLA
-        # multi path serves (one window gather — still not the per-layer
-        # window_prefill gather)
-        use_ragged = self._use_ragged and self._kernel_mesh() is None
-        interp = self._interpret
-        row_group = self._row_group
-
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def mixed_step(params, k_pages, v_pages, tokens, base, lens_inc,
-                       last_idx, table, key, temps, tk, tp):
-            # rope positions: each row's tokens sit at consecutive
-            # absolute positions from its own base (kv_len for decode
-            # rows, the slice start for the prefill row); the write span
-            # derives from lens_inc inside the multi path (UNclamped per
-            # its contract — max_pos masks any overhang)
-            positions = jnp.minimum(
-                base[:, None] + jnp.arange(t)[None, :], max_len - 1)
-            out = forward_paged(
-                params, cfg, tokens, positions, k_pages, v_pages, table,
-                lens_inc, rope_max, use_ragged_kernel=use_ragged,
-                multi_decode=True, interpret=interp, last_pos=last_idx,
-                decode_row_group=row_group,
-            )
-            logits, k_pages, v_pages = out[:3]
-            # single step, no scan/vmap wrapper: sample_logits' lax.cond
-            # fast paths are safe here (ops/sampling.py NOTE)
-            nxt = sample_logits(logits[:, 0], key, temps, tk, tp)
-            return nxt, k_pages, v_pages
-
-        logger.info("compiling mixed step: B=%d slice_bucket=%d window=%d "
-                    "pages (ragged_kernel=%s row_group=%d)", self.B, t, w,
-                    use_ragged, row_group)
-        self._mixed_fns[key_] = mixed_step
-        return mixed_step
+        return self._rpa_mixed_iteration(
+            pf, slots, queue, results, fresh, kv_lens, last_tok,
+            active, temps, top_k, top_p, t_enq, last_block_t)
 
     # ------------------------------------------- ragged span dispatch (RPA)
 
@@ -3870,15 +3323,14 @@ class ContinuousScheduler:
     def _rpa_mixed_iteration(self, pf, slots, queue, results, fresh,
                              kv_lens, last_tok, active, temps, top_k,
                              top_p, t_enq, last_block_t):
-        """One ragged-span mixed step (the RPA default): every live decode
-        row advances as a span — ONE token plain, a (1 + spec_k)-token
-        verify span under speculation — and one prefilling slot's next
-        slice rides the SAME dispatch as a long span row.  Two legacy
-        composition gates are gone here: int8 KV pools mix (a fresh-start
+        """One ragged-span mixed step: every live decode row advances as a
+        span — ONE token plain, a (1 + spec_k)-token verify span under
+        speculation — and one prefilling slot's next slice rides the SAME
+        dispatch as a long span row.  int8 KV pools mix (a fresh-start
         slice owns its slot's frozen scales through the span descriptor,
-        every other row clamps to them — the PERF.md follow-up) and spec
-        blocks no longer yield during prefill windows.  Same
-        (handled, last_block_t) contract as _mixed_iteration."""
+        every other row clamps to them) and spec blocks do not yield
+        during prefill windows.  Same (handled, last_block_t) contract as
+        _mixed_iteration."""
         spec = bool(self.spec_k)
         tree = spec and self._spec_tree
         k = self.spec_k
@@ -4310,7 +3762,7 @@ class ContinuousScheduler:
             else:
                 pending.append(self._dispatch_packed(bin_items))
         for (fresh, s_bucket, w, ring), items in groups.items():
-            if (not fresh and self._rpa and self._use_ragged
+            if (not fresh and not self._latent and self._use_ragged
                     and self._kernel_mesh() is None):
                 # windowed continuation chunks ride the unified span
                 # program: the per-(s_bucket, w) chunked-prefill matrix
@@ -4410,7 +3862,7 @@ class ContinuousScheduler:
         return {"wide_tokens": wide_tokens, "kv_page_reads": kv_page_reads}
 
     def _dispatch_rpa_chunks(self, items) -> tuple[object, list]:
-        """Windowed continuation chunks as ragged SPANS (LMRS_RPA with the
+        """Windowed continuation chunks as ragged SPANS (a K/V cache with the
         kernel armed): every chunk is one long-span row of a single
         unified dispatch.  Returns the ``(tok0_device_array, [(slot,
         row)])`` pending-entry contract of ``_advance_prefills``; the

@@ -342,7 +342,7 @@ def forward_paged(
                                 # page walk, ops/paged_attention.py); 1 =
                                 # per-row grid (the LMRS_MULTIROW=0 path)
     spans: tuple | None = None,  # (q_starts [B], q_lens [B], row_flat [Tp]):
-                                 # ragged span mode (LMRS_RPA) — tokens is
+                                 # ragged span mode — tokens is
                                  # ONE flat [1, Tp] row holding every row's
                                  # query span; kv_lens is then the context
                                  # BEFORE this dispatch (span base), and
@@ -499,7 +499,7 @@ def forward_paged(
         k = apply_rope(k, positions, sin, cos)
 
         if spans is not None:
-            # ragged span mode (LMRS_RPA): every phase is a list of
+            # ragged span mode: every phase is a list of
             # (row, query-span) pairs — write + attention run in the ONE
             # span kernel (or its XLA twin).  kv_lens here is the context
             # BEFORE the dispatch: span token j of row r sits at absolute

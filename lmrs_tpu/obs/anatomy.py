@@ -65,10 +65,10 @@ SEGMENTS: tuple[str, ...] = ("admit", "plan", "draft", "dispatch",
 _SEG_SET = frozenset(SEGMENTS)
 
 # what a scheduler dispatch site may call itself (``dispatch(program, ...)``);
-# the first five compute prompt positions and feed the prefill_* counters
+# the first four compute prompt positions and feed the prefill_* counters
 PROGRAMS: tuple[str, ...] = ("prefill", "packed", "prefill_chunk", "rpa",
-                             "mixed", "decode", "spec")
-PROMPT_PROGRAMS = frozenset(PROGRAMS[:5])
+                             "decode", "spec")
+PROMPT_PROGRAMS = frozenset(PROGRAMS[:4])
 # the additive fields of one dispatch record, as the table and the report
 # carry them (the report adds ``cold_ms`` and, per program, ``keys``)
 RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
@@ -81,7 +81,7 @@ RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
 MOE_FIELDS: tuple[str, ...] = ("moe_routed_pairs", "moe_expert_tokens_max",
                                "moe_expert_tokens_mean", "moe_extra_passes")
 
-# iteration step classes (the decode_split/serving_latency split axis)
+# iteration step classes (the report's per-class split axis)
 CLASSES: tuple[str, ...] = ("plain", "mixed", "spec", "prefill")
 
 # host-overhead histogram: 1 µs (an idle-ish pass) .. 10 s (a compile)

@@ -425,9 +425,8 @@ class CostLedger:
                 "live_requests": live}
 
     def report(self, before: dict | None = None) -> dict:
-        """The ``cost`` block of ``metrics_report()`` / bench detail.
-        With ``before`` (a prior ``report()``), the work fields window to
-        the delta — same convention as ``_mixed_report``."""
+        """The ``cost`` block of ``metrics_report()``.  With ``before`` (a
+        prior ``report()``), the work fields window to the delta."""
         if not self.enabled:
             return {"enabled": False}
         with self._lock:

@@ -1,8 +1,6 @@
 """Live per-dispatch performance attribution + on-demand profiler capture.
 
-Every PERF.md MFU / HBM-bandwidth number so far was an offline bench
-artifact (``scheduler.roofline_microbench``, RTT-amortized chains).  This
-module turns the same roofline model (utils/perf_model) into a LIVE
+This module turns the roofline model (utils/perf_model) into a LIVE
 signal on the serving path:
 
 * ``DispatchAttribution`` — owned by the continuous scheduler, fed from
@@ -272,10 +270,10 @@ class DispatchAttribution:
         model byte cost (the ``hbm_gb`` trace-span arg).
 
         ``span_tokens`` is the SPAN-LEVEL decode token count from a
-        ragged span dispatch (LMRS_RPA): total decode-side query tokens
-        in the step — ``(1 + spec_k) * n_live`` when decode rows carry
-        verify spans.  Defaults to ``n_live`` (one token per live row,
-        the legacy fused step), under which the byte model is unchanged."""
+        ragged span dispatch: total decode-side query tokens in the step
+        — ``(1 + spec_k) * n_live`` when decode rows carry verify spans.
+        Defaults to ``n_live`` (one token per live row), under which the
+        byte model is unchanged."""
         self.note_gap(t_start, t_end)
         if span_tokens is None or span_tokens <= n_live or n_live <= 0:
             nbytes = self.decode_bytes(1, n_live, live_tokens)

@@ -1,17 +1,26 @@
-"""Paged decode attention: single-token queries against a paged KV pool.
+"""Paged attention over a paged KV pool: decode, multi-token verify, spans.
 
-Two implementations with one contract:
+Every Pallas kernel here has an XLA twin with the same contract:
 
-* ``paged_decode_xla`` — gather-based fallback (any platform): gathers the
-  slot's pages into a contiguous [B, W, K, hd] window and runs masked
-  attention.  Cost ∝ the (bucketed) window, independent of real lengths.
-* ``paged_decode_pallas`` — ragged Pallas kernel (TPU): grid over (batch,);
-  each program walks ONLY its row's live pages — a dynamic ``fori_loop``
-  bound from SMEM — DMA-ing K/V pages HBM→VMEM and folding them into an
-  online softmax.  Decode cost is proportional to the tokens actually in
-  the cache (the Ragged Paged Attention idea, PAPERS.md), which is the
-  whole point of paging: the bytes a decode step must move are exactly the
-  live KV bytes.
+* ``paged_decode_pallas_fused`` — write-fused ragged decode (TPU), the
+  decode program's kernel: each program scatters its row's new token into
+  the pool in place and walks ONLY that row's live pages — a dynamic
+  ``fori_loop`` bound from SMEM — DMA-ing K/V pages HBM→VMEM and folding
+  them into an online softmax.  Decode cost is proportional to the tokens
+  actually in the cache (the Ragged Paged Attention idea, PAPERS.md), which
+  is the whole point of paging: the bytes a decode step must move are
+  exactly the live KV bytes.  ``paged_decode_fused_sharded`` is the same
+  kernel under a tensor-parallel mesh.
+* ``paged_decode_pallas_multi`` — the same write + walk for k+1 queries a
+  row (speculative verify).
+* ``ragged_spans_pallas`` — ragged SPANS: rows of any query length (decode
+  rows, prefill chunks) in one flat token axis, each walked against its own
+  pages; a narrow path (8-query tiles) and a wide one (128-256 queries a
+  tile, long continuation spans).
+* ``paged_decode_xla`` / ``paged_decode_multi_xla`` / ``ragged_spans_xla``
+  — the gather-based twins (any platform): gather the slot's pages into a
+  contiguous window and run masked attention.  Cost ∝ the (bucketed)
+  window, independent of real lengths.
 
 Cache layout: [P_total, K, page_size, hd], PAGE-major: one page's ALL kv
 heads are a single contiguous [K, page_size, hd] DMA, and the kv-head axis
@@ -2015,134 +2024,3 @@ def paged_decode_fused_sharded(
     )
     return fn(q, k_new, v_new, k_pages, v_pages, page_tables, kv_lens,
               *extra_args)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "row_group"))
-def paged_decode_pallas(
-    q: jnp.ndarray,            # [B, H, hd]
-    k_pages: jnp.ndarray,      # [P, K, ps, hd]
-    v_pages: jnp.ndarray,      # [P, K, ps, hd]
-    page_tables: jnp.ndarray,  # [B, W]
-    kv_lens: jnp.ndarray,      # [B]
-    interpret: bool = False,
-    row_group: int = 1,        # rows per program (multi-row page walk)
-) -> jnp.ndarray:
-    b, h, hd = q.shape
-    _, kh, ps, _ = k_pages.shape
-    n_rep = h // kh
-    # group query heads by kv head: [B, K, n_rep, hd].  The group dim is a
-    # Mosaic block sublane dim, so pad it to 8 rows (bf16/f32 tiling both
-    # divide 8; the MXU pads small dots to 8x128 anyway, so this is free) —
-    # n_rep=1 (MHA) would otherwise fail sublane alignment on real TPUs.
-    n_rep_p = -(-n_rep // 8) * 8
-    qg = q.reshape(b, kh, n_rep, hd)
-    if n_rep_p != n_rep:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, n_rep_p - n_rep), (0, 0)))
-
-    g = max(1, min(row_group, b))
-    if g > 1:
-        # walk-only multi-row variant (no RMW): one program walks g rows
-        # through the shared double-buffered pipeline, priming row r+1's
-        # first page during row r's epilogue.  Used by the rowcost probe's
-        # group arm; the serving path runs the fused variant.
-        bp = -(-b // g) * g
-        qg = _pad_rows(qg, bp)
-        page_tables = _pad_rows(page_tables, bp)
-        kv_lens = _pad_rows(kv_lens, bp)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(bp // g,),
-            in_specs=[
-                pl.BlockSpec((g, kh, n_rep_p, hd),
-                             lambda gi, *_: (gi, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((g, kh, n_rep_p, hd),
-                                   lambda gi, *_: (gi, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),
-                pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
-                pltpu.VMEM((kh, n_rep_p, hd), jnp.float32),
-                pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
-                pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
-        )
-
-        def group_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_scr, v_scr, acc_scr, m_scr, l_scr, sem):
-            gi = pl.program_id(0)
-            nrows = pl.num_programs(0) * g
-            base = gi * g
-
-            def prime_row(row):
-                @pl.when(_n_live_pages(pt_ref, len_ref, row, ps) > 0)
-                def _():
-                    _fetch_page(pt_ref, k_hbm, v_hbm, k_scr, v_scr, sem,
-                                row, 0, 0)
-
-            @pl.when(gi == 0)
-            def _bootstrap():
-                prime_row(0)
-
-            for j in range(g):
-                row = base + j
-                nxt = row + 1
-
-                def after_walk(nxt=nxt):
-                    @pl.when(nxt < nrows)
-                    def _():
-                        prime_row(nxt)
-
-                _ragged_decode_all_heads(
-                    pt_ref, len_ref, q_ref.at[j], k_hbm, v_hbm, o_ref.at[j],
-                    k_scr, v_scr, acc_scr, m_scr, l_scr, sem,
-                    page_size=ps, sm_scale=hd**-0.5, kh=kh,
-                    row=row, external_prime=True, after_walk=after_walk,
-                )
-
-        out = pl.pallas_call(
-            group_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((bp, kh, n_rep_p, hd), q.dtype),
-            interpret=interpret,
-        )(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32), qg,
-          k_pages, v_pages)
-        return out[:b, :, :n_rep].reshape(b, h, hd)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, kh, n_rep_p, hd), lambda bi, *_: (bi, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # k pool stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, kh, n_rep_p, hd), lambda bi, *_: (bi, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages x2
-            pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
-            pltpu.VMEM((kh, n_rep_p, hd), jnp.float32),
-            pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
-            pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-
-    def kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-               k_scr, v_scr, acc_scr, m_scr, l_scr, sem):
-        _ragged_decode_all_heads(
-            pt_ref, len_ref,
-            q_ref.at[0], k_hbm, v_hbm, o_ref.at[0],
-            k_scr, v_scr, acc_scr, m_scr, l_scr, sem,
-            page_size=ps, sm_scale=hd**-0.5, kh=kh,
-        )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, n_rep_p, hd), q.dtype),
-        interpret=interpret,
-    )(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32), qg, k_pages, v_pages)
-    return out[:, :, :n_rep].reshape(b, h, hd)

@@ -51,7 +51,7 @@ def setup_logging(quiet: bool = False, level: int | None = None,
     """Configure the ``lmrs`` logger tree.  quiet → WARNING (main.py
     --quiet).  ``stream`` defaults to stdout (the reference logs to
     stdout, main.py:32-40); artifact-emitting callers whose stdout is a
-    machine-read contract (bench.py's one-JSON-line) pass stderr.
+    machine-read contract (one JSON line) pass stderr.
     Safe to call repeatedly — later calls update level/stream/format."""
     root = logging.getLogger("lmrs")
     formatter: logging.Formatter = (

@@ -1,5 +1,5 @@
 """Roofline accounting: FLOP / byte counts + chip peaks for MFU and
-HBM-bandwidth utilization reporting (bench.py, docs/PERF.md).
+HBM-bandwidth utilization reporting (obs/perf.py, obs/ledger.py).
 
 The reference publishes no perf model at all (its compute is a vendor API);
 these counts are the standard decoder-transformer roofline: dense-matmul
@@ -166,38 +166,3 @@ def decode_step_bytes(cfg: ModelConfig, total_live_tokens: int,
     if kv_quantized:
         kv /= 2
     return weight_bytes(cfg, quantized) + kv
-
-
-def time_chain(make_chain, lo: int, hi: int, reps: int = 3) -> float:
-    """Per-iteration wall time of a chained on-device computation, by the
-    LONG-minus-SHORT difference.  ``make_chain(iters)`` must return a
-    zero-arg callable that runs ``iters`` chained steps in ONE dispatch
-    (e.g. a jitted ``fori_loop`` whose carry threads the output) and
-    returns a device value to fetch.  Timing the difference between the
-    hi- and lo-length chains and dividing by the iteration delta cancels
-    the dispatch and fetch cost exactly — naive per-call timing of a
-    small dispatch is mostly that fixed cost and produced garbage fits,
-    including negative slopes (docs/PERF.md round 5).  Each chain length
-    compiles + settles once, then takes best-of-``reps``.
-
-    THE one implementation of the chained-probe method: the standalone
-    probes (scripts/decode_rowcost.py) and the in-engine attribution
-    (scheduler.rowcost_microbench) both call it, so the methodology —
-    warmup discipline, best-of timing, the slope arithmetic — cannot
-    drift between them and their us/row numbers stay comparable."""
-    import time
-
-    import jax
-    import numpy as np
-
-    walls = {}
-    for iters in (lo, hi):
-        fn = make_chain(iters)
-        np.asarray(jax.device_get(fn()))  # compile + settle
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.time()
-            np.asarray(jax.device_get(fn()))
-            best = min(best, time.time() - t0)
-        walls[iters] = best
-    return (walls[hi] - walls[lo]) / (hi - lo)

@@ -35,11 +35,10 @@ PASS gate (all must hold):
      sum to the host totals exactly and ``live_requests == 0`` once the
      flood drains (nothing leaked through the admission gate).
 
-Writes a ``FAIRNESS_r*.json``-shaped artifact with ``--artifact`` so
-perf_sentry tracks the fairness trajectory across rounds.
+Writes its verdict and metrics as a JSON artifact with ``--artifact``.
 
 CPU-only, ~15 s.  Usage:
-    JAX_PLATFORMS=cpu python scripts/ab_fairness.py [--artifact FAIRNESS_r1.json]
+    JAX_PLATFORMS=cpu python scripts/ab_fairness.py [--artifact fairness.json]
 """
 
 from __future__ import annotations
@@ -153,8 +152,7 @@ def run_arm(qos_on: bool) -> dict:
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--artifact", default=None,
-                    help="write a FAIRNESS_r*.json artifact here "
-                         "(perf_sentry trajectory input)")
+                    help="write the verdict and metrics as JSON here")
     args = ap.parse_args(argv)
     on = run_arm(qos_on=True)
     off = run_arm(qos_on=False)
@@ -190,7 +188,7 @@ def main(argv: list[str] | None = None) -> int:
     }
     print(json.dumps(report, indent=2))
     if args.artifact:
-        # the perf_sentry artifact shape: rc + parsed.detail metrics
+        # rc + parsed.detail metrics
         with open(args.artifact, "w", encoding="utf-8") as f:
             json.dump({"rc": 0 if ok else 1, "ok": ok,
                        "parsed": {"detail": detail}}, f, indent=2)

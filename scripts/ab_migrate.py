@@ -24,7 +24,7 @@ The arms differ ONLY by ``LMRS_KV_MIGRATE`` at construction time:
 The headline metric is ``migrate.tokens_from_fabric_ratio``: of the
 preamble tokens B re-served during the resume, the fraction that came
 off the fabric (reused from imported page sets) rather than cold
-re-prefill.  perf_sentry tracks it across ``MIGRATE_r*.json`` rounds.
+re-prefill.
 
 PASS gate (all must hold):
   1. migrate_on fabric ratio >= 0.5 (the ISSUE 20 floor);
@@ -38,7 +38,7 @@ PASS gate (all must hold):
      on arm, 0 on the off arm.
 
 CPU-only, ~10 s.  Usage:
-    JAX_PLATFORMS=cpu python scripts/ab_migrate.py [--artifact MIGRATE_r1.json]
+    JAX_PLATFORMS=cpu python scripts/ab_migrate.py [--artifact migrate.json]
 """
 
 from __future__ import annotations
@@ -164,8 +164,7 @@ def run_arm(migrate_on: bool) -> dict:
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--artifact", default=None,
-                    help="write a MIGRATE_r*.json artifact here "
-                         "(perf_sentry trajectory input)")
+                    help="write the verdict and metrics as JSON here")
     args = ap.parse_args(argv)
     on = run_arm(migrate_on=True)
     off = run_arm(migrate_on=False)
@@ -202,7 +201,7 @@ def main(argv: list[str] | None = None) -> int:
     }
     print(json.dumps(report, indent=2))
     if args.artifact:
-        # the perf_sentry artifact shape: rc + parsed.detail metrics
+        # rc + parsed.detail metrics
         with open(args.artifact, "w", encoding="utf-8") as f:
             json.dump({"rc": 0 if ok else 1, "ok": ok,
                        "parsed": {"detail": detail}}, f, indent=2)

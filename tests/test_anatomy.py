@@ -494,10 +494,10 @@ _PATHS = {
     "span_mixed": ({}, dict(mixed_batch=True, prefill_chunk=64,
                             decode_block=3, num_pages=40), tiny_model,
                    [["short probe", _LONG, "third " * 12]], "rpa"),
-    "legacy_mixed": ({"LMRS_RPA": "0"},
-                     dict(mixed_batch=True, prefill_chunk=64,
-                          decode_block=3, num_pages=40), tiny_model,
-                     [["short probe", _LONG, "third " * 12]], "mixed"),
+    "span_mixed_spec": ({}, dict(mixed_batch=True, speculate_k=3,
+                                 prefill_chunk=64, decode_block=3,
+                                 num_pages=40), tiny_model,
+                        [["short probe", _LONG, "third " * 12]], "rpa"),
     "decode": ({}, dict(), tiny_model, [["decode probe"]], "decode"),
     "spec": ({"LMRS_SPEC_TREE": "0"}, dict(speculate_k=4), tiny_model,
              [["spec probe alpha", "spec probe bravo"]], "spec"),
@@ -580,6 +580,42 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
                 e["args"])
     finally:
         disable_tracing()
+        eng.shutdown()
+
+
+def test_dispatched_programs_are_the_documented_closed_list():
+    """docs/OBSERVABILITY.md names the programs a dispatch record can
+    carry: that list is ``PROGRAMS`` (six, and no ``mixed``), and a run
+    with mixed steps, a warm prefix cache and speculation together
+    dispatches nothing outside it."""
+    import re
+    from pathlib import Path
+
+    from lmrs_tpu.obs.anatomy import PROGRAMS
+
+    doc = (Path(__file__).resolve().parent.parent / "docs"
+           / "OBSERVABILITY.md").read_text(encoding="utf-8")
+    row = next(ln for ln in doc.splitlines()
+               if ln.startswith("| `program` |"))
+    documented = re.findall(r"`(\w+)` \(", row.split("|")[2])
+    assert documented == list(PROGRAMS) == [
+        "prefill", "packed", "prefill_chunk", "rpa", "decode", "spec"]
+    eng = JaxEngine(_cfg(mixed_batch=True, prefix_cache=True, speculate_k=3,
+                         prefill_chunk=64, decode_block=3, num_pages=64),
+                    tiny_model())
+    try:
+        for start in (0, 10):  # the second batch meets a warm cache
+            out = eng.generate_batch([
+                GenerationRequest(prompt=_LONG + f"question {start + i}",
+                                  request_id=start + i, temperature=0.0,
+                                  max_new_tokens=6) for i in range(3)])
+            assert all(r.error is None for r in out)
+        sched = eng._scheduler
+        assert sched.audit() == []
+        assert sched.metrics["mixed_dispatches"] > 0
+        programs = sched.anatomy_report()["programs"]
+        assert programs and set(programs) <= set(documented), sorted(programs)
+    finally:
         eng.shutdown()
 
 

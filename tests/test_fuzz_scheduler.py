@@ -280,10 +280,11 @@ def test_fuzzed_mixed_admission_bursts(seed, monkeypatch):
 
 
 @pytest.mark.parametrize("seed", [19, 53])
-def test_fuzzed_rpa_admission_bursts(seed, monkeypatch):
+def test_fuzzed_rpa_admission_bursts(seed):
     """Ragged-span dispatch (ISSUE 16) under the same randomized
-    mid-decode admission bursts: greedy token-identity LMRS_RPA=0 vs 1
-    (the span arm must actually dispatch span programs), span-arm
+    mid-decode admission bursts: greedy token-identity of span-dispatched
+    mixed steps against alternating dispatch (``mixed_batch=False``; the
+    span arm must actually dispatch span programs), span-arm
     determinism, the request contract, and a clean auditor — the fuzzed
     counterpart of the hand-written A/B matrix in test_rpa.py."""
     rng = random.Random(seed)
@@ -306,10 +307,10 @@ def test_fuzzed_rpa_admission_bursts(seed, monkeypatch):
     trigger = {initial[0].request_id: 0,
                initial[-1].request_id: 1}
 
-    def run(rpa: str):
-        monkeypatch.setenv("LMRS_RPA", rpa)
+    def run(mixed: bool):
         eng = JaxEngine(EngineConfig(backend="jax", scheduler="continuous",
-                                     max_tokens=24, seed=0, **scenario), mc)
+                                     max_tokens=24, seed=0,
+                                     mixed_batch=mixed, **scenario), mc)
         fired = set()
 
         def on_result(res, submit):
@@ -333,10 +334,10 @@ def test_fuzzed_rpa_admission_bursts(seed, monkeypatch):
         return sorted((r.request_id, r.text, r.finish_reason,
                        r.completion_tokens) for r in out), m
 
-    base, m_off = run("0")
+    base, m_off = run(False)
     assert m_off["rpa_dispatches"] == 0
-    span1, m_on = run("1")
-    span2, _ = run("1")
+    span1, m_on = run(True)
+    span2, _ = run(True)
     assert span1 == span2, scenario  # determinism
     assert span1 == base, scenario   # greedy A/B identity
     assert m_on["rpa_dispatches"] > 0, scenario

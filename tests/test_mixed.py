@@ -119,9 +119,7 @@ def test_mixed_identity_on_interpret_kernels(monkeypatch, spec_k):
     sched = on._scheduler
     assert sched.metrics["mixed_dispatches"] > 0, "mixed path not exercised"
     assert sched._use_ragged, "multi-token kernel silently degraded"
-    # RPA (the default) compiles span programs; LMRS_RPA=0 the legacy
-    # mixed family — either way a fused shape must actually have built
-    assert sched._rpa_fns or sched._mixed_fns, "no mixed shape compiled"
+    assert sched._rpa_fns, "no span program compiled"
     assert sched.audit() == []
     on.shutdown()
     assert got == want
@@ -185,22 +183,6 @@ def test_mixed_metrics_and_report_shape():
     # the block-gap scope label (docs/PERF.md): batch waves vs serving
     # cadence must be distinguishable from the report alone
     assert "decode_block_gap_scope" in rep
-    eng.shutdown()
-
-
-def test_mixed_gated_off_under_int8_kv(monkeypatch):
-    """LEGACY dispatch (LMRS_RPA=0): kv_quantize=int8 cannot own a mixed
-    chunk's prefill scales through the [B, T] fused path, so the
-    dispatcher must disarm itself (and say so in the report)."""
-    monkeypatch.setenv("LMRS_RPA", "0")
-    mc = tiny_model()
-    eng = JaxEngine(_cfg(True, page_size=32, kv_quantize="int8",
-                         prefix_cache=False), mc)
-    assert not eng._scheduler._mixed
-    assert eng._scheduler.metrics_report()["mixed_batch"]["enabled"] is False
-    out = eng.generate_batch(_mix_requests(2))
-    assert all(r.error is None for r in out)
-    assert eng._scheduler.metrics["mixed_dispatches"] == 0
     eng.shutdown()
 
 

@@ -1,5 +1,5 @@
-"""Roofline accounting (utils/perf_model.py) — the MFU/bandwidth numbers in
-bench.py are only as honest as these counts."""
+"""Roofline accounting (utils/perf_model.py) — the live MFU/bandwidth
+attribution (obs/perf.py) is only as honest as these counts."""
 
 import jax
 import pytest

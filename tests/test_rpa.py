@@ -13,12 +13,10 @@ Two layers of contract:
   the mixed path's existing convention, where the references park them
   on the null page.
 
-* scheduler-level — greedy outputs must be token-identical with
-  ``LMRS_RPA=0`` (legacy per-phase dispatch) vs ``1`` across the
-  prefix-cache x speculation x int8-KV matrix, the kill switch must be
-  byte-for-byte (legacy program caches populated, span caches empty),
-  and the one-bucket-family claim must show up as a compile-shape count
-  no larger than the legacy per-phase families for the same workload.
+* scheduler-level — greedy outputs of the span-dispatched mixed step must
+  be token-identical with alternating dispatch (``mixed_batch=False``)
+  across the prefix-cache x speculation x int8-KV matrix, and the span
+  family must be the only mixed family a scheduler compiles.
 """
 
 from __future__ import annotations
@@ -397,8 +395,8 @@ def _mix_requests(n: int = 4) -> list[GenerationRequest]:
 
 
 def _run(cfg: EngineConfig, mc, reqs):
-    """Returns (texts, metrics, program-cache key sets) for one engine
-    run; audits clean."""
+    """Returns (texts, metrics, keys of the programs that ran) for one
+    engine run; audits clean."""
     eng = JaxEngine(cfg, mc)
     out = eng.generate_batch(reqs)
     sched = eng._scheduler
@@ -406,92 +404,64 @@ def _run(cfg: EngineConfig, mc, reqs):
     assert all(r.error is None for r in out)
     texts = [(r.text, r.finish_reason, r.completion_tokens) for r in out]
     m = dict(sched.metrics)
-    caches = {"rpa": set(sched._rpa_fns),
-              "mixed": set(sched._mixed_fns),
-              "window": set(sched._prefill_window_fns),
-              "decode": set(sched._decode_fns)}
+    ran = set(sched._ran_ok)
     eng.shutdown()
-    return texts, m, caches
+    return texts, m, ran
 
 
 @pytest.mark.parametrize("prefix_cache", [True, False])
 @pytest.mark.parametrize("spec_k", [0, 3])
-def test_rpa_greedy_identity_matrix(monkeypatch, prefix_cache, spec_k):
-    """LMRS_RPA=0 vs 1 greedy token identity across prefix-cache x
-    speculation with mixed batches armed — the ISSUE 16 acceptance bar.
-    The span arm must actually dispatch span programs."""
+def test_rpa_greedy_identity_matrix(prefix_cache, spec_k):
+    """Span-dispatched mixed steps vs alternating dispatch
+    (``mixed_batch=False``): greedy token identity across prefix-cache x
+    speculation — the ISSUE 16 acceptance bar.  The span arm must
+    actually dispatch span programs."""
     mc = tiny_model()
     reqs = _mix_requests()
-    cfg = lambda: _cfg(prefix_cache=prefix_cache, speculate_k=spec_k)
-    monkeypatch.setenv("LMRS_RPA", "0")
-    want, m_off, _ = _run(cfg(), mc, reqs)
-    assert m_off["rpa_dispatches"] == 0  # kill switch really off
-    monkeypatch.setenv("LMRS_RPA", "1")
-    got, m_on, _ = _run(cfg(), mc, reqs)
+    cfg = lambda mixed: _cfg(prefix_cache=prefix_cache, speculate_k=spec_k,
+                             mixed_batch=mixed)
+    want, m_off, _ = _run(cfg(False), mc, reqs)
+    assert m_off["rpa_dispatches"] == 0  # the reference arm alternates
+    got, m_on, _ = _run(cfg(True), mc, reqs)
     assert m_on["rpa_dispatches"] > 0, "span path not exercised"
     assert got == want
 
 
 @pytest.mark.parametrize("spec_k", [0, 3])
-def test_rpa_greedy_identity_int8_kv(monkeypatch, spec_k):
-    """The forbidden compositions, armed: int8 KV x mixed (x spec) runs
-    through the span path with greedy outputs identical to the legacy
-    per-phase dispatch of the same int8 engine."""
+def test_rpa_greedy_identity_int8_kv(spec_k):
+    """int8 KV x mixed (x spec) runs through the span path with greedy
+    outputs identical to alternating dispatch of the same int8 engine."""
     mc = tiny_model()
     reqs = _mix_requests()
-    cfg = lambda: _cfg(page_size=32, kv_quantize="int8",
-                       prefix_cache=False, speculate_k=spec_k)
-    monkeypatch.setenv("LMRS_RPA", "0")
-    want, m_off, _ = _run(cfg(), mc, reqs)
+    cfg = lambda mixed: _cfg(page_size=32, kv_quantize="int8",
+                             prefix_cache=False, speculate_k=spec_k,
+                             mixed_batch=mixed)
+    want, m_off, _ = _run(cfg(False), mc, reqs)
     assert m_off["rpa_dispatches"] == 0
-    monkeypatch.setenv("LMRS_RPA", "1")
-    got, m_on, _ = _run(cfg(), mc, reqs)
+    got, m_on, _ = _run(cfg(True), mc, reqs)
     assert m_on["rpa_dispatches"] > 0, "int8 span path not exercised"
     assert m_on["mixed_dispatches"] > 0, "int8 x mixed not armed"
     assert got == want
 
 
-def test_rpa_killswitch_byte_for_byte(monkeypatch):
-    """LMRS_RPA=0 restores the legacy dispatch layer wholesale: no span
-    program compiles, the legacy mixed family compiles instead, and the
-    outputs match the span arm byte for byte."""
-    mc = tiny_model()
-    reqs = _mix_requests()
+def test_rpa_is_the_only_mixed_family(monkeypatch):
+    """The retired switch is no longer read: a scheduler built with
+    ``LMRS_RPA=0`` in the environment serves its mixed steps with span
+    programs, and no program of another mixed family runs."""
     monkeypatch.setenv("LMRS_RPA", "0")
-    want, m_off, c_off = _run(_cfg(), mc, reqs)
-    assert not c_off["rpa"], "legacy arm compiled a span program"
-    assert c_off["mixed"], "legacy mixed family did not compile"
-    assert m_off["rpa_compile_shapes"] == 0
-    monkeypatch.setenv("LMRS_RPA", "1")
-    got, m_on, c_on = _run(_cfg(), mc, reqs)
-    assert c_on["rpa"], "span arm compiled no span program"
-    assert not c_on["mixed"], "span arm still compiled legacy mixed fns"
-    assert m_on["rpa_compile_shapes"] == len(c_on["rpa"])
-    assert got == want
-
-
-def test_rpa_compile_shapes_do_not_exceed_legacy(monkeypatch):
-    """One bucket family: for the same workload the span arm's distinct
-    compiled program count must not exceed the legacy per-phase families
-    it replaces (mixed [t,w] + prefill-window [s,w]), and the span
-    metric must report real span tokens."""
-    mc = tiny_model()
-    reqs = _mix_requests(6)
-    monkeypatch.setenv("LMRS_RPA", "0")
-    _, m_off, c_off = _run(_cfg(max_batch_slots=3), mc, reqs)
-    legacy = len(c_off["mixed"]) + len(c_off["window"])
-    assert legacy > 0, "workload never exercised the retired families"
-    monkeypatch.setenv("LMRS_RPA", "1")
-    _, m_on, c_on = _run(_cfg(max_batch_slots=3), mc, reqs)
-    assert 0 < len(c_on["rpa"]) <= legacy
-    assert m_on["rpa_span_tokens"] > 0
-    assert m_on["rpa_span_tokens"] >= m_on["rpa_dispatches"]
+    _, m, ran = _run(_cfg(), tiny_model(), _mix_requests())
+    assert m["mixed_dispatches"] > 0 and m["rpa_dispatches"] > 0
+    families = {k[0] for k in ran if isinstance(k[0], str)}
+    assert "rpa" in families
+    assert families <= {"rpa", "rpa_spec", "prefill", "packed", "decode",
+                        "specfn"}, families
+    assert m["rpa_compile_shapes"] == len(
+        {k for k in ran if k[0] in ("rpa", "rpa_spec")})
 
 
 def test_rpa_report_block_shape():
-    """The windowed ``rpa`` report block bench/serving_latency consume:
-    keys exist, dispatch counts agree with the counters, compile_shapes
-    stays cumulative."""
+    """The ``rpa`` block of ``metrics_report()``: keys exist, dispatch
+    counts agree with the counters, compile_shapes stays cumulative."""
     mc = tiny_model()
     eng = JaxEngine(_cfg(), mc)
     eng.generate_batch(_mix_requests())
@@ -505,9 +475,9 @@ def test_rpa_report_block_shape():
     eng.shutdown()
 
 
-def test_mock_engine_rpa_block(monkeypatch):
-    """No-device knob parity: the mock exposes the same ``rpa`` metrics
-    block and the LMRS_RPA kill switch disarms it."""
+def test_mock_engine_rpa_block():
+    """No-device parity: the mock exposes the same ``rpa`` metrics
+    block."""
     from lmrs_tpu.engine.mock import MockEngine
 
     reqs = [GenerationRequest(prompt="one " * 30, request_id=0),
@@ -519,7 +489,3 @@ def test_mock_engine_rpa_block(monkeypatch):
     assert blk["enabled"] and blk["dispatches"] > 0
     assert blk["span_tokens"] >= blk["dispatches"]
     assert blk["compile_shapes"] >= 1
-    monkeypatch.setenv("LMRS_RPA", "0")
-    off = MockEngine(mixed_token_budget=64)
-    off.generate_batch(reqs)
-    assert "rpa" not in off.engine_metrics()

@@ -403,40 +403,6 @@ def test_preemption_under_page_pressure_preserves_output():
         assert g.completion_tokens == w.completion_tokens
 
 
-def test_roofline_microbench_refuses_unknown_device(cont_engine):
-    """No peaks are known for the CPU backend, so the probe has nothing to
-    divide by: it raises instead of reporting against assumed v5e peaks, and
-    leaves the engine serving."""
-    with pytest.raises(RuntimeError, match="no peaks known"):
-        cont_engine._scheduler.roofline_microbench(prefill_reps=1,
-                                                   decode_reps=1)
-    out = cont_engine.generate_batch(
-        [GenerationRequest(prompt="still serving", request_id=0,
-                           max_new_tokens=4)])
-    assert out[0].error is None and out[0].completion_tokens > 0
-
-
-def test_roofline_microbench_smoke(cont_engine, monkeypatch):
-    """The roofline probe shares the compiled-program arg contract with the
-    scheduler; this smoke run catches signature drift off-chip.  The peaks
-    are handed in HERE (the test's own steering): the numbers only mean
-    something on a TPU — bench.py."""
-    from lmrs_tpu.utils import perf_model
-
-    monkeypatch.setattr(perf_model, "chip_spec",
-                        lambda: perf_model.ChipSpec("test", 197e12, 819e9))
-    out = cont_engine._scheduler.roofline_microbench(prefill_reps=2,
-                                                     decode_reps=1)
-    for key in ("prefill_tokens_per_sec", "decode_tokens_per_sec"):
-        assert out[key] > 0, out
-    for key in ("model_flops_utilization", "hbm_bw_utilization"):
-        # tiny CPU model: utilization rounds to ~0; presence + range only
-        assert 0 <= out[key] < 1.5, out
-    # pool must be fully released afterwards
-    cache = cont_engine._scheduler.cache
-    assert cache.allocator.free_count == cache.num_pages - 1
-
-
 def test_stalled_slot_keeps_first_token():
     """Regression: a slot that finishes prefill but must STALL (pool pages
     held by a mid-prefill neighbor, no preemptable decode victim) must not
