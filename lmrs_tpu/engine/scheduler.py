@@ -45,7 +45,7 @@ from lmrs_tpu.engine.prefix_cache import PrefixCache
 from lmrs_tpu.fleet.qos import maybe_qos
 from lmrs_tpu.models.transformer import forward_paged
 from lmrs_tpu.ops.paged_attention import (pack_spans, pow2_bucket,
-                                          span_walk_counts)
+                                          span_walk_counts, whole_pages)
 from lmrs_tpu.obs import (POW2_TOKEN_BUCKETS, RATIO_BUCKETS, CostLedger,
                           DispatchAttribution, MetricsRegistry, SLOEngine,
                           dump_postmortem, get_tracer, maybe_anatomy, req_tid,
@@ -3833,6 +3833,8 @@ class ContinuousScheduler:
                     rows=len(items), row_slots=n, q_tokens=batch_tokens,
                     prompt_tokens=batch_tokens, q_slots=n * s_bucket,
                     ctx_tokens=sum(p for _, _, _, p, _ in items),
+                    page_writes=(n * whole_pages(
+                        s_bucket, self.cache.page_size, w) if fresh else 0),
                     cold=cold):
                 fn = (self._get_prefill_fn(s_bucket, use_ring=ring)
                       if fresh
@@ -4094,9 +4096,12 @@ class ContinuousScheduler:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1])[None], tokens.shape)
             # Padded tail positions can exceed this sequence's allocated
-            # pages (prompt bucket > budget); clamp their page writes INTO
-            # the owned region — garbage there is masked by kv_lens, whereas
-            # an out-of-table write would corrupt another sequence's page.
+            # pages (prompt bucket > budget): clamp them into the table.
+            # forward_paged keeps the padding off every real token's slot
+            # (the row form sends it to the null page; the page form leaves
+            # it behind kv_lens in the row's own pages, and on the null page
+            # past the allocation): written AT the clamped slot it would
+            # land on the last real token of a prompt that fills its pages.
             write_pos = jnp.minimum(positions, alloc_tokens[:, None] - 1)
             out = forward_paged(
                 params, cfg, tokens, write_pos, k_pages, v_pages, table,

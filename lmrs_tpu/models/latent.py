@@ -286,7 +286,8 @@ def forward_paged(params, cfg: ModelConfig, tokens, positions, pool,
     from lmrs_tpu.models.transformer import _use_flash_prefill
     from lmrs_tpu.ops.mla_attention import (mla_paged_decode_pallas,
                                             mla_paged_decode_xla)
-    from lmrs_tpu.ops.paged_attention import scatter_kv_rows
+    from lmrs_tpu.ops.paged_attention import (scatter_kv_pages,
+                                              scatter_kv_rows, whole_pages)
 
     b, s = tokens.shape
     ps = pool.shape[2]
@@ -300,6 +301,15 @@ def forward_paged(params, cfg: ModelConfig, tokens, positions, pool,
         page_tables, jnp.clip(positions // ps, 0, page_tables.shape[1] - 1),
         axis=1)
     offsets = positions % ps
+    if not is_decode:
+        # the bucket's padding goes to the null page, as in
+        # transformer.forward_paged (never onto the row's last real token)
+        chunk_len = kv_lens - positions[:, 0] if window_prefill else kv_lens
+        page_idx = jnp.where(jnp.arange(s)[None, :] < chunk_len[:, None],
+                             page_idx, 0)
+    # a fresh prefill writes whole pages, as transformer.forward_paged does
+    n_whole = (0 if is_decode or window_prefill
+               else whole_pages(s, ps, page_tables.shape[1]))
 
     def layer_fn(experts, carry, xs):
         x, pool = carry
@@ -307,9 +317,13 @@ def forward_paged(params, cfg: ModelConfig, tokens, positions, pool,
         g_tables = li * n_pool + page_tables
         h = rms_norm(x, lp["ln_attn"]["scale"], cfg.norm_eps)
         q_nope, q_rope, c_kv, k_rope = project(lp, cfg, h, positions, sin, cos)
-        # the one write of a token's row (scatter_kv_rows: pool axis 1 is 1)
-        pool = scatter_kv_rows(pool, li * n_pool + page_idx, offsets,
-                               latent_rows(cfg, c_kv, k_rope)[:, :, None, :])
+        # the one write of a token's row (pool axis 1 is 1): a page at a
+        # time where the program is a fresh prefill over whole pages
+        rows = latent_rows(cfg, c_kv, k_rope)[:, :, None, :]
+        if n_whole:
+            pool = scatter_kv_pages(pool, g_tables[:, :n_whole], rows)
+        else:
+            pool = scatter_kv_rows(pool, li * n_pool + page_idx, offsets, rows)
         with jax.named_scope("mla.attn"):
             if is_decode:
                 q_cat = absorb_query(lp, cfg, q_nope[:, 0], q_rope[:, 0])

@@ -373,7 +373,13 @@ def forward_paged(
 
     Prefill (S>1, fresh sequence starting at position 0) attends the current
     tokens directly (flash path eligible); decode (S==1) attends the paged
-    pool — via the ragged Pallas kernel on TPU or the gather fallback.
+    pool — via the ragged Pallas kernel on TPU or the gather fallback.  A
+    fresh prefill over whole pages (S a multiple of the page size) writes
+    page ``page_tables[b, j]`` whole from positions [j*ps, (j+1)*ps)
+    (``scatter_kv_pages``): what a row holds past its allocation goes to the
+    null page (table column 0).  Every other prefill writes a row at a time
+    and sends the bucket's padding (tokens at and past the row's length) to
+    the null page too.
 
     ``window_prefill`` is the chunked-prefill path (SARATHI-style,
     PAPERS.md): S>1 queries at positions ``>= 0`` that must also see KV
@@ -414,7 +420,9 @@ def forward_paged(
         paged_decode_xla,
         ragged_spans_pallas,
         ragged_spans_xla,
+        scatter_kv_pages,
         scatter_kv_rows,
+        whole_pages,
     )
     from lmrs_tpu.ops.quant import (kv_dequant, kv_quant, kv_quant_tokens,
                                     kv_scale_from)
@@ -472,8 +480,29 @@ def forward_paged(
             page_tables, jnp.clip(positions // ps, 0, page_tables.shape[1] - 1),
             axis=1,
         )  # [B, S] logical page per token
+    # a prefill row's tokens: those below its length (a window chunk counts
+    # from its first position).  ``positions`` cannot say: the scheduler
+    # clamps them into the row's allocation
+    is_prefill = ((window_prefill or not is_decode) and not multi_decode
+                  and spans is None and segment_ids is None)
+    valid = None
+    if is_prefill:
+        chunk_len = kv_lens - positions[:, 0] if window_prefill else kv_lens
+        valid = jnp.arange(s)[None, :] < chunk_len[:, None]
+        if token_pages is None:
+            # the bucket's padding goes to the null page.  Clamped onto the
+            # row's last slot it would land ON the last real token of a
+            # prompt that fills its pages exactly (length == allocation)
+            page_idx = jnp.where(valid, page_idx, 0)
     offsets = positions % ps
-    batch_r = jnp.arange(b)[:, None]
+    # pages a row that this program writes whole: a fresh prefill's rows sit
+    # at positions 0 .. S-1 (the scheduler's fresh-prefill contract), so
+    # where S is whole pages the wave is B x S/ps of them.  Everything else
+    # (a window that starts mid-page, packed rows, decode, a bucket of 64)
+    # writes a row at a time.  Static at trace time, like the program kind
+    n_whole = 0
+    if is_prefill and not window_prefill and token_pages is None:
+        n_whole = whole_pages(s, ps, page_tables.shape[1])
 
     def layer_fn(carry, xs):
         # The page pools ride the scan CARRY (not xs/ys) and the layer axis
@@ -590,9 +619,6 @@ def forward_paged(
                 # (chunked) dispatch only for rows whose chunk starts at
                 # position 0 — later chunks reuse (and clamp to) the first
                 # chunk's scales, since written pages can't be requantized
-                chunk_len = (kv_lens if is_fresh
-                             else kv_lens - positions[:, 0])
-                valid = jnp.arange(s)[None, :] < chunk_len[:, None]
                 s_k = kv_scale_from(k, valid)
                 s_v = kv_scale_from(v, valid)
                 rows_i = (jnp.arange(b, dtype=jnp.int32)
@@ -658,13 +684,10 @@ def forward_paged(
             attn_out = attn[:, None]  # [B, 1, H, hd]
             return _finish_layer(lp, x, attn_out, kp_all, vp_all, ksc, vsc)
 
-        # scatter current K/V into the page-major pool: [L*P, K, ps, hd]
-        # at [g_page_idx[b,s], :, offsets[b,s]] (updates are [B, S, K, hd]
-        # — the K/V's own layout; scatter_kv_rows says why it is spelled
-        # the way it is).  Int8 pools store the
-        # quantized rows; attention below reads the ORIGINAL k/v wherever
-        # the current tokens are the whole context (fresh prefill), so only
-        # pool readers pay quantization error
+        # write current K/V into the page-major pool [L*P, K, ps, hd].
+        # Int8 pools store the quantized rows; attention below reads the
+        # ORIGINAL k/v wherever the current tokens are the whole context
+        # (fresh prefill), so only pool readers pay quantization error
         k_store, v_store = k, v
         if kv_scales is not None:
             if tok_scales is not None:  # packed: per-token segment scales
@@ -673,8 +696,20 @@ def forward_paged(
             else:
                 k_store = kv_quant(k, row_scales[0])
                 v_store = kv_quant(v, row_scales[1])
-        kp_all = scatter_kv_rows(kp_all, g_page_idx, offsets, k_store)
-        vp_all = scatter_kv_rows(vp_all, g_page_idx, offsets, v_store)
+        if n_whole:
+            # fresh prefill: positions [j*ps, (j+1)*ps) of row b ARE page
+            # page_tables[b, j], so the wave goes in a whole page at a time
+            # (scatter_kv_pages).  A column past a row's allocation is 0
+            # (the null page); padding inside the allocation lies behind
+            # kv_lens for every reader
+            kp_all = scatter_kv_pages(kp_all, g_tables[:, :n_whole], k_store)
+            vp_all = scatter_kv_pages(vp_all, g_tables[:, :n_whole], v_store)
+        else:
+            # a row at a time, at [g_page_idx[b,s], :, offsets[b,s]]
+            # (updates are [B, S, K, hd], the K/V's own layout;
+            # scatter_kv_rows says why it is spelled the way it is)
+            kp_all = scatter_kv_rows(kp_all, g_page_idx, offsets, k_store)
+            vp_all = scatter_kv_rows(vp_all, g_page_idx, offsets, v_store)
 
         if is_decode:
             attn = paged_decode_xla(q[:, 0], kp_all, vp_all, g_tables, kv_lens,

@@ -24,9 +24,10 @@ shapes".  This module names every microsecond between two dispatches:
   device dispatch AND its record: the site says what the dispatch carried
   (rows and row slots, real and padded query positions, prompt positions,
   context tokens, the span kernel's wide-tile tokens and page fetches,
-  cold or warm) and the record folds into a per-program,
-  per-key table that ``snapshot()`` / ``report(before=...)`` window like
-  the segment totals.  On a cold key the segment's wall is the compile.
+  the pages a fresh prefill writes whole, cold or warm) and the record
+  folds into a per-program, per-key table that ``snapshot()`` /
+  ``report(before=...)`` window like the segment totals.  On a cold key
+  the segment's wall is the compile.
   The ragged-span bucket economics of PR 16/18 (``buckets``,
   ``rpa_pad_waste_ratio``) are a view of the table's ``rpa`` program.
 * Every segment goes through ``obs.trace.span``: ``sched.<segment>`` in a
@@ -74,7 +75,7 @@ PROMPT_PROGRAMS = frozenset(PROGRAMS[:4])
 RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
                                   "q_tokens", "prompt_tokens", "q_slots",
                                   "ctx_tokens", "wide_tokens",
-                                  "kv_page_reads", "cold")
+                                  "kv_page_reads", "page_writes", "cold")
 
 # what a routed model's dispatches add to their record once their counts
 # are back (``note_moe``); absent from a dense model's records and report
@@ -267,7 +268,8 @@ class StepAnatomy:
         self._unretired: list[int] = []  # ids no fetch has retired yet
         # flat sums of the same records, for ``scheduler.metrics``
         self._flat = {"prefill_dispatches": 0, "prefill_query_tokens": 0,
-                      "prefill_token_slots": 0, "rpa_wide_tokens": 0,
+                      "prefill_token_slots": 0, "prefill_page_writes": 0,
+                      "rpa_wide_tokens": 0,
                       "rpa_kv_page_reads": 0, "cold_dispatches": 0,
                       "cold_seconds": 0.0}
         # routed models only (the scheduler sets ``has_moe`` for one): a
@@ -408,7 +410,8 @@ class StepAnatomy:
     def dispatch(self, program: str, key: tuple, *, rows: int,
                  row_slots: int, q_tokens: int, prompt_tokens: int,
                  q_slots: int, ctx_tokens: int, cold: bool,
-                 wide_tokens: int = 0, kv_page_reads: int = 0) -> _Dispatch:
+                 wide_tokens: int = 0, kv_page_reads: int = 0,
+                 page_writes: int = 0) -> _Dispatch:
         """The ``dispatch`` segment of one device dispatch, with what it
         carried.  ``program`` is one of ``PROGRAMS`` and ``key`` the site's
         own compile key; ``rows`` carry work out of ``row_slots`` operand
@@ -420,7 +423,11 @@ class StepAnatomy:
         runs in the ragged span kernel also says what the kernel will do
         with it (``ops/paged_attention.span_walk_counts``, the kernel's
         rule on the host): ``wide_tokens`` of the query positions fall in
-        wide tiles, and its page walks fetch ``kv_page_reads`` pages."""
+        wide tiles, and its page walks fetch ``kv_page_reads`` pages.  A
+        prefill dispatch says how many pages a layer it writes into the
+        pool WHOLE (``page_writes``: ``row_slots`` x bucket / page size for
+        a fresh prefill over whole pages, ``ops/paged_attention.
+        whole_pages``; 0 where the program writes a row at a time)."""
         if program not in PROGRAMS:
             raise ValueError(f"unknown dispatch program {program!r} "
                              f"(want one of {PROGRAMS})")
@@ -430,7 +437,8 @@ class StepAnatomy:
             "q_tokens": int(q_tokens), "prompt_tokens": int(prompt_tokens),
             "q_slots": int(q_slots), "ctx_tokens": int(ctx_tokens),
             "wide_tokens": int(wide_tokens),
-            "kv_page_reads": int(kv_page_reads), "cold": bool(cold)})
+            "kv_page_reads": int(kv_page_reads),
+            "page_writes": int(page_writes), "cold": bool(cold)})
 
     def _fold(self, r: dict) -> None:
         rec = self._table.get((r["program"], r["key"]))
@@ -446,6 +454,7 @@ class StepAnatomy:
             flat["prefill_dispatches"] += 1
             flat["prefill_query_tokens"] += r["q_tokens"]
             flat["prefill_token_slots"] += r["q_slots"]
+            flat["prefill_page_writes"] += r["page_writes"]
         if r["program"] == "rpa":
             flat["rpa_wide_tokens"] += r["wide_tokens"]
             flat["rpa_kv_page_reads"] += r["kv_page_reads"]
@@ -475,11 +484,12 @@ class StepAnatomy:
 
     def counters(self) -> dict:
         """The flat sums ``ContinuousScheduler.metrics`` carries: prefill
-        dispatches, their real query positions and the positions their
-        operands held (the prompt programs), the span kernel's wide-tile
-        tokens and page fetches (the ``rpa`` program), cold dispatches and
-        their wall (all programs); for a routed model, the ``MOE_FIELDS``
-        sums over all programs."""
+        dispatches, their real query positions, the positions their
+        operands held and the pages they wrote whole (the prompt
+        programs), the span kernel's wide-tile tokens and page fetches
+        (the ``rpa`` program), cold dispatches and their wall (all
+        programs); for a routed model, the ``MOE_FIELDS`` sums over all
+        programs."""
         if self.has_moe:
             return {**self._flat, **self._moe_flat}
         return dict(self._flat)

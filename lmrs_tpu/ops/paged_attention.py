@@ -137,8 +137,12 @@ def _fetch_page(page_tables_ref, k_hbm, v_hbm, k_scr, v_scr, sem,
 def scatter_kv_rows(pool: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray,
                     rows: jnp.ndarray) -> jnp.ndarray:
     """Write ``rows`` [..., K, hd] into the page-major pool [P, K, ps, hd]
-    at ``[page[...], :, off[...]]`` — THE XLA pool write (prefill, and the
-    XLA decode / multi / span paths).
+    at ``[page[...], :, off[...]]`` — the XLA pool write of everything but
+    a fresh prefill over whole pages (``scatter_kv_pages``): the windowed
+    continuation of a chunked prompt (it starts mid-page), packed prefill,
+    a fresh prefill whose bucket is no multiple of the page (a bucket of
+    64), the latent pool's decode write, and the XLA decode / multi / span
+    twins.
 
     All three leading dims are indexed (the kv heads through an arange), so
     the scatter window is a single hd row.  The obvious spelling
@@ -146,9 +150,43 @@ def scatter_kv_rows(pool: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray,
     TPU compiler re-lays-out the WHOLE pool: a pool-sized HBM temporary per
     pool, copied in and out of every program that writes — 2 x 2 GiB at the
     Llama-3-8B shape with the default pool, which does not fit one chip.
-    This form compiles with no temporary, also kv-head-sharded under tp."""
+    This form compiles with no temporary, also kv-head-sharded under tp.
+    Its price is the window: one row of hd elements a scatter step, about
+    70 ns each on the v5e whatever the row holds (27 ms for a [24 x 2048]
+    wave's 393,216 int8 rows; PERF.md section 6, PR 32) — cheap for a
+    decode step's B*K rows, and why a fresh prefill does not come here."""
     kh = pool.shape[1]
     return pool.at[page[..., None], jnp.arange(kh), off[..., None]].set(rows)
+
+
+def whole_pages(s: int, page_size: int, table_width: int) -> int:
+    """Pages a row that a FRESH prefill of ``s`` positions writes whole
+    (``scatter_kv_pages``), or 0 where it must write row by row: the bucket
+    is no multiple of the page (a bucket of 64) or the table is narrower
+    than the bucket.  Static facts of a program's shapes — the model step
+    and the scheduler's dispatch record (``page_writes``) both ask here."""
+    n = s // page_size
+    return n if n and s % page_size == 0 and table_width >= n else 0
+
+
+def scatter_kv_pages(pool: jnp.ndarray, pages: jnp.ndarray,
+                     rows: jnp.ndarray) -> jnp.ndarray:
+    """Write ``rows`` [B, S, K, hd], the tokens at positions 0 .. S-1 of B
+    sequences (S a multiple of the page size), into the page-major pool
+    [P, K, ps, hd] a WHOLE PAGE at a time: positions [j*ps, (j+1)*ps) of
+    row b are page ``pages[b, j]`` — the pool write of a fresh prefill.
+
+    One transposing copy of ``rows`` to [B*S/ps, K, ps, hd], then a scatter
+    whose window is a page: B*S/ps windows of K*ps*hd elements where the
+    row form sends B*S*K windows of hd.  In place on a donated pool, like
+    the row form.  ``pages`` may repeat (every pad row and every column
+    past a row's allocation names the null page 0): which of those writes
+    lands is unspecified, and nothing reads the null page."""
+    b, s, kh, hd = rows.shape
+    ps = pool.shape[2]
+    n = s // ps
+    tiles = rows.reshape(b, n, ps, kh, hd).transpose(0, 1, 3, 2, 4)
+    return pool.at[pages.reshape(-1)].set(tiles.reshape(b * n, kh, ps, hd))
 
 
 def paged_decode_xla(

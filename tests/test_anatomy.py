@@ -208,8 +208,8 @@ def test_dispatch_record_table_and_flat_counters(an):
     assert rep["decode"]["ctx_tokens"] == 150
     assert an.counters() == {
         "prefill_dispatches": 1, "prefill_query_tokens": 150,
-        "prefill_token_slots": 256, "rpa_wide_tokens": 0,
-        "rpa_kv_page_reads": 0, "cold_dispatches": 0,
+        "prefill_token_slots": 256, "prefill_page_writes": 0,
+        "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
         "cold_seconds": 0.0}
     assert an.audit(prefill_tokens=150) == []
     with pytest.raises(ValueError):
@@ -482,6 +482,10 @@ _LONG = "the quarterly planning review covered budgets and hiring " * 3
 _PATHS = {
     "fresh_prefill": ({}, dict(), tiny_model, [["one fresh prompt"]],
                       "prefill"),
+    # 128-token pages: the bucket of 64 is short of a page (row-form write)
+    "fresh_prefill_short_of_a_page": (
+        {}, dict(page_size=128, num_pages=8), tiny_model,
+        [["one fresh prompt"]], "prefill"),
     "packed": ({}, dict(), tiny_model,
                [["packed prompt one", "second packed prompt here"]],
                "packed"),
@@ -547,6 +551,16 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
                 path == "prefix_hit_spans" and name == "rpa"), (name, rec)
             for f in RECORD_FIELDS:
                 assert rec[f] == sum(k[f] for k in rec["keys"].values())
+            # a fresh prefill whose bucket is whole pages writes them whole:
+            # row_slots x bucket / page size a dispatch; nothing else does
+            for key, krec in rec["keys"].items():
+                _, fresh, n, bucket, *_ = (key.split(":") + [""] * 4)
+                ps = sched.cache.page_size
+                whole = (name == "prefill" and fresh == "True"
+                         and int(bucket) % ps == 0)
+                assert krec["page_writes"] == (
+                    krec["dispatches"] * int(n) * int(bucket) // ps
+                    if whole else 0), (key, krec)
         if path == "prefix_hit_spans":
             # the continuation attends the cached pages it did not compute
             assert programs["rpa"]["ctx_tokens"] > 0
@@ -562,6 +576,11 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
                 == sum(r["q_tokens"] for r in prompt))
         assert (m1["prefill_token_slots"] - m0["prefill_token_slots"]
                 == sum(r["q_slots"] for r in prompt))
+        assert (m1["prefill_page_writes"] - m0["prefill_page_writes"]
+                == sum(r["page_writes"] for r in prompt))
+        if want == "prefill":
+            assert (programs["prefill"]["page_writes"] > 0) == (
+                path == "fresh_prefill")
         assert m1["prefill_tokens"] - m0["prefill_tokens"] == sum(
             r["prompt_tokens"] for r in programs.values())
         # a fresh engine's first sight of each key is its one cold dispatch

@@ -366,3 +366,187 @@ def test_int8_composes_with_packed_prefill(monkeypatch):
         assert not np.allclose(ks[:, b], 1.0), f"slot {b} scales never set"
     packed.shutdown()
     assert got == want
+
+
+# ------------------------------------- the page-form pool write (PR 32)
+
+_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+# case -> (page size, bucket S, rows as (length, pages allocated))
+_WAVES = {
+    # two rows of different lengths, one allocated short of the bucket
+    # (its columns past the allocation are 0) and a pad row (table all 0)
+    "ragged_wave": (16, 64, [(64, 4), (37, 3), (20, 2), (1, 0)]),
+    "one_page_bucket": (32, 32, [(32, 1), (5, 1), (1, 0)]),
+    "lone_row": (16, 128, [(100, 7)]),
+}
+
+
+def _wave(case: str, dtype):
+    ps, s, rows = _WAVES[case]
+    rng = np.random.default_rng(3)
+    kh, hd, b, n = 2, 8, len(rows), s // ps
+    n_pages = 1 + sum(a for _, a in rows) + 3  # null page, owned, 3 unowned
+    order = 1 + rng.permutation(n_pages - 1)
+    table = np.zeros((b, n), np.int32)
+    at = 0
+    for r, (_, alloc) in enumerate(rows):
+        table[r, :alloc] = order[at: at + alloc]
+        at += alloc
+    draw = ((lambda shape: rng.integers(-127, 128, shape))
+            if dtype == jnp.int8 else
+            (lambda shape: rng.standard_normal(shape)))
+    pool = jnp.asarray(draw((n_pages, kh, ps, hd)), dtype)
+    new = jnp.asarray(draw((b, s, kh, hd)), dtype)
+    lens = np.asarray([length for length, _ in rows])
+    alloc = np.asarray([max(a, 1) * ps for _, a in rows])
+    return pool, jnp.asarray(table), new, lens, alloc, order[at:]
+
+
+@pytest.mark.parametrize("case", sorted(_WAVES))
+@pytest.mark.parametrize("dtype", sorted(_DTYPES))
+def test_page_form_write_equals_row_form_below_lengths(dtype, case):
+    """``scatter_kv_pages`` against ``scatter_kv_rows`` as the prefill
+    program drives it (positions clamped into the row's allocation): the
+    same bits at every position below each row's length, the positions
+    past an allocation on the null page and nowhere else (no page outside
+    the wave's tables changes), for f32, bf16 and int8 pools."""
+    from lmrs_tpu.ops.paged_attention import (scatter_kv_pages,
+                                              scatter_kv_rows, whole_pages)
+
+    pool, table, new, lens, alloc, unowned = _wave(case, _DTYPES[dtype])
+    ps = pool.shape[2]
+    b, s = new.shape[:2]
+    assert whole_pages(s, ps, table.shape[1]) == s // ps
+    pos = jnp.minimum(jnp.arange(s)[None], jnp.asarray(alloc)[:, None] - 1)
+    page = jnp.take_along_axis(table, pos // ps, axis=1)
+    want = np.asarray(scatter_kv_rows(pool, page, pos % ps, new)
+                      .astype(jnp.float32))
+    got = np.asarray(scatter_kv_pages(pool, table, new).astype(jnp.float32))
+    before = np.asarray(pool.astype(jnp.float32))
+    for r in range(b):
+        if not np.asarray(table)[r].any():
+            continue  # a pad row: its token sits on the null page
+        for j in range(-(-int(lens[r]) // ps)):
+            g = int(np.asarray(table)[r, j])
+            live = min(ps, int(lens[r]) - j * ps)
+            np.testing.assert_array_equal(got[g][:, :live], want[g][:, :live])
+            np.testing.assert_array_equal(
+                got[g][:, :live],
+                np.asarray(new.astype(jnp.float32))[
+                    r, j * ps: j * ps + live].transpose(1, 0, 2))
+    np.testing.assert_array_equal(got[unowned], before[unowned])
+    np.testing.assert_array_equal(want[unowned], before[unowned])
+
+
+def test_whole_pages_rule():
+    """The one static rule both the model step and the dispatch record ask:
+    a bucket of 64 under 128-token pages writes row by row, as does a table
+    narrower than the bucket; whole pages otherwise."""
+    from lmrs_tpu.ops.paged_attention import whole_pages
+
+    assert whole_pages(2048, 128, 16) == 16
+    assert whole_pages(128, 128, 16) == 1
+    assert whole_pages(64, 128, 16) == 0      # a bucket of 64: S < ps
+    assert whole_pages(192, 128, 16) == 0     # no multiple of the page
+    assert whole_pages(2048, 128, 8) == 0     # the table is too narrow
+
+
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+def test_fresh_prefill_then_decode_equal_in_both_write_forms(kv, monkeypatch):
+    """A fresh prefill of two rows (one short of its bucket) and six decode
+    steps through ``forward_paged``: the page-form write (the bucket is
+    whole pages) gives the logits, bit for bit, that the row form gave
+    before it (``whole_pages`` held at 0), int8 KV and bf16."""
+    import dataclasses
+
+    import jax
+
+    from lmrs_tpu.models.transformer import forward_paged, init_params
+    from lmrs_tpu.ops import paged_attention
+
+    cfg = tiny_model()
+    if kv == "bf16":
+        cfg = dataclasses.replace(cfg, dtype="bfloat16")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    B, S, K, hd, ps, P = 2, 64, cfg.n_kv_heads, cfg.hd, 32, 8
+    rng = np.random.default_rng(5)
+    tokens = jnp.asarray(rng.integers(1, 500, (B, S), dtype=np.int32))
+    tables = jnp.asarray([[1, 2, 3], [4, 5, 0]], jnp.int32)
+    lens = jnp.asarray([S, 41], jnp.int32)
+    alloc = jnp.asarray([3 * ps, 2 * ps], jnp.int32)
+
+    page_calls = []
+    writer = paged_attention.scatter_kv_pages
+
+    def run(form):
+        monkeypatch.setattr(
+            paged_attention, "scatter_kv_pages",
+            lambda *a: page_calls.append(form) or writer(*a))
+        if form == "rows":
+            monkeypatch.setattr(paged_attention, "whole_pages",
+                                lambda *a: 0)
+        pdt = jnp.int8 if kv == "int8" else jnp.bfloat16
+        kp = jnp.zeros((cfg.n_layers * P, K, ps, hd), pdt)
+        vp = jnp.zeros((cfg.n_layers * P, K, ps, hd), pdt)
+        sc = ((jnp.ones((cfg.n_layers, B, K, hd), jnp.float32),) * 2
+              if kv == "int8" else None)
+        pos = jnp.minimum(jnp.arange(S)[None], alloc[:, None] - 1)
+        out = forward_paged(params, cfg, tokens, pos, kp, vp, tables, lens,
+                            cfg.max_seq_len, kv_scales=sc,
+                            last_pos=lens - 1)
+        logits = [np.asarray(out[0][:, 0])]
+        n = lens
+        for _ in range(6):
+            tok = jnp.asarray(np.argmax(logits[-1], -1)[:, None], jnp.int32)
+            out = forward_paged(params, cfg, tok, n[:, None], out[1], out[2],
+                                tables, n + 1, cfg.max_seq_len,
+                                kv_scales=out[3] if sc else None)
+            logits.append(np.asarray(out[0][:, 0]))
+            n = n + 1
+        monkeypatch.undo()
+        return np.stack(logits)
+
+    pages, rows = run("pages"), run("rows")
+    assert page_calls == ["pages", "pages"]  # K and V of the one prefill
+    assert np.isfinite(pages.astype(np.float32)).all()
+    np.testing.assert_array_equal(pages, rows)
+
+
+@pytest.mark.parametrize("program", ["fresh_pages", "fresh_rows", "window"])
+def test_padding_never_lands_on_the_last_real_token(program, monkeypatch):
+    """A prompt that fills its pages exactly (32 tokens of 16-token pages,
+    nothing allocated beyond) in a bucket of 64: the bucket's padding, whose
+    positions the scheduler clamps to the allocation's last slot, goes to
+    the null page in every prefill program.  Clamped writes used to land ON
+    position 31 and could replace the last real token's K/V (found by PR 32:
+    the page form wrote it right and disagreed with the row form)."""
+    import jax
+
+    from lmrs_tpu.models.transformer import forward_paged, init_params
+    from lmrs_tpu.ops import paged_attention
+
+    cfg = tiny_model()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    if program == "fresh_rows":
+        monkeypatch.setattr(paged_attention, "whole_pages", lambda *a: 0)
+    n, ps, P = 32, 16, 4
+    tokens = jnp.asarray(
+        np.random.default_rng(9).integers(1, 500, (1, 64), dtype=np.int32))
+    table = jnp.asarray([[1, 2, 0, 0]], jnp.int32)
+
+    def pools(s):
+        kp = jnp.zeros((cfg.n_layers * P, cfg.n_kv_heads, ps, cfg.hd),
+                       jnp.float32)
+        pos = jnp.minimum(jnp.arange(s)[None], n - 1)  # the scheduler's clamp
+        out = forward_paged(params, cfg, tokens[:, :s], pos, kp, kp, table,
+                            jnp.asarray([n]), cfg.max_seq_len,
+                            window_prefill=program == "window")
+        return np.asarray(out[1]), np.asarray(out[2])
+
+    for padded, exact in zip(pools(64), pools(n)):
+        for li in range(cfg.n_layers):
+            # two programs of different shapes: float32 roundings apart,
+            # where a pad token's K/V in place of a real one is O(1) off
+            np.testing.assert_allclose(padded[li * P + 1: li * P + 3],
+                                       exact[li * P + 1: li * P + 3],
+                                       atol=1e-5, rtol=0)

@@ -155,6 +155,61 @@ def test_prefill_then_decode_through_the_latent_pool(fam, model, kernel):
     assert np.abs(np.stack(got) - want[rows]).max() < TOL
 
 
+def test_latent_fresh_prefill_writes_whole_pages_and_decodes_the_same(
+        model, monkeypatch):
+    """The latent pool's fresh prefill through the page-form write (two
+    rows over 32 positions of 16-token pages, one row short of its bucket
+    with its last column 0) and four decode steps: logits bit for bit what
+    the row form gives (``whole_pages`` held at 0); 40 positions (no
+    multiple of the page) take the row form by themselves."""
+    from lmrs_tpu.models.transformer import forward_paged
+    from lmrs_tpu.ops import paged_attention
+
+    cfg, params = model
+    ps, n_pages = 16, 8
+    tokens = jnp.asarray([_ids(5, 32), _ids(6, 32)], jnp.int32)
+    table = jnp.asarray([[1, 2, 3], [4, 0, 0]], jnp.int32)
+    lens = jnp.asarray([32, 11], jnp.int32)
+    alloc = jnp.asarray([3 * ps, ps], jnp.int32)
+    calls = []
+    writer = paged_attention.scatter_kv_pages
+    monkeypatch.setattr(paged_attention, "scatter_kv_pages",
+                        lambda *a: calls.append(a[2].shape[1]) or writer(*a))
+
+    def run(n_pre=32):
+        pool = jnp.zeros((cfg.n_layers * n_pages, 1, ps, cfg.latent_width),
+                         jnp.float32)
+        pos = jnp.minimum(jnp.arange(n_pre)[None], alloc[:, None] - 1)
+        out = forward_paged(params, cfg, tokens[:, :n_pre], pos, pool, None,
+                            table, lens, 256, use_flash=False,
+                            last_pos=lens - 1)
+        got, n = [np.asarray(out[0][:, 0])], lens
+        for _ in range(4):
+            tok = jnp.asarray(np.argmax(got[-1], -1)[:, None], jnp.int32)
+            out = forward_paged(params, cfg, tok, n[:, None], out[1], None,
+                                table, n + 1, 256)
+            got.append(np.asarray(out[0][:, 0]))
+            n = n + 1
+        return np.stack(got)
+
+    pages = run()
+    assert calls and set(calls) == {32}  # every layer's write, whole pages
+    del calls[:]
+    monkeypatch.setattr(paged_attention, "whole_pages", lambda *a: 0)
+    rows = run()
+    assert not calls
+    np.testing.assert_array_equal(pages, rows)
+    monkeypatch.undo()
+    monkeypatch.setattr(paged_attention, "scatter_kv_pages",
+                        lambda *a: calls.append(a[2].shape[1]) or writer(*a))
+    forward_paged(params, cfg, jnp.asarray([_ids(7, 40)], jnp.int32),
+                  jnp.arange(40)[None], jnp.zeros(
+                      (cfg.n_layers * n_pages, 1, ps, cfg.latent_width),
+                      jnp.float32), None, table[:1], jnp.asarray([40]), 256,
+                  use_flash=False)
+    assert not calls  # 40 is no multiple of 16: a row at a time
+
+
 def test_yarn_table_matches_a_float64_formula(model):
     """The program's rope tables against the published formula worked in
     float64 here: inverse frequencies blended by the linear ramp between

@@ -245,6 +245,95 @@ def test_pool_write_needs_no_pool_sized_temporary(one_chip, pool_dtype):
     assert ma.temp_size_in_bytes < pool_bytes // 8, ma.temp_size_in_bytes
 
 
+# the page-form write of a fresh prefill at the two prefill cells' shapes:
+# (layers, pages a layer, kv heads, lanes, rows, bucket, pool type)
+_PAGE_WRITES = {
+    "mistral-int8": (32, 385, 8, 128, 24, 2048, jnp.int8),
+    "latent-bf16": (6, 257, 1, 640, 16, 2048, jnp.bfloat16),
+}
+
+
+def _page_write_program(layers, n_pool):
+    from lmrs_tpu.ops.paged_attention import scatter_kv_pages
+
+    def prog(pool, table, rows):
+        def body(pool, li):
+            # a layer's own rows, as a model's are: nothing to hoist
+            return scatter_kv_pages(pool, li * n_pool + table,
+                                    rows + li.astype(rows.dtype)), None
+
+        return jax.lax.scan(body, pool, jnp.arange(layers))[0]
+
+    return prog
+
+
+def _assert_page_write_in_place(compiled, pool_bytes):
+    import re
+
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool_bytes  # donated in place
+    assert ma.temp_size_in_bytes < pool_bytes // 8, ma.temp_size_in_bytes
+    scatters = [ln for ln in compiled.as_text().splitlines()
+                if re.search(r"= \S+ scatter\(", ln)]
+    assert scatters, "the page write is no longer an XLA scatter"
+    for ln in scatters:
+        # the row form's window is one row: the updates' only window
+        # dimension is then the last one
+        dims = re.search(r"update_window_dims=\{([\d,]*)\}", ln).group(1)
+        assert len(dims.split(",")) >= 2, ln
+
+
+@pytest.mark.parametrize("cell", sorted(_PAGE_WRITES))
+def test_page_form_write_stays_in_place_with_a_page_as_its_window(one_chip,
+                                                                  cell):
+    """``scatter_kv_pages`` over a donated scan carry at the mistral cell's
+    pool (32 x 385 pages of [8, 128, 128] int8, a [24 x 2048] wave) and at
+    the latent pool's ([1542, 1, 128, 640] bf16, [16 x 2048]): in place, no
+    pool-sized temporary, and no scatter whose window is a single row (the
+    row form's 393,216 windows of 128 bytes a pool a layer were 18% of the
+    mistral cycle; PERF.md section 6, PR 32)."""
+    layers, n_pool, kh, lanes, b, s, dt = _PAGE_WRITES[cell]
+    ps = 128
+    args = [jax.ShapeDtypeStruct(shp, d, sharding=one_chip)
+            for shp, d in (((layers * n_pool, kh, ps, lanes), dt),
+                           ((b, s // ps), jnp.int32),
+                           ((b, s, kh, lanes), dt))]
+    compiled = jax.jit(_page_write_program(layers, n_pool),
+                       donate_argnums=(0,)).lower(*args).compile()
+    pool_bytes = layers * n_pool * kh * ps * lanes * jnp.dtype(dt).itemsize
+    _assert_page_write_in_place(compiled, pool_bytes)
+
+
+@pytest.mark.parametrize("axes", [("tp",), ("sp", "tp")],
+                         ids=["tp4", "sp2-tp2"])
+def test_page_form_write_stays_in_place_under_a_mesh(topo, axes):
+    """The same write with the pool kv-head-sharded over ``tp`` (and the
+    wave's rows sequence-sharded over ``sp``, as a ring prefill leaves
+    them), compiled for the described v5e:2x2: each chip writes its own
+    heads' share of a page in place; no cell runs a mesh, so this compile is
+    what lets ``forward_paged`` take the page form there too."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    layers, n_pool, kh, lanes, b, s, _ = _PAGE_WRITES["mistral-int8"]
+    ps, dt = 128, jnp.bfloat16
+    shape = (4,) if len(axes) == 1 else (2, 2)
+    mesh = Mesh(np.array(topo.devices).reshape(shape), axes)
+    sh = lambda *spec: NamedSharding(mesh, P(*spec))
+    seq = "sp" if "sp" in axes else None
+    args = [jax.ShapeDtypeStruct((layers * n_pool, kh, ps, lanes), dt,
+                                 sharding=sh(None, "tp")),
+            jax.ShapeDtypeStruct((b, s // ps), jnp.int32, sharding=sh()),
+            jax.ShapeDtypeStruct((b, s, kh, lanes), dt,
+                                 sharding=sh(None, seq, "tp"))]
+    compiled = jax.jit(_page_write_program(layers, n_pool),
+                       donate_argnums=(0,),
+                       out_shardings=sh(None, "tp")).lower(*args).compile()
+    tp = mesh.shape["tp"]
+    _assert_page_write_in_place(
+        compiled, layers * n_pool * kh * ps * lanes * 2 // tp)
+
+
 def test_flash_prefill_compiles_at_latent_attention_widths(one_chip):
     """MLA's expanded prefill at the published widths: 64 heads, queries
     and keys 192 wide (no multiple of 128), values 128 wide."""
