@@ -105,6 +105,27 @@ class ModelConfig:
     routed_scaling_factor = 1.0
     expert_first = n_experts_held = 0
     n_dense_layers = dense_hidden_dim = 0
+    # What ``LoopedModelConfig`` adds, likewise: the stack runs once, no
+    # second norm on a sublayer's output, no token leaves the loop early.
+    n_loops, sandwich_norm, early_exit_threshold = 1, False, 1.0
+
+    def __post_init__(self) -> None:
+        if self.n_loops < 1:
+            raise ValueError(f"n_loops={self.n_loops}: the stack runs at "
+                             "least once")
+        if self.early_exit_threshold < 1.0:
+            raise ValueError(
+                f"early_exit_threshold={self.early_exit_threshold} < 1 (a "
+                "token leaves the loop once its exit gate passes the "
+                "threshold: depth chosen per token, a scheduler change) is "
+                "not built; every token runs all n_loops passes, which is "
+                "what the threshold 1.0 means")
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers the KV cache is deep: one per (pass, layer).  Weights are
+        ``n_layers`` deep."""
+        return self.n_layers * self.n_loops
 
     @property
     def hd(self) -> int:
@@ -166,6 +187,22 @@ class LatentModelConfig(ModelConfig):
     n_experts_held: int = 0
     n_dense_layers: int = 0
     dense_hidden_dim: int = 0
+
+
+@dataclass
+class LoopedModelConfig(ModelConfig):
+    """Looped layers (models/transformer.run_stack): the one stack of
+    ``n_layers`` layers runs ``n_loops`` times over the same weights, the
+    final norm closing every pass; pass t's layer l keeps its own K/V, cache
+    layer ``t * n_layers + l`` (``cache_layers``).  ``sandwich_norm``: a
+    second norm on each sublayer's OUTPUT, before the residual add.  Depth
+    is fixed: an exit gate that lets a token leave early
+    (``early_exit_threshold`` < 1) would make a step's depth data-dependent
+    per row and is refused (``ModelConfig.__post_init__``)."""
+
+    n_loops: int = 1
+    sandwich_norm: bool = False
+    early_exit_threshold: float = 1.0
 
 
 @dataclass
@@ -634,6 +671,25 @@ def model_preset(name: str) -> ModelConfig:
             n_routed_experts=16, n_shared_experts=1, n_experts_per_token=4,
             routed_scaling_factor=2.5, expert_first=4, n_experts_held=4,
         ),
+        "ouro-2.6b": dict(
+            # ByteDance/Ouro-2.6B config.json (model_type ouro, "LoopLM"):
+            # one stack of 48 layers run total_ut_steps = 4 times over the
+            # same weights, sandwich norms, the final norm closing every
+            # pass; 2.67 B parameters, 192 cache layers: 1.5 MiB of bf16
+            # K/V a token (benchmarks/configs/ouro-2.6b.json serves it on
+            # int8 pages)
+            vocab_size=49152, dim=2048, n_layers=48, n_heads=16,
+            n_kv_heads=16, head_dim=128, hidden_dim=5632, max_seq_len=8192,
+            rope_theta=1e6, norm_eps=1e-6, tie_embeddings=False,
+            n_loops=4, sandwich_norm=True,
+        ),
+        "tiny-looped": dict(
+            # the same block at test size: two layers run three times
+            vocab_size=512, dim=128, n_layers=2, n_heads=4, n_kv_heads=4,
+            hidden_dim=256, max_seq_len=256, rope_theta=10000.0,
+            norm_eps=1e-6, tie_embeddings=False, n_loops=3,
+            sandwich_norm=True,
+        ),
         "mixtral-8x7b": dict(
             vocab_size=32000, dim=4096, n_layers=32, n_heads=32, n_kv_heads=8,
             hidden_dim=14336, max_seq_len=8192, rope_theta=1e6,
@@ -642,5 +698,7 @@ def model_preset(name: str) -> ModelConfig:
     }
     if name not in presets:
         raise ValueError(f"unknown model preset {name!r}; have {sorted(presets)}")
-    cls = LatentModelConfig if "kv_lora_rank" in presets[name] else ModelConfig
+    cls = (LatentModelConfig if "kv_lora_rank" in presets[name]
+           else LoopedModelConfig if "n_loops" in presets[name]
+           else ModelConfig)
     return cls(name=name, **presets[name])

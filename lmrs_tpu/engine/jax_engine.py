@@ -57,9 +57,9 @@ def _bf16_tree_gb(cfg: ModelConfig) -> float:
     — subtract the head term or e.g. gemma-2b's estimate carries a
     phantom 1.05 GB and trips the 6.0 GB host-init gate early (ADVICE
     r5)."""
-    from lmrs_tpu.utils.perf_model import matmul_params
+    from lmrs_tpu.utils.perf_model import stored_matmul_params
 
-    n = matmul_params(cfg) + cfg.vocab_size * cfg.dim
+    n = stored_matmul_params(cfg) + cfg.vocab_size * cfg.dim
     if cfg.tie_embeddings:
         n -= cfg.vocab_size * cfg.dim
     if cfg.n_experts:
@@ -95,7 +95,8 @@ def sharded_random_init(cfg: ModelConfig, key, mesh):
     from lmrs_tpu.parallel.sharding import param_shardings
 
     shardings = param_shardings(mesh, cfg.tie_embeddings,
-                                moe=cfg.n_experts > 0)
+                                moe=cfg.n_experts > 0,
+                                sandwich_norm=cfg.sandwich_norm)
     return jax.jit(lambda k: init_params(cfg, k),
                    out_shardings=shardings)(key)
 
@@ -246,7 +247,8 @@ class JaxEngine:
             from lmrs_tpu.parallel.sharding import shard_params
 
             return shard_params(params, self._mesh, self.model_cfg.tie_embeddings,
-                                moe=self.model_cfg.n_experts > 0)
+                                moe=self.model_cfg.n_experts > 0,
+                                sandwich_norm=self.model_cfg.sandwich_norm)
         return jax.device_put(params)
 
     def shutdown(self) -> None:

@@ -168,7 +168,9 @@ class PagedKVCache:
         # page and double the tokens per HBM GiB; scales are scheduler-owned
         # (ops/quant.py KV section)
         dt = jnp.dtype(kv_dtype) if kv_dtype else jnp.dtype(model_cfg.dtype)
-        shape = (model_cfg.n_layers * num_pages, model_cfg.n_kv_heads,
+        # L counts CACHE layers: one per (pass, layer) of a looped stack
+        # (ModelConfig.cache_layers; n_layers where the stack runs once)
+        shape = (model_cfg.cache_layers * num_pages, model_cfg.n_kv_heads,
                  page_size, hd)
         self.latent = bool(model_cfg.kv_lora_rank)
         if self.latent:
@@ -177,7 +179,7 @@ class PagedKVCache:
                     "latent KV cache (kv_lora_rank > 0): no int8 pages and "
                     "no mesh of several devices (the latent row has no "
                     "per-head scales and no kv-head axis to shard)")
-            shape = (model_cfg.n_layers * num_pages, 1, page_size,
+            shape = (model_cfg.cache_layers * num_pages, 1, page_size,
                      model_cfg.latent_width)
             pinned = None
             if mesh is not None:  # a replica's one device

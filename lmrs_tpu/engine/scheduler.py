@@ -172,7 +172,7 @@ class ContinuousScheduler:
                                   mesh=mesh,
                                   kv_dtype="int8" if self._kv_quant else None)
         if self._kv_quant:
-            sshape = (model_cfg.n_layers, self.B, model_cfg.n_kv_heads,
+            sshape = (model_cfg.cache_layers, self.B, model_cfg.n_kv_heads,
                       model_cfg.hd)
             self.kscale = jnp.ones(sshape, jnp.float32)
             self.vscale = jnp.ones(sshape, jnp.float32)
@@ -3556,6 +3556,7 @@ class ContinuousScheduler:
                 "rpa", key_, rows=len(rows) + (pf is not None),
                 row_slots=self.B, q_tokens=real, prompt_tokens=c,
                 q_slots=tpb, ctx_tokens=live_tokens + pos, cold=not warm,
+                layer_passes=self.model_cfg.cache_layers,
                 **self._span_walk(q_lens_np, base_np, w,
                                   kernel=not tree_live)):
             out = dispatch()
@@ -3835,7 +3836,7 @@ class ContinuousScheduler:
                     ctx_tokens=sum(p for _, _, _, p, _ in items),
                     page_writes=(n * whole_pages(
                         s_bucket, self.cache.page_size, w) if fresh else 0),
-                    cold=cold):
+                    layer_passes=self.model_cfg.cache_layers, cold=cold):
                 fn = (self._get_prefill_fn(s_bucket, use_ring=ring)
                       if fresh
                       else self._get_prefill_window_fn(s_bucket, w))
@@ -3935,6 +3936,7 @@ class ContinuousScheduler:
                 "rpa", key_, rows=len(items), row_slots=self.B,
                 q_tokens=batch_tokens, prompt_tokens=batch_tokens,
                 q_slots=tpb, ctx_tokens=int(base_np.sum()), cold=not warm,
+                layer_passes=self.model_cfg.cache_layers,
                 **self._span_walk(q_lens_np, base_np, w)):
             tok0, self.cache.k, self.cache.v, ks, vs = \
                 self._get_rpa_fn(tpb, w)(*args)
@@ -4028,7 +4030,8 @@ class ContinuousScheduler:
         with self._an.dispatch(
                 "packed", key_, rows=len(items), row_slots=self.B,
                 q_tokens=s_real, prompt_tokens=s_real, q_slots=s_bucket,
-                ctx_tokens=0, cold=cold):
+                ctx_tokens=0, layer_passes=self.model_cfg.cache_layers,
+                cold=cold):
             tok0, self.cache.k, self.cache.v, \
                 self.kscale, self.vscale = \
                 self._get_packed_prefill_fn(s_bucket)(*args)
@@ -4290,7 +4293,9 @@ class ContinuousScheduler:
                 "decode", key_, rows=attr_live_rows, row_slots=bc,
                 q_tokens=0, prompt_tokens=0,
                 q_slots=bc * self.decode_block,
-                ctx_tokens=attr_live_tokens, cold=not decode_warm) as disp:
+                ctx_tokens=attr_live_tokens,
+                layer_passes=self.model_cfg.cache_layers * self.decode_block,
+                cold=not decode_warm) as disp:
             out = self._get_decode_fn(w)(*args)
         self._note_ran_ok(key_)
         toks, n_valid, self.cache.k, self.cache.v = self._moe_take(
@@ -4473,6 +4478,7 @@ class ContinuousScheduler:
                 q_tokens=0, prompt_tokens=0,
                 q_slots=self.B * self.decode_steps * (1 + self.spec_k),
                 ctx_tokens=int(np.sum(kv_lens[active])),
+                layer_passes=self.model_cfg.cache_layers * self.decode_steps,
                 cold=cold) as disp:
             out = self._get_spec_decode_fn(w)(*args)
         self._note_ran_ok(key_)

@@ -51,7 +51,8 @@ def load_checkpoint(path: str, model_cfg: ModelConfig, mesh=None) -> Any:
         from lmrs_tpu.parallel.sharding import param_shardings
 
         shardings = param_shardings(mesh, model_cfg.tie_embeddings,
-                                    moe=model_cfg.n_experts > 0)
+                                    moe=model_cfg.n_experts > 0,
+                                    sandwich_norm=model_cfg.sandwich_norm)
         target = jax.tree.map(
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
             target, shardings,
@@ -91,6 +92,11 @@ def convert_hf_llama(src_dir: str, cfg: ModelConfig, *, norm_offset: float = 1.0
     """
     import json as _json
 
+    if cfg.sandwich_norm:
+        raise NotImplementedError(
+            "sandwich_norm: no loader for the four-norm tree (a published "
+            "checkpoint's second norm of each sublayer, ln_attn_out / "
+            "ln_mlp_out here, is not mapped); weights are random-init only")
     try:
         from safetensors import safe_open
     except ImportError as e:  # pragma: no cover - gated dependency

@@ -13,6 +13,10 @@ Design choices (TPU-first, not a torch translation):
   data-dependent Python control flow).
 * **GQA + RoPE + RMSNorm + SwiGLU**, optional Gemma quirks (embedding scale,
   logit softcap, tied embeddings).
+* **Looped layers** (``cfg.n_loops`` > 1): the stack runs several times over
+  the same weights (``run_stack``), the final norm closing every pass; pass
+  ``t``'s layer ``l`` keeps its own K/V in cache layer ``t * n_layers + l``.
+  ``cfg.sandwich_norm`` norms each sublayer's output before the residual add.
 
 The reference has no model code at all — the LLM lives behind OpenAI's API
 (SURVEY.md L0, llm_executor.py:292).  This module is the heart of what the
@@ -74,11 +78,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 "w_down": tn(lk[6], (L, cfg.hidden_dim, cfg.dim), cfg.hidden_dim),
             }
         }
+    norms = ("ln_attn", "ln_mlp")
+    if cfg.sandwich_norm:  # the norms on the two sublayers' outputs
+        norms += ("ln_attn_out", "ln_mlp_out")
     params: Params = {
         "embed": {"weight": tn(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim)},
         "layers": {
-            "ln_attn": {"scale": jnp.zeros((L, cfg.dim), dt)},
-            "ln_mlp": {"scale": jnp.zeros((L, cfg.dim), dt)},
+            **{n: {"scale": jnp.zeros((L, cfg.dim), dt)} for n in norms},
             "attn": {
                 "wq": tn(lk[0], (L, cfg.dim, cfg.n_heads, hd), cfg.dim),
                 "wk": tn(lk[1], (L, cfg.dim, cfg.n_kv_heads, hd), cfg.dim),
@@ -99,9 +105,10 @@ def param_count(params: Params) -> int:
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict[str, jnp.ndarray]:
-    """Dense per-slot KV cache [L, B, S, K, hd] (paged cache: engine/kv_cache)."""
+    """Dense per-slot KV cache [L, B, S, K, hd] (paged cache: engine/kv_cache);
+    L counts cache layers, one per (pass, layer) of a looped stack."""
     hd = cfg.hd
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, hd)
+    shape = (cfg.cache_layers, batch, max_len, cfg.n_kv_heads, hd)
     dt = _dtype(cfg)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
@@ -146,6 +153,44 @@ def out_proj(lp: Params, cfg: ModelConfig, attn_out: jnp.ndarray) -> jnp.ndarray
                    attn_out.dtype)
 
 
+def sublayer_out(lp: Params, cfg: ModelConfig, norm: str,
+                 y: jnp.ndarray) -> jnp.ndarray:
+    """What a sublayer adds to the residual stream: its output ``y``, under
+    ``cfg.sandwich_norm`` normed first by the layer's ``norm`` leaf."""
+    if cfg.sandwich_norm:
+        return rms_norm(y, lp[norm]["scale"], cfg.norm_eps)
+    return y
+
+
+def run_stack(params: Params, cfg: ModelConfig, carry: tuple, layer_fn):
+    """The layer stack over ``carry`` (``carry[0]`` is the residual stream
+    [.., D]): ``layer_fn(carry, (layer params, cache layer)) -> (carry,
+    None)`` scanned over the stacked weights, ``cfg.n_loops`` times over the
+    SAME weights.  Pass ``t`` hands layer ``l`` the cache layer ``t *
+    n_layers + l``, so every (pass, layer) keeps its own K/V, and the
+    model's final norm closes every pass: its output is the next pass's
+    input and, after the last pass, what the LM head reads (``lm_head``
+    does not norm again).  With ``n_loops`` 1 this is the one layer scan,
+    and the final norm is the head's."""
+    ids = jnp.arange(cfg.n_layers)
+
+    def stack(carry, cache_layers):
+        return jax.lax.scan(layer_fn, carry, (params["layers"], cache_layers))[0]
+
+    if cfg.n_loops == 1:
+        return stack(carry, ids)
+
+    def one_pass(carry, t):
+        with jax.named_scope("loop.pass"):
+            carry = stack(carry, t * cfg.n_layers + ids)
+        with jax.named_scope("loop.close"):
+            x = rms_norm(carry[0], params["final_norm"]["scale"],
+                         cfg.norm_eps)
+        return (x, *carry[1:]), None
+
+    return jax.lax.scan(one_pass, carry, jnp.arange(cfg.n_loops))[0]
+
+
 def decoder_layer(
     lp: Params,               # one layer's params (no leading L axis)
     cfg: ModelConfig,
@@ -171,10 +216,10 @@ def decoder_layer(
         attn_out = attn_fn(q, k, v, positions)
     else:
         attn_out = attention(q, k, v, positions, kv_length, logit_softcap=None)
-    x = x + out_proj(lp, cfg, attn_out)
+    x = x + sublayer_out(lp, cfg, "ln_attn_out", out_proj(lp, cfg, attn_out))
     h = rms_norm(x, lp["ln_mlp"]["scale"], cfg.norm_eps)
     ff, aux = ffn_block(lp, cfg, h)
-    return x + ff, aux
+    return x + sublayer_out(lp, cfg, "ln_mlp_out", ff), aux
 
 
 def embed_tokens(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -186,8 +231,11 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.n
 
 
 def lm_head(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    """Final norm + output projection to f32 logits (+ optional softcap)."""
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    """Final norm + output projection to f32 logits (+ optional softcap).
+    A looped stack (``run_stack``) has closed its last pass with that norm
+    already."""
+    if cfg.n_loops == 1:
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", x, params["embed"]["weight"])
     else:
@@ -259,19 +307,19 @@ def forward(
             cv = cv.at[batch_idx, positions].set(v)
             attn_out = attention(q, ck, cv, positions, kv_length,
                                  logit_softcap=None)
-            x = x + out_proj(lp, cfg, attn_out)
+            x = x + sublayer_out(lp, cfg, "ln_attn_out",
+                                 out_proj(lp, cfg, attn_out))
 
             h = rms_norm(x, lp["ln_mlp"]["scale"], cfg.norm_eps)
             ff, _ = ffn_block(lp, cfg, h)
-            x = x + ff
+            x = x + sublayer_out(lp, cfg, "ln_mlp_out", ff)
             ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
             cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
             return (x, ck_all, cv_all), None
 
         # lax.scan over stacked layers: wq etc. are [L, ...]; cache [L, B, ...]
-        (x, new_k, new_v), _ = jax.lax.scan(
-            layer_fn, (x, cache["k"], cache["v"]),
-            (params["layers"], jnp.arange(cfg.n_layers)))
+        x, new_k, new_v = run_stack(
+            params, cfg, (x, cache["k"], cache["v"]), layer_fn)
         new_cache = {"k": new_k, "v": new_v}
         aux = jnp.float32(0.0)
     else:
@@ -285,14 +333,14 @@ def forward(
             # O(L * per-layer intermediates) to O(L * [B,S,D]).
             one_layer = jax.checkpoint(one_layer)
 
-        def layer_fn_nocache(carry, lp):
+        def layer_fn_nocache(carry, xs):
             x, aux = carry
-            x, layer_aux = one_layer(lp, x)
+            x, layer_aux = one_layer(xs[0], x)
             return (x, aux + layer_aux), None
 
-        (x, aux), _ = jax.lax.scan(
-            layer_fn_nocache, (x, jnp.float32(0.0)), params["layers"])
-        aux = aux / cfg.n_layers
+        x, aux = run_stack(params, cfg, (x, jnp.float32(0.0)),
+                           layer_fn_nocache)
+        aux = aux / cfg.cache_layers
         new_cache = None
 
     logits = lm_head(params, cfg, x)
@@ -457,7 +505,7 @@ def forward_paged(
     b, s = tokens.shape
     hd = cfg.hd
     ps = k_pages.shape[2]
-    n_pool = k_pages.shape[0] // cfg.n_layers  # logical pages per layer
+    n_pool = k_pages.shape[0] // cfg.cache_layers  # logical pages per layer
     # (page-major pool [L*P, K, ps, hd]: pages are axis 0.  The round-3
     # relayout left this reading axis 1 — the kv-head count — which
     # collapsed every layer's global page ids onto the same few pages and
@@ -518,7 +566,7 @@ def forward_paged(
         else:
             x, kp_all, vp_all = carry  # pools: [L*P, K, ps, hd]
             ksc = vsc = None
-        lp, li = xs  # layer params, layer index
+        lp, li = xs  # layer params, CACHE layer (run_stack: pass * L + layer)
         g_page_idx = (None if page_idx is None
                       else li * n_pool + page_idx)  # [B, S] global page ids
         g_tables = li * n_pool + page_tables     # [B, W]
@@ -776,19 +824,18 @@ def forward_paged(
         return _finish_layer(lp, x, attn_out, kp_all, vp_all, ksc, vsc)
 
     def _finish_layer(lp, x, attn_out, kp_all, vp_all, ksc, vsc):
-        x = x + out_proj(lp, cfg, attn_out)
+        x = x + sublayer_out(lp, cfg, "ln_attn_out",
+                             out_proj(lp, cfg, attn_out))
         h = rms_norm(x, lp["ln_mlp"]["scale"], cfg.norm_eps)
         ff, _ = ffn_block(lp, cfg, h)
+        x = x + sublayer_out(lp, cfg, "ln_mlp_out", ff)
         if kv_scales is not None:
-            return (x + ff, kp_all, vp_all, ksc, vsc), None
-        return (x + ff, kp_all, vp_all), None
+            return (x, kp_all, vp_all, ksc, vsc), None
+        return (x, kp_all, vp_all), None
 
     init = ((x, k_pages, v_pages) if kv_scales is None
             else (x, k_pages, v_pages, kv_scales[0], kv_scales[1]))
-    carry_out, _ = jax.lax.scan(
-        layer_fn, init,
-        (params["layers"], jnp.arange(cfg.n_layers)),
-    )
+    carry_out = run_stack(params, cfg, init, layer_fn)
     if kv_scales is None:
         x, new_k, new_v = carry_out
         new_scales = None
@@ -802,14 +849,7 @@ def forward_paged(
         # per-row gather: [B, S, D] -> [B, 1, D]
         x = jnp.take_along_axis(
             x, jnp.clip(last_pos, 0, s - 1)[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"]["weight"])
-    else:
-        logits = qeinsum("bsd,dv->bsv", x, params["lm_head"]["weight"], x.dtype)
-    logits = logits.astype(jnp.float32)
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+    logits = lm_head(params, cfg, x)
     if kv_scales is not None:
         return logits, new_k, new_v, new_scales
     return logits, new_k, new_v

@@ -75,7 +75,8 @@ PROMPT_PROGRAMS = frozenset(PROGRAMS[:4])
 RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
                                   "q_tokens", "prompt_tokens", "q_slots",
                                   "ctx_tokens", "wide_tokens",
-                                  "kv_page_reads", "page_writes", "cold")
+                                  "kv_page_reads", "page_writes",
+                                  "layer_passes", "cold")
 
 # what a routed model's dispatches add to their record once their counts
 # are back (``note_moe``); absent from a dense model's records and report
@@ -269,7 +270,7 @@ class StepAnatomy:
         # flat sums of the same records, for ``scheduler.metrics``
         self._flat = {"prefill_dispatches": 0, "prefill_query_tokens": 0,
                       "prefill_token_slots": 0, "prefill_page_writes": 0,
-                      "rpa_wide_tokens": 0,
+                      "layer_passes": 0, "rpa_wide_tokens": 0,
                       "rpa_kv_page_reads": 0, "cold_dispatches": 0,
                       "cold_seconds": 0.0}
         # routed models only (the scheduler sets ``has_moe`` for one): a
@@ -411,7 +412,7 @@ class StepAnatomy:
                  row_slots: int, q_tokens: int, prompt_tokens: int,
                  q_slots: int, ctx_tokens: int, cold: bool,
                  wide_tokens: int = 0, kv_page_reads: int = 0,
-                 page_writes: int = 0) -> _Dispatch:
+                 page_writes: int = 0, layer_passes: int = 0) -> _Dispatch:
         """The ``dispatch`` segment of one device dispatch, with what it
         carried.  ``program`` is one of ``PROGRAMS`` and ``key`` the site's
         own compile key; ``rows`` carry work out of ``row_slots`` operand
@@ -427,7 +428,11 @@ class StepAnatomy:
         prefill dispatch says how many pages a layer it writes into the
         pool WHOLE (``page_writes``: ``row_slots`` x bucket / page size for
         a fresh prefill over whole pages, ``ops/paged_attention.
-        whole_pages``; 0 where the program writes a row at a time)."""
+        whole_pages``; 0 where the program writes a row at a time).  Every
+        dispatch says how many layer applications it runs
+        (``layer_passes``: the model's ``cache_layers``, ``n_layers`` x
+        the passes of a looped stack, times the steps of a decode or
+        speculative block; one step for a prefill or span dispatch)."""
         if program not in PROGRAMS:
             raise ValueError(f"unknown dispatch program {program!r} "
                              f"(want one of {PROGRAMS})")
@@ -438,7 +443,8 @@ class StepAnatomy:
             "q_slots": int(q_slots), "ctx_tokens": int(ctx_tokens),
             "wide_tokens": int(wide_tokens),
             "kv_page_reads": int(kv_page_reads),
-            "page_writes": int(page_writes), "cold": bool(cold)})
+            "page_writes": int(page_writes),
+            "layer_passes": int(layer_passes), "cold": bool(cold)})
 
     def _fold(self, r: dict) -> None:
         rec = self._table.get((r["program"], r["key"]))
@@ -450,6 +456,7 @@ class StepAnatomy:
             rec[f] += r[f]
         flat = self._flat
         flat["cold_dispatches"] += r["cold"]
+        flat["layer_passes"] += r["layer_passes"]
         if r["program"] in PROMPT_PROGRAMS:
             flat["prefill_dispatches"] += 1
             flat["prefill_query_tokens"] += r["q_tokens"]
@@ -487,9 +494,9 @@ class StepAnatomy:
         dispatches, their real query positions, the positions their
         operands held and the pages they wrote whole (the prompt
         programs), the span kernel's wide-tile tokens and page fetches
-        (the ``rpa`` program), cold dispatches and their wall (all
-        programs); for a routed model, the ``MOE_FIELDS`` sums over all
-        programs."""
+        (the ``rpa`` program), layer applications, cold dispatches and
+        their wall (all programs); for a routed model, the ``MOE_FIELDS``
+        sums over all programs."""
         if self.has_moe:
             return {**self._flat, **self._moe_flat}
         return dict(self._flat)

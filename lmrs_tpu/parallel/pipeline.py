@@ -67,6 +67,11 @@ def pipeline_causal_lm_loss(
     token-mean loss as a replicated scalar.
     """
     pp = mesh.shape[pp_axis]
+    if cfg.n_loops > 1:
+        raise ValueError(
+            f"pipeline stages over a looped stack (n_loops={cfg.n_loops}) "
+            "are not built: a stage holds L/pp layers and every pass would "
+            "have to travel the ring of stages again")
     if cfg.n_layers % pp != 0:
         raise ValueError(f"n_layers={cfg.n_layers} not divisible by pp={pp}")
 

@@ -24,8 +24,11 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def param_specs(tie_embeddings: bool = True, moe: bool = False) -> dict[str, Any]:
-    """PartitionSpec pytree matching models.transformer.init_params layout."""
+def param_specs(tie_embeddings: bool = True, moe: bool = False,
+                sandwich_norm: bool = False) -> dict[str, Any]:
+    """PartitionSpec pytree matching models.transformer.init_params layout
+    (``sandwich_norm``: the two norm leaves on the sublayers' outputs,
+    replicated like every norm)."""
     if moe:
         ffn = {
             "moe": {
@@ -43,11 +46,13 @@ def param_specs(tie_embeddings: bool = True, moe: bool = False) -> dict[str, Any
                 "w_down": P(None, "tp", None),  # [L, F, D] row
             }
         }
+    norms = ("ln_attn", "ln_mlp")
+    if sandwich_norm:
+        norms += ("ln_attn_out", "ln_mlp_out")
     specs = {
         "embed": {"weight": P("tp", None)},  # vocab sharded
         "layers": {
-            "ln_attn": {"scale": P(None, None)},
-            "ln_mlp": {"scale": P(None, None)},
+            **{n: {"scale": P(None, None)} for n in norms},
             "attn": {
                 "wq": P(None, None, "tp", None),  # [L, D, H, hd] heads sharded
                 "wk": P(None, None, "tp", None),  # [L, D, K, hd]
@@ -71,19 +76,22 @@ def specs_to_shardings(specs: Any, mesh: Mesh) -> Any:
     )
 
 
-def param_shardings(mesh: Mesh, tie_embeddings: bool = True, moe: bool = False):
+def param_shardings(mesh: Mesh, tie_embeddings: bool = True, moe: bool = False,
+                    sandwich_norm: bool = False):
     """NamedSharding pytree for jit in_shardings / device_put."""
-    return specs_to_shardings(param_specs(tie_embeddings, moe), mesh)
+    return specs_to_shardings(
+        param_specs(tie_embeddings, moe, sandwich_norm), mesh)
 
 
 def shard_params(params: Any, mesh: Mesh, tie_embeddings: bool = True,
-                 moe: bool = False) -> Any:
+                 moe: bool = False, sandwich_norm: bool = False) -> Any:
     """Place a host-side param pytree onto the mesh with the TP layout.
     Handles int8-quantized trees (ops/quant.py): the q tensor takes the
     weight's spec, scales replicate."""
     from lmrs_tpu.ops.quant import match_quantized_specs
 
-    specs = match_quantized_specs(param_specs(tie_embeddings, moe), params)
+    specs = match_quantized_specs(
+        param_specs(tie_embeddings, moe, sandwich_norm), params)
     return jax.tree.map(jax.device_put, params, specs_to_shardings(specs, mesh))
 
 

@@ -157,7 +157,8 @@ def main(argv=None) -> int:
             from lmrs_tpu.parallel.sharding import shard_params
 
             params = shard_params(params, mesh, cfg.tie_embeddings,
-                                  moe=cfg.n_experts > 0)
+                                  moe=cfg.n_experts > 0,
+                                  sandwich_norm=cfg.sandwich_norm)
     optimizer = optax.adamw(args.lr)
     opt_state = optimizer.init(params)
     step_fn = make_train_step(cfg, optimizer, mesh,

@@ -78,7 +78,8 @@ def make_train_step(
     if mesh is None:
         return jax.jit(step)
 
-    pspecs = param_shardings(mesh, cfg.tie_embeddings, moe=cfg.n_experts > 0)
+    pspecs = param_shardings(mesh, cfg.tie_embeddings, moe=cfg.n_experts > 0,
+                             sandwich_norm=cfg.sandwich_norm)
     batch_sh = NamedSharding(mesh, batch_spec(seq_sharded))
     in_sh = [pspecs, None, batch_sh] + ([batch_sh] if masked else [])
     # opt_state sharding left unconstrained: XLA propagates the param layout

@@ -64,7 +64,10 @@ def chip_spec() -> ChipSpec | None:
 
 def matmul_params(cfg: ModelConfig) -> int:
     """Parameters that participate in per-token matmuls (embedding lookup
-    excluded; the LM head included — tied or not, it is a [D, V] matmul)."""
+    excluded; the LM head included — tied or not, it is a [D, V] matmul).
+    A looped stack (``cfg.n_loops``) multiplies, and a decode step reads,
+    every layer's weights once a pass: its layers count ``n_loops`` times
+    (``stored_matmul_params`` counts what the tree holds)."""
     if cfg.kv_lora_rank:
         return _latent_matmul_params(cfg)
     d, hd = cfg.dim, cfg.hd
@@ -78,7 +81,14 @@ def matmul_params(cfg: ModelConfig) -> int:
         per_layer += 3 * d * cfg.hidden_dim * cfg.n_experts_per_token
     else:
         per_layer += 3 * d * cfg.hidden_dim
-    return cfg.n_layers * per_layer + d * cfg.vocab_size
+    return cfg.cache_layers * per_layer + d * cfg.vocab_size
+
+
+def stored_matmul_params(cfg: ModelConfig) -> int:
+    """``matmul_params`` as the weight tree holds them: each layer once,
+    however often the stack runs."""
+    head = cfg.dim * cfg.vocab_size
+    return (matmul_params(cfg) - head) // cfg.n_loops + head
 
 
 def _latent_matmul_params(cfg: ModelConfig) -> int:
@@ -128,7 +138,7 @@ def prefill_flops(cfg: ModelConfig, n_tokens: int,
     fl = 2.0 * body * n_tokens
     fl += 2.0 * (head_tokens if head_tokens is not None else n_tokens) \
         * d * cfg.vocab_size
-    fl += 2.0 * cfg.n_layers * (float(n_tokens) ** 2
+    fl += 2.0 * cfg.cache_layers * (float(n_tokens) ** 2
                                 + 2.0 * kv_start * n_tokens) \
         * _attn_width(cfg) * cfg.n_heads
     return fl
@@ -136,9 +146,10 @@ def prefill_flops(cfg: ModelConfig, n_tokens: int,
 
 def weight_bytes(cfg: ModelConfig, quantized: bool = False) -> float:
     """Bytes of MATMUL weights a decode step streams from HBM (all of
-    them, once — one read serves the whole batch).  The embedding lookup
-    gathers only B rows per step and is excluded (negligible; counting
-    the full table would overstate untied models' bandwidth)."""
+    them, once a pass of the stack — one read serves the whole batch).
+    The embedding lookup gathers only B rows per step and is excluded
+    (negligible; counting the full table would overstate untied models'
+    bandwidth)."""
     import jax.numpy as jnp
 
     itemsize = 1 if quantized else jnp.dtype(cfg.dtype).itemsize
@@ -146,13 +157,14 @@ def weight_bytes(cfg: ModelConfig, quantized: bool = False) -> float:
 
 
 def kv_bytes_per_token(cfg: ModelConfig) -> float:
-    """KV-cache bytes per cached token (K + V, all layers, all kv heads)."""
+    """KV-cache bytes per cached token (K + V, all cache layers, one per
+    (pass, layer) of a looped stack, all kv heads)."""
     import jax.numpy as jnp
 
     if cfg.kv_lora_rank:  # one latent row a layer, as stored
         return (cfg.n_layers * cfg.latent_width
                 * jnp.dtype(cfg.dtype).itemsize)
-    return (2 * cfg.n_layers * cfg.n_kv_heads * cfg.hd
+    return (2 * cfg.cache_layers * cfg.n_kv_heads * cfg.hd
             * jnp.dtype(cfg.dtype).itemsize)
 
 

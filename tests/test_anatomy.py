@@ -209,7 +209,7 @@ def test_dispatch_record_table_and_flat_counters(an):
     assert an.counters() == {
         "prefill_dispatches": 1, "prefill_query_tokens": 150,
         "prefill_token_slots": 256, "prefill_page_writes": 0,
-        "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
+        "layer_passes": 0, "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
         "cold_seconds": 0.0}
     assert an.audit(prefill_tokens=150) == []
     with pytest.raises(ValueError):
