@@ -67,6 +67,21 @@ _pow2_bucket = pow2_bucket
 # multi-second XLA compiles at runtime.  Pure pow2 buckets stay.
 
 
+def prefill_row_rung(group: int, slots: int) -> int:
+    """Operand rows of a batched prefill dispatch that carries ``group``
+    prompts on an engine of ``slots`` slots: 1 for a lone prompt, else the
+    smallest rung of ``8, 16, 32, ...`` capped at ``slots`` that holds the
+    group (24 slots: 1 / 8 / 16 / 24; 4 slots: 1 / 4).  The program pays
+    for its operand, not for the rows that carry work, so a 16-prompt wave
+    on 24 slots must not run the 24-row program; the ladder doubles so a
+    sequence bucket compiles at most ``ceil(log2(slots / 8)) + 2`` row
+    counts.  Reads nothing but its two arguments: one rule for every
+    model, cache type and mesh."""
+    if group <= 1:
+        return 1
+    return min(pow2_bucket(group, 8), slots)
+
+
 @dataclass
 class _SlotState:
     req: GenerationRequest
@@ -755,7 +770,8 @@ class ContinuousScheduler:
             "watchdog_fires": int(self._c_watchdog_fires.value),
             "wedged_requests": int(self._c_wedged.value),
             # sums of the anatomy's dispatch records (obs/anatomy.py):
-            # prefill_dispatches / _query_tokens / _token_slots,
+            # prefill_dispatches / _rows / _row_slots / _query_tokens /
+            # _token_slots,
             # cold_dispatches / cold_seconds; no keys under LMRS_ANATOMY=0
             **self._an.counters(),
         }
@@ -3704,9 +3720,12 @@ class ContinuousScheduler:
         the chunk directly); longer prompts run the windowed continuation
         program per chunk (attends the page window, which includes earlier
         chunks' KV).  Chunks with the same (program, bucket) run as ONE
-        batched dispatch; the batch dim is either 1 or B (padded) so each
-        shape compiles at most twice — XLA compiles are seconds-long and a
-        per-group-size shape zoo would thrash the cache at runtime.
+        batched dispatch whose batch dim is a rung of ``prefill_row_rung``'s
+        short ladder between 1 and B (padded up to the rung): XLA compiles
+        are seconds-long and a per-group-size shape zoo would thrash the
+        cache at runtime, so the ladder doubles and a (program, bucket)
+        compiles at most ``ceil(log2(B / 8)) + 2`` times; a B-row operand for
+        every group would make a two-thirds-full wave pay for the whole.
         """
         groups: dict[tuple, list] = {}
         fresh_pack: list[tuple[int, object, list[int]]] = []
@@ -3773,7 +3792,7 @@ class ContinuousScheduler:
                 if entry[1]:
                     pending.append(entry)
                 continue
-            n = 1 if len(items) == 1 else self.B
+            n = prefill_row_rung(len(items), self.B)
             tokens = np.full((n, s_bucket), self.tokenizer.pad_id, np.int32)
             start = np.zeros((n,), np.int32)
             length = np.ones((n,), np.int32)  # pad rows: 1 token on the null page
@@ -3821,9 +3840,10 @@ class ContinuousScheduler:
                 jnp.asarray(alloc), jnp.asarray(table[:, :w]), sub,
                 jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
             )
-            # n is in the key: [1, S] and [B, S] are two compiled programs
-            # (the dispatch table's q_slots == dispatches x bucket identity
-            # found the key without it calling a compiling [B, S] warm)
+            # n is in the key: every rung of prefill_row_rung is its own
+            # compiled program (the dispatch table's q_slots == dispatches
+            # x bucket identity found the key without it calling a
+            # compiling [B, S] warm)
             key_ = ("prefill", fresh, n, s_bucket, w, ring)
             cold = key_ not in self._ran_ok
             if cold:

@@ -268,7 +268,8 @@ class StepAnatomy:
         self._dispatch_id = 0
         self._unretired: list[int] = []  # ids no fetch has retired yet
         # flat sums of the same records, for ``scheduler.metrics``
-        self._flat = {"prefill_dispatches": 0, "prefill_query_tokens": 0,
+        self._flat = {"prefill_dispatches": 0, "prefill_rows": 0,
+                      "prefill_row_slots": 0, "prefill_query_tokens": 0,
                       "prefill_token_slots": 0, "prefill_page_writes": 0,
                       "layer_passes": 0, "rpa_wide_tokens": 0,
                       "rpa_kv_page_reads": 0, "cold_dispatches": 0,
@@ -459,6 +460,8 @@ class StepAnatomy:
         flat["layer_passes"] += r["layer_passes"]
         if r["program"] in PROMPT_PROGRAMS:
             flat["prefill_dispatches"] += 1
+            flat["prefill_rows"] += r["rows"]
+            flat["prefill_row_slots"] += r["row_slots"]
             flat["prefill_query_tokens"] += r["q_tokens"]
             flat["prefill_token_slots"] += r["q_slots"]
             flat["prefill_page_writes"] += r["page_writes"]

@@ -207,7 +207,8 @@ def test_dispatch_record_table_and_flat_counters(an):
     assert rep["decode"]["q_tokens"] == 11
     assert rep["decode"]["ctx_tokens"] == 150
     assert an.counters() == {
-        "prefill_dispatches": 1, "prefill_query_tokens": 150,
+        "prefill_dispatches": 1, "prefill_rows": 3, "prefill_row_slots": 4,
+        "prefill_query_tokens": 150,
         "prefill_token_slots": 256, "prefill_page_writes": 0,
         "layer_passes": 0, "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
         "cold_seconds": 0.0}
@@ -486,6 +487,12 @@ _PATHS = {
     "fresh_prefill_short_of_a_page": (
         {}, dict(page_size=128, num_pages=8), tiny_model,
         [["one fresh prompt"]], "prefill"),
+    # three prompts of over half the context each (no two share a packed
+    # bin) on 12 slots: one wave on the 8-row rung of the prefill ladder
+    "fresh_prefill_narrow_wave": (
+        {}, dict(max_batch_slots=12, num_pages=64), tiny_model,
+        [[f"speaker {i}: " + "the review covered budgets " * 5
+          for i in range(3)]], "prefill"),
     "packed": ({}, dict(), tiny_model,
                [["packed prompt one", "second packed prompt here"]],
                "packed"),
@@ -576,11 +583,26 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
                 == sum(r["q_tokens"] for r in prompt))
         assert (m1["prefill_token_slots"] - m0["prefill_token_slots"]
                 == sum(r["q_slots"] for r in prompt))
+        for f in ("rows", "row_slots"):
+            assert m1["prefill_" + f] - m0["prefill_" + f] == sum(
+                r[f] for r in prompt), f
         assert (m1["prefill_page_writes"] - m0["prefill_page_writes"]
                 == sum(r["page_writes"] for r in prompt))
         if want == "prefill":
             assert (programs["prefill"]["page_writes"] > 0) == (
-                path == "fresh_prefill")
+                path in ("fresh_prefill", "fresh_prefill_narrow_wave"))
+        if path == "fresh_prefill_narrow_wave":
+            # the key carries the rung, and the record pays for the rung:
+            # 3 rows of 8 (not of the 12 slots), 8 x 256 positions, 8 x 256
+            # / 16 pages a layer
+            (key, krec), = programs["prefill"]["keys"].items()
+            assert key == "prefill:True:8:256:16:False"
+            assert (krec["dispatches"], krec["rows"], krec["row_slots"]) == (
+                1, 3, 8)
+            assert krec["q_slots"] == 1 * 8 * 256
+            assert krec["page_writes"] == 8 * 256 // 16
+            assert m1["prefill_row_slots"] - m0["prefill_row_slots"] == 8
+            assert m1["prefill_rows"] - m0["prefill_rows"] == 3
         assert m1["prefill_tokens"] - m0["prefill_tokens"] == sum(
             r["prompt_tokens"] for r in programs.values())
         # a fresh engine's first sight of each key is its one cold dispatch

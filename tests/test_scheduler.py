@@ -1,5 +1,7 @@
 """Continuous-batching scheduler tests (CPU, tiny model)."""
 
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
@@ -567,3 +569,157 @@ def test_max_new_clamped_to_context_window():
     eng.shutdown()
     assert res.error is None
     assert res.completion_tokens <= mc.max_seq_len - 1
+
+
+# ------------------------------------------- the prefill programs' row ladder
+
+
+@pytest.mark.parametrize("slots", [4, 8, 16, 24, 64])
+def test_prefill_row_rung_holds_the_group_on_a_short_ladder(slots):
+    """The operand rows of a batched prefill dispatch: 1 for a lone prompt,
+    else the smallest of 8, 16, 32, ... capped at the slots that holds the
+    group; a doubling ladder keeps the compiled row counts of a sequence
+    bucket at ceil(log2(slots / 8)) + 2 or fewer (24 slots: 1 / 8 / 16 / 24)."""
+    import math
+
+    from lmrs_tpu.engine.scheduler import prefill_row_rung
+
+    rungs = [prefill_row_rung(k, slots) for k in range(1, slots + 1)]
+    assert rungs[0] == 1
+    for k, n in enumerate(rungs, start=1):
+        assert k <= n <= slots, (k, n)
+        if k > 1:  # a power of two from 8 up, or the cap
+            assert n == slots or (n >= 8 and n & (n - 1) == 0), (k, n)
+            assert n == slots or n < 2 * k or n == 8, (k, n)  # the smallest
+    assert rungs == sorted(rungs) and rungs[-1] == slots
+    assert len(set(rungs)) <= max(math.ceil(math.log2(slots / 8)), 0) + 2
+    want = {4: {1, 4}, 8: {1, 8}, 16: {1, 8, 16}, 24: {1, 8, 16, 24},
+            64: {1, 8, 16, 32, 64}}[slots]
+    assert set(rungs) == want
+
+
+_BENCH = Path(__file__).resolve().parents[1] / "benchmarks"
+
+
+def _family_model(name: str):
+    """(ModelConfig in float32, the family's seeded weights as float32) of a
+    benchmark family's tiny rehearsal configuration, built as
+    tests/test_latent_moe.py and tests/test_looped.py build theirs."""
+    import dataclasses
+    import json
+    import sys
+
+    import jax.numpy as jnp
+
+    for d in (_BENCH.parent, _BENCH):
+        if str(d) not in sys.path:
+            sys.path.insert(0, str(d))
+    import families
+
+    config = json.loads((_BENCH / "configs" / name).read_text())
+    family = families.load(config, name)
+    m = family.sizes(config)
+    cfg = dataclasses.replace(
+        family.model_config("tiny", m, {"max_seq_len": 256}), dtype="float32")
+    params = jax.tree.map(lambda x: x.astype(jnp.float32),
+                          family.make_params(m, 11))
+    return cfg, params
+
+
+# case -> (model and weights, engine overrides, the program of its waves)
+_LADDER_CASES = {
+    "dense": (lambda: (tiny_model(), None), {}, "prefill"),
+    "dense_int8_kv": (lambda: (tiny_model(), None),
+                      {"kv_quantize": "int8", "page_size": 32}, "prefill"),
+    "dense_chunked": (lambda: (tiny_model(), None),
+                      {"prefill_chunk": 64}, "prefill_chunk"),
+    "dense_tp2": (lambda: (tiny_model(), None), {"tp": 2}, "prefill"),
+    "latent": (lambda: _family_model("tiny-mla-moe-rehearsal.json"), {},
+               "prefill"),
+    "looped": (lambda: _family_model("tiny-looped-rehearsal.json"), {},
+               "prefill"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LADDER_CASES))
+def test_a_narrow_prefill_wave_yields_the_tokens_of_the_full_width_one(
+        monkeypatch, case):
+    """Waves of 9, 16 and 5 prompts on 24 slots run the 16-, 16- and 8-row
+    programs; at temperature 0 every request's token ids are those of the
+    parent rule's 24-row dispatch of the same wave (pad rows were one token
+    on the null page whose logits nobody read), on the fresh program, the
+    windowed continuation, int8 pages, a tp mesh, a latent pool and a looped
+    stack."""
+    import inspect
+
+    from lmrs_tpu.config import MeshConfig
+    from lmrs_tpu.engine import scheduler as sched_mod
+
+    monkeypatch.setenv("LMRS_WATCHDOG", "0")
+    make, over, program = _LADDER_CASES[case]
+    mc, params = make()
+    slots = 24
+    kw = dict(backend="jax", scheduler="continuous", max_tokens=6,
+              max_batch_slots=slots, page_size=16, num_pages=1,
+              decode_block=3, prefix_cache=False, host_kv=False,
+              retry_attempts=1, seed=0)
+    kw.update(over)
+    tp = kw.pop("tp", 1)
+    eng = JaxEngine(EngineConfig(**kw), mc,
+                    MeshConfig(dp=1, tp=tp) if tp > 1 else None,
+                    params=params)
+    sched = eng._scheduler
+    served: dict[int, list[int]] = {}
+    finish = sched._finish_slot
+    names = list(inspect.signature(finish).parameters)
+
+    def spy(*a, **k):
+        bound = dict(zip(names, a), **k)
+        served[bound["slots"][bound["b"]].req.request_id] = list(bound["gen"])
+        return finish(*a, **k)
+
+    monkeypatch.setattr(sched, "_finish_slot", spy)
+
+    def wave(k: int, start: int) -> list[GenerationRequest]:
+        # over half the context each: no two share a packed bin, so every
+        # prompt takes a row of the batched program, as a map prompt does
+        return [GenerationRequest(
+            prompt=f"speaker {start + i}: " + "the review covered budgets "
+            * 5 + "and hiring " * (i % 4), request_id=start + i,
+            temperature=0.0, max_new_tokens=6) for i in range(k)]
+
+    def run_waves() -> tuple[dict, dict]:
+        served.clear()
+        an0 = sched.anatomy_snapshot()
+        for k, start in ((9, 0), (16, 100), (5, 200)):
+            out = eng.generate_batch(wave(k, start))
+            assert all(r.error is None and r.completion_tokens > 0
+                       for r in out)
+        assert sched.audit() == []
+        return dict(served), sched.anatomy_report(an0)["programs"][program]
+
+    got, narrow = run_waves()
+    monkeypatch.setattr(sched_mod, "prefill_row_rung",
+                        lambda group, n: 1 if group <= 1 else n)
+    want, full = run_waves()
+    eng.shutdown()
+    assert len(want) == 30 and got == want
+
+    def rows_by_rung(rec: dict) -> dict[int, tuple[int, int, int]]:
+        out: dict[int, tuple[int, int, int]] = {}
+        for key, k in rec["keys"].items():
+            n = int(key.split(":")[2])
+            d, r, s = out.get(n, (0, 0, 0))
+            out[n] = (d + k["dispatches"], r + k["rows"], s + k["row_slots"])
+        return out
+
+    by_rung = rows_by_rung(narrow)
+    assert set(by_rung) == {8, 16}
+    assert set(rows_by_rung(full)) == {slots}
+    for n, (d, rows, row_slots) in by_rung.items():
+        assert row_slots == d * n and rows < row_slots
+    if program == "prefill":  # one dispatch a wave: 9, 16 of 16; 5 of 8
+        assert by_rung == {16: (2, 25, 32), 8: (1, 5, 8)}
+    assert narrow["rows"] == full["rows"]
+    assert narrow["q_tokens"] == full["q_tokens"]
+    assert narrow["q_slots"] < full["q_slots"]

@@ -304,18 +304,26 @@ def test_page_form_write_stays_in_place_with_a_page_as_its_window(one_chip,
     _assert_page_write_in_place(compiled, pool_bytes)
 
 
+@pytest.mark.parametrize("rows", [24, 16], ids=["b24", "b16"])
 @pytest.mark.parametrize("axes", [("tp",), ("sp", "tp")],
                          ids=["tp4", "sp2-tp2"])
-def test_page_form_write_stays_in_place_under_a_mesh(topo, axes):
+def test_page_form_write_stays_in_place_under_a_mesh(topo, axes, rows):
     """The same write with the pool kv-head-sharded over ``tp`` (and the
     wave's rows sequence-sharded over ``sp``, as a ring prefill leaves
     them), compiled for the described v5e:2x2: each chip writes its own
     heads' share of a page in place; no cell runs a mesh, so this compile is
-    what lets ``forward_paged`` take the page form there too."""
+    what lets ``forward_paged`` take the page form there too.  ``b16`` is a
+    16-prompt wave on the 24 slots: a rung of the prefill programs' row
+    ladder below the slots (``scheduler.prefill_row_rung``), whose operand
+    no mesh axis divides."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    layers, n_pool, kh, lanes, b, s, _ = _PAGE_WRITES["mistral-int8"]
+    from lmrs_tpu.engine.scheduler import prefill_row_rung
+
+    layers, n_pool, kh, lanes, slots, s, _ = _PAGE_WRITES["mistral-int8"]
+    b = prefill_row_rung(rows, slots)
+    assert b == rows
     ps, dt = 128, jnp.bfloat16
     shape = (4,) if len(axes) == 1 else (2, 2)
     mesh = Mesh(np.array(topo.devices).reshape(shape), axes)
