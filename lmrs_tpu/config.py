@@ -108,6 +108,11 @@ class ModelConfig:
     # What ``LoopedModelConfig`` adds, likewise: the stack runs once, no
     # second norm on a sublayer's output, no token leaves the loop early.
     n_loops, sandwich_norm, early_exit_threshold = 1, False, 1.0
+    # What ``WindowMoEModelConfig`` adds, likewise: every layer a full
+    # layer, a norm on every sublayer's input, no norm on q and k, rope on
+    # every layer.
+    sliding_window, window_pattern = 0, ""
+    norm_inputs, qk_norm, rope_window_only = True, False, False
 
     def __post_init__(self) -> None:
         if self.n_loops < 1:
@@ -145,6 +150,27 @@ class ModelConfig:
     def n_routed_layers(self) -> int:
         return (self.n_layers - self.n_dense_layers
                 if self.n_routed_experts else 0)
+
+    @property
+    def layer_windows(self) -> tuple[int, ...]:
+        """Per layer, the positions back that its attention sees: 0 on a
+        full layer (every earlier position), ``sliding_window`` where the
+        layer's letter in ``window_pattern`` (repeated over the layers) is
+        ``L``."""
+        if not self.sliding_window:
+            return (0,) * self.n_layers
+        pat = self.window_pattern or "L"
+        return tuple(self.sliding_window if pat[i % len(pat)] == "L" else 0
+                     for i in range(self.n_layers))
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(1 for w in self.layer_windows if w)
+
+    def window_ring_pages(self, page_size: int) -> int:
+        """Pages a window layer keeps a sequence, however long it grows:
+        ``ceil(window / page_size) + 1`` (engine/kv_cache.py)."""
+        return -(-self.sliding_window // page_size) + 1
 
     @property
     def experts_held(self) -> int:
@@ -203,6 +229,48 @@ class LoopedModelConfig(ModelConfig):
     n_loops: int = 1
     sandwich_norm: bool = False
     early_exit_threshold: float = 1.0
+
+
+@dataclass
+class WindowMoEModelConfig(ModelConfig):
+    """GQA attention whose layers differ in kind (models/windowed.py; the
+    EXAONE-4 block, with the DeepSeek-V3 routed layer behind it where
+    ``n_routed_experts`` > 0).  ``sliding_window`` > 0 selects it: a layer
+    whose letter in ``window_pattern`` (``"LLLG"``, repeated over the
+    layers) is ``L`` sees ``sliding_window`` positions back, position i the
+    keys j with 0 <= i - j < window; a ``G`` layer sees them all.  A window
+    layer's cache is a ring of ``window_ring_pages`` pages a slot.  The
+    routed fields are ``LatentModelConfig``'s, over K and V pools.
+
+    Three block switches, each static: ``norm_inputs`` False leaves a
+    sublayer's input un-normed (with ``sandwich_norm`` the norms sit on the
+    outputs alone); ``qk_norm`` norms q and k over each head's width before
+    rope; ``rope_window_only`` leaves the full layers without rope."""
+
+    sliding_window: int = 0
+    window_pattern: str = ""
+    norm_inputs: bool = True
+    qk_norm: bool = False
+    rope_window_only: bool = False
+    sandwich_norm: bool = False
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    expert_first: int = 0
+    n_experts_held: int = 0
+    n_dense_layers: int = 0
+    dense_hidden_dim: int = 0
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.sliding_window <= 0:
+            raise ValueError("WindowMoEModelConfig: sliding_window > 0 is "
+                             "what selects models/windowed.py")
+        if set(self.window_pattern) - set("LG"):
+            raise ValueError(f"window_pattern {self.window_pattern!r}: L "
+                             "(window) and G (full) only")
+        if self.n_loops != 1:
+            raise ValueError("a windowed stack runs once (n_loops 1)")
 
 
 @dataclass
@@ -690,6 +758,18 @@ def model_preset(name: str) -> ModelConfig:
             norm_eps=1e-6, tie_embeddings=False, n_loops=3,
             sandwich_norm=True,
         ),
+        "tiny-swa-moe": dict(
+            # the EXAONE-4 block over routed experts at test size: three
+            # window layers (16) to a full one, a dense leading layer, 8
+            # routed experts top-2 with a shared one
+            vocab_size=512, dim=64, n_layers=5, n_heads=4, n_kv_heads=2,
+            head_dim=16, hidden_dim=32, dense_hidden_dim=96, max_seq_len=256,
+            rope_theta=10000.0, tie_embeddings=False, sliding_window=16,
+            window_pattern="LLLG", norm_inputs=False, sandwich_norm=True,
+            qk_norm=True, rope_window_only=True, n_dense_layers=1,
+            n_routed_experts=8, n_shared_experts=1, n_experts_per_token=2,
+            routed_scaling_factor=2.5,
+        ),
         "mixtral-8x7b": dict(
             vocab_size=32000, dim=4096, n_layers=32, n_heads=32, n_kv_heads=8,
             hidden_dim=14336, max_seq_len=8192, rope_theta=1e6,
@@ -699,6 +779,7 @@ def model_preset(name: str) -> ModelConfig:
     if name not in presets:
         raise ValueError(f"unknown model preset {name!r}; have {sorted(presets)}")
     cls = (LatentModelConfig if "kv_lora_rank" in presets[name]
+           else WindowMoEModelConfig if "sliding_window" in presets[name]
            else LoopedModelConfig if "n_loops" in presets[name]
            else ModelConfig)
     return cls(name=name, **presets[name])

@@ -143,12 +143,16 @@ class JaxEngine:
             from lmrs_tpu.parallel.mesh import build_mesh
 
             self._mesh = build_mesh(mesh_cfg, devices)
-        if model_cfg.kv_lora_rank and engine_cfg.scheduler == "continuous":
-            # before any weight is placed: what a latent cache cannot be
-            # combined with is refused by name (scheduler.py)
+        if engine_cfg.scheduler == "continuous":
+            # before any weight is placed: what a latent or a window cache
+            # cannot be combined with is refused by name (scheduler.py)
             from lmrs_tpu.engine.scheduler import ContinuousScheduler
 
-            ContinuousScheduler._refuse_for_latent(engine_cfg, self._mesh)
+            if model_cfg.kv_lora_rank:
+                ContinuousScheduler._refuse_for_latent(engine_cfg, self._mesh)
+            if model_cfg.sliding_window:
+                ContinuousScheduler._refuse_for_window(
+                    engine_cfg, self._mesh, model_cfg.max_seq_len)
         key = jax.random.PRNGKey(engine_cfg.seed)
         t0 = time.time()
         quantized = False

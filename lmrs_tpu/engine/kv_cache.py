@@ -155,11 +155,20 @@ class PagedKVCache:
     ``k`` is [L*P, 1, page_size, latent_width], a token's row the normed
     latent beside the shared rotary key (models/latent.py), and ``v`` is
     None; every program carries the pair through as it carries K and V.
+
+    Under a sliding window (``ModelConfig.sliding_window``) the pool holds
+    TWO KINDS of layer (models/windowed.pool_layout, ``self.window``): the
+    full layers as above, ``num_pages`` each, and behind them the window
+    layers, each a ring of ``ceil(window / page_size) + 1`` pages for every
+    one of ``slots`` slots plus a null page, whatever ``num_pages`` is: a
+    window layer's share does not grow with the sequences.  The allocator
+    and the page tables are the full layers'; a window layer's table is
+    its slot's ring, built inside the forward from the row's slot.
     """
 
     def __init__(self, model_cfg: ModelConfig, num_pages: int, page_size: int,
                  max_pages_per_slot: int, allocator: PageAllocator | None = None,
-                 mesh=None, kv_dtype: str | None = None):
+                 mesh=None, kv_dtype: str | None = None, slots: int = 0):
         hd = model_cfg.hd
         self.page_size = page_size
         self.num_pages = num_pages
@@ -173,6 +182,19 @@ class PagedKVCache:
         shape = (model_cfg.cache_layers * num_pages, model_cfg.n_kv_heads,
                  page_size, hd)
         self.latent = bool(model_cfg.kv_lora_rank)
+        self.window = None
+        if model_cfg.sliding_window:
+            if kv_dtype or (mesh is not None and mesh.devices.size > 1):
+                raise ValueError(
+                    "window KV cache (sliding_window > 0): no int8 pages "
+                    "and no mesh of several devices")
+            if slots < 1:
+                raise ValueError("window KV cache: the rings are sized by "
+                                 "the engine's slots")
+            from lmrs_tpu.models.windowed import pool_layout
+
+            self.window = pool_layout(model_cfg, page_size, slots, num_pages)
+            shape = (self.window["total"], *shape[1:])
         if self.latent:
             if kv_dtype or (mesh is not None and mesh.devices.size > 1):
                 raise ValueError(
@@ -229,12 +251,28 @@ class PagedKVCache:
             self.v = jnp.zeros(self.v.shape, self.v.dtype,
                                device=self.v.sharding)
 
-    def _no_latent(self, op: str) -> None:
+    def _no_export(self, op: str) -> None:
         if self.latent:
             raise NotImplementedError(
                 f"latent KV cache: {op} (page export/import: prefix-cache "
                 "spill, handoff, migration) is not built for the one-pool "
                 "layout")
+        if self.window is not None:
+            raise NotImplementedError(
+                f"window KV cache: {op} (page export/import: prefix-cache "
+                "spill, handoff, migration) is not built for the two-kind "
+                "pool: a window layer holds a ring a slot, not the "
+                "sequence's pages")
+
+    def kind_pages(self) -> tuple[int, int]:
+        """(pages the full layers hold, pages the window layers hold), null
+        pages left out: the pool's two shares.  (every page, 0) without a
+        window."""
+        if self.window is None:
+            return (self.n_layers * (self.num_pages - 1), 0)
+        w = self.window
+        return (w["n_full"] * (self.num_pages - 1),
+                w["n_win"] * (w["win_pages"] - 1))
 
     def pages_needed(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
@@ -312,7 +350,7 @@ class PagedKVCache:
         separately).  The sequence itself is untouched: the caller keeps
         the pages pinned until the importer acks (scheduler pin class).
         """
-        self._no_latent("export_sequence")
+        self._no_export("export_sequence")
         faults.fire("handoff.export")
         n = self.pages_needed(max(1, length))
         if n > len(seq.pages):
@@ -346,7 +384,7 @@ class PagedKVCache:
         ``export_sequence`` (one host sync), minus the
         sequence framing: the prefix cache's radix node carries the token
         labels, so the payload is just raw page content + dtype."""
-        self._no_latent("export_pages")
+        self._no_export("export_pages")
         phys = jnp.asarray(self._phys_ids(pages))
         k, v = (np.asarray(a)
                 for a in jax.device_get((self.k[phys], self.v[phys])))
@@ -370,7 +408,7 @@ class PagedKVCache:
         (``LMRS_HOST_KV_SYNC`` A/B fallback).  Geometry/dtype mismatches
         raise ``ValueError`` — same rejection discipline as
         ``import_sequence``; the caller re-prefills."""
-        self._no_latent("import_pages")
+        self._no_export("import_pages")
         n = len(pages)
         if payload.get("dtype") != str(self.k.dtype):
             raise ValueError(
@@ -411,7 +449,7 @@ class PagedKVCache:
         under pool pressure (back-pressure: the importer retries, never
         corrupts).  On any failure after allocation the pages are freed —
         a failed import must not leak."""
-        self._no_latent("import_sequence")
+        self._no_export("import_sequence")
         faults.fire("handoff.import")
         kh, ps, hd = (int(x) for x in self.k.shape[1:])
         want = {"page_size": self.page_size, "n_layers": self.n_layers,

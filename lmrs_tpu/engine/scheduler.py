@@ -50,6 +50,7 @@ from lmrs_tpu.obs import (POW2_TOKEN_BUCKETS, RATIO_BUCKETS, CostLedger,
                           DispatchAttribution, MetricsRegistry, SLOEngine,
                           dump_postmortem, get_tracer, maybe_anatomy, req_tid,
                           span)
+from lmrs_tpu.obs.anatomy import WINDOW_FIELDS
 from lmrs_tpu.ops.sampling import sample_logits
 from lmrs_tpu.testing import faults
 from lmrs_tpu.utils.env import env_bool, env_float, env_int, env_str
@@ -183,9 +184,16 @@ class ContinuousScheduler:
         # every program (``_moe_take``)
         self._moe_on = bool(model_cfg.n_routed_experts)
         self._moe_pending: list[tuple[str, tuple, object]] = []
+        # A windowed stack (models/windowed.py) keeps two kinds of cache
+        # layer in one pool, a window layer's share a ring a slot: what is
+        # not built for that is refused here too
+        self._window = int(model_cfg.sliding_window)
+        if self._window:
+            self._refuse_for_window(engine_cfg, mesh, self.max_len)
         self.cache = PagedKVCache(model_cfg, num_pages, ps, max_pages_per_slot,
                                   mesh=mesh,
-                                  kv_dtype="int8" if self._kv_quant else None)
+                                  kv_dtype="int8" if self._kv_quant else None,
+                                  slots=self.B)
         if self._kv_quant:
             sshape = (model_cfg.cache_layers, self.B, model_cfg.n_kv_heads,
                       model_cfg.hd)
@@ -219,7 +227,7 @@ class ContinuousScheduler:
         # (measured ~43% padded q rows at the bench shape).  LMRS_PACK_PREFILL=0
         # restores per-prompt prefill for A/B measurement.
         self._pack_prefill = (env_bool("LMRS_PACK_PREFILL", True)
-                              and not self._latent)
+                              and not self._latent and not self._window)
         # int8 KV composes with packing since r4 (VERDICT r3 item 3): the
         # packed program computes per-SEGMENT scales and scatters them into
         # each segment's slot row — no gate needed
@@ -305,9 +313,16 @@ class ContinuousScheduler:
             logger.info("latent KV cache: packed prefill, mixed steps and "
                         "the span program are off (fresh prefill, chunked "
                         "continuation and decode blocks serve it)")
+        if self._window:
+            logger.info("window KV cache: packed prefill, mixed steps and "
+                        "the span program are off (a packed row and a span "
+                        "write every layer's pages of a sequence; a window "
+                        "layer keeps a ring): fresh prefill and decode "
+                        "blocks serve it")
         self._rpa_fns: dict[tuple, object] = {}
         self._mixed = (engine_cfg.mixed_batch and env_bool("LMRS_MIXED", True)
-                       and not self._use_ring and not self._latent)
+                       and not self._use_ring and not self._latent
+                       and not self._window)
         self.mixed_token_budget = max(32, engine_cfg.mixed_token_budget)
         # Tree speculation on the span family (ISSUE 19): the linear draft
         # becomes LMRS_SPEC_TREE_WIDTH root-branching chains drafted
@@ -700,6 +715,14 @@ class ContinuousScheduler:
                                  metrics_cb=lambda: self.metrics)
         if self._moe_on:
             self._an.has_moe = True  # the moe_* counters, from the start
+        if self._window:
+            self._an.has_window = True  # the kv_pages_* / flash_* counters
+            full, win = self.cache.kind_pages()
+            g = self.registry.gauge
+            g("lmrs_cache_pages_full", "pool pages the full attention "
+              "layers hold (null pages left out)").set(full)
+            g("lmrs_cache_pages_window", "pool pages the window attention "
+              "layers hold: slots x ring x window layers").set(win)
         # LMRS_PROFILE_ON_SLOW_STEP: a decode block slower than the
         # threshold (warm shapes only) triggers ONE jax.profiler capture
         # per process into LMRS_PROFILE_DIR — the "why was that step
@@ -774,6 +797,9 @@ class ContinuousScheduler:
             # _token_slots,
             # cold_dispatches / cold_seconds; no keys under LMRS_ANATOMY=0
             **self._an.counters(),
+            # the pool's two shares (gauges; a windowed model only)
+            **(dict(zip(("cache_pages_full", "cache_pages_window"),
+                        self.cache.kind_pages())) if self._window else {}),
         }
 
     def metrics_registry(self) -> MetricsRegistry:
@@ -969,6 +995,15 @@ class ContinuousScheduler:
             # LMRS_ANATOMY=0 — the pre-anatomy report is byte-identical
             **({"anatomy": self.anatomy_report()}
                if self._an.enabled else {}),
+            # a windowed model only: the pool's two kinds of layer
+            **({"window_cache": {
+                "window": self._window,
+                "ring_pages": self.cache.window["ring"],
+                "full_layers": self.cache.window["n_full"],
+                "window_layers": self.cache.window["n_win"],
+                "cache_pages_full": m["cache_pages_full"],
+                "cache_pages_window": m["cache_pages_window"]}}
+               if self._window else {}),
             **({"spec_accepted_tokens": m["spec_accepted_tokens"]}
                if self.spec_k else {}),
             **({"spec_tree": self._spec_tree_report()}
@@ -1000,7 +1035,7 @@ class ContinuousScheduler:
         tokens, pow2 window) only)."""
         m = self.metrics
         return {
-            "enabled": not self._latent,
+            "enabled": not self._latent and not self._window,
             "dispatches": m["rpa_dispatches"],
             "span_tokens": int(m["rpa_span_tokens"]),
             "compile_shapes": m["rpa_compile_shapes"],
@@ -1138,6 +1173,88 @@ class ContinuousScheduler:
                 raise ValueError(
                     f"latent KV cache (kv_lora_rank > 0) does not support "
                     f"{what}; turn it off for this model")
+
+    @staticmethod
+    def _refuse_for_window(engine_cfg: EngineConfig, mesh,
+                           max_len: int) -> None:
+        """What a window KV cache (a ring of pages a slot in the window
+        layers) cannot be combined with yet, each by its name."""
+        tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+        sp = 1 if mesh is None else mesh.shape.get("sp", 1)
+        for bad, what in (
+            (engine_cfg.prefix_cache and env_bool("LMRS_PREFIX_CACHE", True),
+             "prefix_cache (a page behind a shared prefix is valid for the "
+             "full layers and only partly for the window layers, and a hit "
+             "continues through the span program)"),
+            (engine_cfg.kv_quantize, "kv_quantize (int8 KV pages: the "
+                                     "windowed decode walk and the ring "
+                                     "write have no scaled form)"),
+            (engine_cfg.quantize, "quantize (int8 weights: the routed "
+                                  "experts have no int8 path)"),
+            (engine_cfg.speculate_k, "speculate_k (the verify kernels walk "
+                                     "every layer from page 0; the model's "
+                                     "own draft module is not held)"),
+            (tp > 1, "tp > 1 (the rings are not sharded by kv head)"),
+            (sp > 1, "sp > 1 (ring prefill writes every layer's pages)"),
+            (mesh is not None and mesh.devices.size > 1,
+             "a mesh of more than one device"),
+            (engine_cfg.prefill_chunk < max_len,
+             f"prefill_chunk={engine_cfg.prefill_chunk} < max_seq_len="
+             f"{max_len} (the continuation of a chunked prompt reads "
+             "earlier chunks' pages, which a window layer has not kept)"),
+            (engine_cfg.scheduler != "continuous",
+             f"scheduler={engine_cfg.scheduler!r} (only the continuous "
+             "scheduler carries the two-kind pool)"),
+        ):
+            if bad:
+                raise ValueError(
+                    f"window KV cache (sliding_window > 0) does not support "
+                    f"{what}; turn it off for this model")
+
+    def _window_counts(self, *, prefill=None, decode=None) -> dict | None:
+        """A windowed model's share of a dispatch record (obs/anatomy.
+        WINDOW_FIELDS), by the kernels' rules on the host.  ``decode``
+        (lengths of the live rows at the block's start, steps): the pages
+        the block's steps walk, a full layer ceil(len / page) at every
+        step, a window layer the pages from position len - window on
+        (ops/paged_attention.window_walk).  ``prefill`` (real tokens a
+        row, bucket): the flash kernel's causal (q tile, kv tile) pairs at
+        the windowed call's tile over ALL attention layers, and those of
+        the window layers that lie wholly behind the window (the banded
+        grid never visits them); 0 where the XLA attention serves."""
+        if not self._window:
+            return None
+        ps, w = self.cache.page_size, self._window
+        lay = self.cache.window
+        out = dict.fromkeys(WINDOW_FIELDS, 0)
+        if decode is not None:
+            lens, steps = decode
+            # step t of the block attends len + t + 1 positions
+            n = (np.asarray(lens, np.int64)[:, None]
+                 + np.arange(1, steps + 1)[None, :])
+            n = np.minimum(n, self.max_len)
+            full = -(-n // ps)
+            win = (n - 1) // ps - np.maximum(n - w, 0) // ps + 1
+            out["kv_pages_full"] = int(full.sum()) * lay["n_full"]
+            out["kv_pages_window"] = int(win.sum()) * lay["n_win"]
+        if prefill is not None:
+            from lmrs_tpu.models.transformer import _use_flash_prefill
+            from lmrs_tpu.ops.flash_attention import (window_band,
+                                                      window_block)
+            lens, bucket = prefill
+            if self._use_flash and _use_flash_prefill(
+                    bucket, self.model_cfg.hd, self._interpret):
+                tile = min(window_block(w), bucket)
+                band = window_band(w, tile)
+                for n in lens:
+                    nq = -(-int(n) // tile)
+                    pairs = nq * (nq + 1) // 2
+                    seen = sum(min(q + 1, band) for q in range(nq))
+                    out["flash_blocks"] += pairs * (lay["n_full"]
+                                                    + lay["n_win"])
+                    out["flash_blocks_skipped"] += ((pairs - seen)
+                                                    * lay["n_win"])
+        return out
 
     def _moe_take(self, program: str, key: tuple, out: tuple) -> tuple:
         """A routed model's programs return the held experts' counts last
@@ -3856,7 +3973,9 @@ class ContinuousScheduler:
                     ctx_tokens=sum(p for _, _, _, p, _ in items),
                     page_writes=(n * whole_pages(
                         s_bucket, self.cache.page_size, w) if fresh else 0),
-                    layer_passes=self.model_cfg.cache_layers, cold=cold):
+                    layer_passes=self.model_cfg.cache_layers, cold=cold,
+                    window=self._window_counts(prefill=(
+                        [len(c) for _, _, c, _, _ in items], s_bucket))):
                 fn = (self._get_prefill_fn(s_bucket, use_ring=ring)
                       if fresh
                       else self._get_prefill_window_fn(s_bucket, w))
@@ -4107,6 +4226,7 @@ class ContinuousScheduler:
         interp = self._interpret
         kv_q = bool(self._kv_quant)
         moe = self._moe_on
+        n_slots = self.B
         if use_ring and s_bucket % self._sp:
             raise ValueError(
                 f"ring prefill bucket {s_bucket} not divisible by "
@@ -4134,6 +4254,7 @@ class ContinuousScheduler:
                 kv_scales=(kscale, vscale) if kv_q else None,
                 scale_rows=scale_rows,
                 token_valid=(positions < length[:, None]) if moe else None,
+                window_slots=n_slots,
             )
             logits, k_pages, v_pages = out[:3]
             kscale, vscale = out[3] if kv_q else (None, None)
@@ -4315,7 +4436,9 @@ class ContinuousScheduler:
                 q_slots=bc * self.decode_block,
                 ctx_tokens=attr_live_tokens,
                 layer_passes=self.model_cfg.cache_layers * self.decode_block,
-                cold=not decode_warm) as disp:
+                cold=not decode_warm,
+                window=self._window_counts(decode=(
+                    kv_lens[active], self.decode_block))) as disp:
             out = self._get_decode_fn(w)(*args)
         self._note_ran_ok(key_)
         toks, n_valid, self.cache.k, self.cache.v = self._moe_take(
@@ -4374,6 +4497,10 @@ class ContinuousScheduler:
 
         kv_q = bool(self._kv_quant)
         moe = self._moe_on
+        # a window layer's pages are its slot's ring: the rows' slots ride
+        # along as they do for the int8 scales
+        rows_to_slots = kv_q or bool(self._window)
+        n_slots = self.B
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def decode(params, k_pages, v_pages, kscale, vscale, scale_rows,
@@ -4387,9 +4514,10 @@ class ContinuousScheduler:
                     use_ragged_kernel=use_ragged,
                     mesh=mesh_, interpret=interp,
                     kv_scales=(kscale, vscale) if kv_q else None,
-                    scale_rows=scale_rows if kv_q else None,
+                    scale_rows=scale_rows if rows_to_slots else None,
                     decode_row_group=row_group,
                     token_valid=(~done)[:, None] if moe else None,
+                    window_slots=n_slots,
                 )
                 logits, k_pages, v_pages = out[:3]
                 key, sub = jax.random.split(key)

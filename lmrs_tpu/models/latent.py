@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from lmrs_tpu.config import ModelConfig
+from lmrs_tpu.models.blocks import ffn, layer_groups as _groups, split_experts
 from lmrs_tpu.ops.attention import attention
 from lmrs_tpu.ops.norms import rms_norm
 from lmrs_tpu.ops.rope import (apply_rope, rope_table, yarn_inv_freq,
@@ -182,63 +183,8 @@ def latent_rows(cfg: ModelConfig, c_kv, k_rope):
         axis=-1)
 
 
-def _swiglu(mp, cfg: ModelConfig, h):
-    from lmrs_tpu.models.transformer import gate_act
-
-    dt = h.dtype
-    gate = jnp.einsum("bsd,df->bsf", h, mp["w_gate"])
-    up = jnp.einsum("bsd,df->bsf", h, mp["w_up"])
-    return jnp.einsum("bsf,fd->bsd", gate_act(cfg, gate).astype(dt) * up,
-                      mp["w_down"])
-
-
-_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
-
-
-def split_experts(group):
-    """A layer group for a scan: (what the scan slices a layer at a time,
-    the stacked expert leaves it must NOT slice).  ``ffn`` takes them
-    whole with the layer's index (ops/moe.routed_experts says why)."""
-    if "moe" not in group:
-        return group, None
-    moe = group["moe"]
-    scanned = {**group, "moe": {k: v for k, v in moe.items()
-                                if k not in _EXPERT_LEAVES}}
-    return scanned, {k: moe[k] for k in _EXPERT_LEAVES}
-
-
-def ffn(lp, cfg: ModelConfig, h, token_valid=None, experts=None,
-        layer=None):
-    """(out, stats): the dense FFN of a leading layer (stats None), or the
-    held experts' part of a routed layer plus the shared expert.
-    ``experts``/``layer``: the group's stacked expert leaves and this
-    layer's index in it (``split_experts``)."""
-    if "moe" not in lp:
-        return _swiglu(lp["mlp"], cfg, h), None
-    from lmrs_tpu.ops.moe import routed_experts
-
-    mp = lp["moe"] if experts is None else {**lp["moe"], **experts}
-    out, stats = routed_experts(mp, cfg, h, token_valid,
-                                layer=None if experts is None else layer)
-    if "shared" in lp:
-        with jax.named_scope("moe.shared"):
-            out = out + _swiglu(lp["shared"], cfg, h)
-    return out, stats
-
-
 def _out_proj(lp, o):
     return jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["wo"])
-
-
-def _groups(params):
-    """The layer groups in model order: (stacked params, layer count)."""
-    out = []
-    if "dense_layers" in params:
-        g = params["dense_layers"]
-        out.append((g, g["ln_attn"]["scale"].shape[0]))
-    g = params["layers"]
-    out.append((g, g["ln_attn"]["scale"].shape[0]))
-    return out
 
 
 def _head(params, cfg: ModelConfig, x):

@@ -50,6 +50,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         from lmrs_tpu.models import latent
 
         return latent.init_params(cfg, key)
+    if cfg.sliding_window:  # window and full layers: models/windowed.py
+        from lmrs_tpu.models import windowed
+
+        return windowed.init_params(cfg, key)
     dt = _dtype(cfg)
     hd = cfg.hd
     k_embed, k_layers, k_head = jax.random.split(key, 3)
@@ -281,6 +285,15 @@ def forward(
         from lmrs_tpu.models import latent
 
         return latent.forward(params, cfg, tokens, positions, kv_length)
+    if cfg.sliding_window:
+        if cache is not None or attn_fn is not None or return_aux or remat:
+            raise NotImplementedError(
+                "a windowed stack (sliding_window > 0) has the plain forward "
+                "and the paged one: no dense KV cache, ring attention, aux "
+                "loss or remat")
+        from lmrs_tpu.models import windowed
+
+        return windowed.forward(params, cfg, tokens, positions, kv_length)
     dt = _dtype(cfg)
     b, s = tokens.shape
     hd = cfg.hd
@@ -407,6 +420,8 @@ def forward_paged(
     token_valid: jnp.ndarray | None = None,  # [B, S] bool: tokens that carry
                                  # work (not padding, not an idle row); read
                                  # by routed layers only (ops/moe.py)
+    window_slots: int = 0,  # a windowed stack's pool (models/windowed.py):
+                            # the engine's slot count, which sizes the rings
 ) -> tuple:
     """Forward pass against a paged KV cache (engine/kv_cache.PagedKVCache).
 
@@ -490,6 +505,24 @@ def forward_paged(
             rope_max, use_ragged_kernel=use_ragged_kernel,
             window_prefill=window_prefill, use_flash=use_flash,
             interpret=interpret, last_pos=last_pos, token_valid=token_valid)
+
+    if cfg.sliding_window:
+        # window and full layers over a two-kind pool (models/windowed.py);
+        # the engine refuses at start what is named here
+        if (kv_scales is not None or spans is not None or multi_decode
+                or segment_ids is not None or use_ring or mesh is not None):
+            raise NotImplementedError(
+                "window cache: no int8 KV, span or packed program, "
+                "speculative verify, ring prefill or mesh")
+        from lmrs_tpu.models import windowed
+
+        return windowed.forward_paged(
+            params, cfg, tokens, positions, k_pages, v_pages, page_tables,
+            kv_lens, rope_max, window_slots=window_slots,
+            use_ragged_kernel=use_ragged_kernel,
+            window_prefill=window_prefill, use_flash=use_flash,
+            interpret=interpret, last_pos=last_pos, token_valid=token_valid,
+            scale_rows=scale_rows, decode_row_group=decode_row_group)
 
     if kv_scales is not None:
         # int8 KV: packed prefill composes (per-SEGMENT scales, r4 — each

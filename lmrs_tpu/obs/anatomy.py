@@ -83,6 +83,13 @@ RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
 MOE_FIELDS: tuple[str, ...] = ("moe_routed_pairs", "moe_expert_tokens_max",
                                "moe_expert_tokens_mean", "moe_extra_passes")
 
+# what a windowed model's dispatches add to their record (``dispatch``'s
+# ``window``): pages its decode steps walk in full and in window layers,
+# and the flash prefill's tile pairs, all and those the window leaves out;
+# absent from every other model's records and report
+WINDOW_FIELDS: tuple[str, ...] = ("kv_pages_full", "kv_pages_window",
+                                  "flash_blocks", "flash_blocks_skipped")
+
 # iteration step classes (the report's per-class split axis)
 CLASSES: tuple[str, ...] = ("plain", "mixed", "spec", "prefill")
 
@@ -278,6 +285,9 @@ class StepAnatomy:
         # dense model's counters and report stay as they were
         self._moe_flat = dict.fromkeys(MOE_FIELDS, 0)
         self.has_moe = False
+        # windowed models only, likewise (``has_window``)
+        self._window_flat = dict.fromkeys(WINDOW_FIELDS, 0)
+        self.has_window = False
 
         c, g, h = (registry.counter, registry.gauge, registry.histogram)
         self._c_iters = c("lmrs_anatomy_iterations_total",
@@ -413,7 +423,8 @@ class StepAnatomy:
                  row_slots: int, q_tokens: int, prompt_tokens: int,
                  q_slots: int, ctx_tokens: int, cold: bool,
                  wide_tokens: int = 0, kv_page_reads: int = 0,
-                 page_writes: int = 0, layer_passes: int = 0) -> _Dispatch:
+                 page_writes: int = 0, layer_passes: int = 0,
+                 window: dict | None = None) -> _Dispatch:
         """The ``dispatch`` segment of one device dispatch, with what it
         carried.  ``program`` is one of ``PROGRAMS`` and ``key`` the site's
         own compile key; ``rows`` carry work out of ``row_slots`` operand
@@ -433,7 +444,9 @@ class StepAnatomy:
         dispatch says how many layer applications it runs
         (``layer_passes``: the model's ``cache_layers``, ``n_layers`` x
         the passes of a looped stack, times the steps of a decode or
-        speculative block; one step for a prefill or span dispatch)."""
+        speculative block; one step for a prefill or span dispatch).  A
+        windowed model's dispatch adds ``window``: ``WINDOW_FIELDS`` by the
+        host's rule (``ContinuousScheduler._window_counts``)."""
         if program not in PROGRAMS:
             raise ValueError(f"unknown dispatch program {program!r} "
                              f"(want one of {PROGRAMS})")
@@ -445,7 +458,8 @@ class StepAnatomy:
             "wide_tokens": int(wide_tokens),
             "kv_page_reads": int(kv_page_reads),
             "page_writes": int(page_writes),
-            "layer_passes": int(layer_passes), "cold": bool(cold)})
+            "layer_passes": int(layer_passes), "cold": bool(cold),
+            **{f: int(v) for f, v in (window or {}).items()}})
 
     def _fold(self, r: dict) -> None:
         rec = self._table.get((r["program"], r["key"]))
@@ -455,6 +469,10 @@ class StepAnatomy:
                 "slots": r["q_slots"]}
         for f in RECORD_FIELDS:
             rec[f] += r[f]
+        for f in WINDOW_FIELDS:
+            if f in r:
+                rec[f] = rec.get(f, 0) + r[f]
+                self._window_flat[f] += r[f]
         flat = self._flat
         flat["cold_dispatches"] += r["cold"]
         flat["layer_passes"] += r["layer_passes"]
@@ -500,9 +518,8 @@ class StepAnatomy:
         (the ``rpa`` program), layer applications, cold dispatches and
         their wall (all programs); for a routed model, the ``MOE_FIELDS``
         sums over all programs."""
-        if self.has_moe:
-            return {**self._flat, **self._moe_flat}
-        return dict(self._flat)
+        return {**self._flat, **(self._moe_flat if self.has_moe else {}),
+                **(self._window_flat if self.has_window else {})}
 
     # --------------------------------------------------------------- reading
 
@@ -642,7 +659,8 @@ def _programs_report(table: dict, before: dict) -> dict:
         d = {f: rec[f] - b.get(f, 0) for f in RECORD_FIELDS}
         if not d["dispatches"]:
             continue
-        moe = {f: rec[f] - b.get(f, 0) for f in MOE_FIELDS if f in rec}
+        moe = {f: rec[f] - b.get(f, 0)
+               for f in (*MOE_FIELDS, *WINDOW_FIELDS) if f in rec}
         d["cold_ms"] = (rec["cold_s"] - b.get("cold_s", 0.0)) * 1e3
         tot = programs.setdefault(program, {
             **dict.fromkeys(RECORD_FIELDS, 0), "cold_ms": 0.0, "keys": {}})

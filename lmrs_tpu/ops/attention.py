@@ -31,14 +31,17 @@ def attention(
     kv_length: jnp.ndarray | None = None,  # [B] valid KV prefix length
     logit_softcap: float | None = None,
     scale: float | None = None,  # softmax scale; None: hd ** -0.5
+    window=None,  # int or traced int32 scalar: positions back a query sees
+                  # (itself included); 0 or None: all of them
 ) -> jnp.ndarray:
     """Causal attention over a (possibly padded) KV buffer.  ``v`` may be
     narrower than ``q`` and ``k`` (latent attention): the output takes
     ``v``'s width.
 
     Masking rule: query at absolute position p attends KV slots [0, p], and
-    only slots < kv_length are valid.  Works for both prefill (Sq == Skv,
-    positions 0..S-1) and single-token decode (Sq == 1 against the cache).
+    only slots < kv_length are valid; with ``window`` w > 0, only slots j
+    with p - j < w.  Works for both prefill (Sq == Skv, positions 0..S-1)
+    and single-token decode (Sq == 1 against the cache).
     """
     b, sq, h, hd = q.shape
     kh = k.shape[2]
@@ -59,6 +62,9 @@ def attention(
     if kv_length is not None:
         valid = kv_pos < kv_length[:, None, None, None]
         mask = jnp.logical_and(mask, valid)
+    if window is not None:
+        behind = q_positions[:, None, :, None] - kv_pos
+        mask = jnp.logical_and(mask, (window <= 0) | (behind < window))
     logits = jnp.where(mask, logits, NEG_INF)
 
     probs = jnp.exp(logits - logits.max(axis=-1, keepdims=True))

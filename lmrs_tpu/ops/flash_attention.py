@@ -11,6 +11,11 @@ Causal + ragged masking: blocks entirely above the diagonal are skipped
 valid-length (`lengths`, from SMEM) masks padded KV — the kernel equivalent
 of ops.attention's (causal & kv_length) rule.
 
+A sliding window (``window`` > 0: query i sees keys j with 0 <= i - j <
+window) runs a BANDED grid: a query block visits only the kv blocks its
+window reaches (``window_band`` of them, at tiles of ``window_block``), so
+the blocks wholly behind the window cost neither a grid step nor a DMA.
+
 Correctness contract: must match ops.attention.attention() to f32 tolerance —
 see tests/test_kernels.py.  Interpret mode is explicit (``interpret=True``,
 the CPU test path); there is no automatic fallback off-TPU — without it
@@ -41,6 +46,21 @@ NEG_INF = -1e30
 _DEFAULT_BLOCK = env_int("LMRS_FLASH_BLOCK", 1024, lo=128)
 
 
+def window_block(window: int, block: int = _DEFAULT_BLOCK) -> int:
+    """The q and kv tile of a windowed call: twice the window rounded up to
+    a power of two (a band of two tiles then holds a query tile's whole
+    window, and at most half of what it multiplies lies outside it), at
+    least 128 and at most the full kernel's tile."""
+    return min(block, max(128, 2 << max(window - 1, 0).bit_length()))
+
+
+def window_band(window: int, block: int) -> int:
+    """kv tiles of ``block`` that a query tile of ``block`` reaches under
+    ``window``: its own and those behind it that hold a position within
+    ``window - 1`` of its first row."""
+    return -(-(window - 1) // block) + 1
+
+
 def _flash_kernel(
     lengths_ref,  # SMEM [B] valid kv length per batch row (unblocked)
     q_ref,        # VMEM [1, 1, QB, hd]
@@ -53,6 +73,8 @@ def _flash_kernel(
     sm_scale: float,
     skip_padded_q: bool,
     has_segs: bool = False,
+    window: int = 0,  # > 0: the banded grid (module docstring)
+    band: int = 0,    # kv tiles the band holds (clamped to the sequence's)
 ):
     if has_segs:
         # packed-prompt prefill: per-token segment ids; a key is visible to
@@ -73,7 +95,10 @@ def _flash_kernel(
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q_start = qi * q_block
-    k_start = ki * kv_block
+    # banded: step ki of query tile qi is kv tile qi - (band - 1) + ki
+    # (tiles are square); a tile before the sequence's first is off
+    kb = qi - (band - 1) + ki if window else ki
+    k_start = kb * kv_block
     length = lengths_ref[pl.program_id(0)]
 
     # A (q, kv) block pair is live iff some VALID query row can see it:
@@ -87,6 +112,8 @@ def _flash_kernel(
     live = jnp.logical_and(k_start <= q_start + q_block - 1, k_start < length)
     if skip_padded_q:
         live = jnp.logical_and(live, q_start < length)
+    if window:
+        live = jnp.logical_and(live, kb >= 0)
 
     @pl.when(live)
     def _compute():
@@ -99,6 +126,8 @@ def _flash_kernel(
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (q_block, kv_block), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (q_block, kv_block), 1)
         mask = jnp.logical_and(k_pos <= q_pos, k_pos < length)
+        if window:
+            mask = jnp.logical_and(mask, q_pos - k_pos < window)
         if has_segs:
             mask = jnp.logical_and(mask, sq_ref[0][:, None] == sk_ref[0][None, :])
         s = jnp.where(mask, s, NEG_INF)
@@ -129,7 +158,7 @@ def _flash_kernel(
 
 @functools.partial(
     jax.jit, static_argnames=("q_block", "kv_block", "interpret",
-                              "skip_padded_q", "sm_scale")
+                              "skip_padded_q", "sm_scale", "window")
 )
 def flash_attention(
     q: jnp.ndarray,          # [B, Sq, H, hd]
@@ -142,6 +171,7 @@ def flash_attention(
     skip_padded_q: bool = True,
     segment_ids: jnp.ndarray | None = None,  # [B, S] packed-prompt segments
     sm_scale: float | None = None,  # softmax scale; None: hd ** -0.5
+    window: int = 0,  # > 0: query i sees keys j with 0 <= i - j < window
 ) -> jnp.ndarray:
     """Causal flash attention over fresh (position-0-based) sequences.
 
@@ -156,6 +186,9 @@ def flash_attention(
     concatenated into one row): attention is additionally masked to
     same-segment pairs, so causal masking on the global row index becomes
     per-segment causality (segments are contiguous).
+
+    ``window`` > 0 runs the banded grid at square tiles of
+    ``window_block`` (module docstring); not with ``segment_ids``.
     """
     b, sq, h, hd = q.shape
     skv, kh, hd_v = k.shape[1], k.shape[2], v.shape[3]
@@ -164,6 +197,9 @@ def flash_attention(
     if lengths is None:
         lengths = jnp.full((b,), sq, jnp.int32)
 
+    if window:
+        assert segment_ids is None, "no windowed packed prefill"
+        q_block = kv_block = window_block(window, min(q_block, kv_block))
     q_block = min(q_block, sq)
     kv_block = min(kv_block, skv)
     pad_q = (-sq) % q_block
@@ -185,12 +221,19 @@ def flash_attention(
     vt = v.transpose(0, 2, 1, 3)
 
     has_segs = segment_ids is not None
-    grid = (b, h, sq_p // q_block, skv_p // kv_block)
+    n_kv = skv_p // kv_block
+    band = min(window_band(window, kv_block), n_kv) if window else 0
+    grid = (b, h, sq_p // q_block, band or n_kv)
     kernel = functools.partial(
         _flash_kernel, q_block=q_block, kv_block=kv_block,
         sm_scale=hd ** -0.5 if sm_scale is None else sm_scale,
         skip_padded_q=skip_padded_q, has_segs=has_segs,
+        window=window, band=band,
     )
+
+    def kv_tile(qi, ki):  # the kv tile of grid step (qi, ki)
+        return jnp.maximum(qi - (band - 1) + ki, 0) if window else ki
+
     in_specs = [
         # whole [B] array in SMEM (rank-1 blocking is restricted on real
         # TPU lowering); the kernel indexes it by program_id(0)
@@ -198,9 +241,11 @@ def flash_attention(
         pl.BlockSpec((1, 1, q_block, hd),
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, kv_block, hd),
-                     lambda bi, hi, qi, ki: (bi, hi // n_rep, ki, 0)),
+                     lambda bi, hi, qi, ki: (bi, hi // n_rep,
+                                             kv_tile(qi, ki), 0)),
         pl.BlockSpec((1, 1, kv_block, hd_v),
-                     lambda bi, hi, qi, ki: (bi, hi // n_rep, ki, 0)),
+                     lambda bi, hi, qi, ki: (bi, hi // n_rep,
+                                             kv_tile(qi, ki), 0)),
     ]
     operands = [lengths.astype(jnp.int32), qt, kt, vt]
     if has_segs:

@@ -196,6 +196,8 @@ def paged_decode_xla(
     page_tables: jnp.ndarray,  # [B, W] page ids (live window)
     kv_lens: jnp.ndarray,      # [B] tokens in cache (incl. current)
     kv_scales=None,            # (k_scale, v_scale) [B, K, hd] for int8 pools
+    window=None,               # int32 scalar (may be traced): the newest
+                               # ``window`` positions are seen; 0/None: all
 ) -> jnp.ndarray:
     b, h, hd = q.shape
     _, kh, ps, _ = k_pages.shape
@@ -215,6 +217,10 @@ def paged_decode_xla(
     logits = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * hd**-0.5
     pos = jnp.arange(w * ps)[None, None, :]
     mask = pos < kv_lens[:, None, None]
+    if window is not None:
+        # a window layer's table is a ring (engine/kv_cache.py): what lies
+        # behind the window may be a later page's rows, and is masked
+        mask &= (window <= 0) | (pos >= (kv_lens - window)[:, None, None])
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhk,bkhd->bhd", probs.astype(v.dtype), v)
@@ -378,6 +384,8 @@ def _ragged_decode_all_heads(
     length=None,        # override for kv_lens_ref[row]: the span kernel
                         # walks each query TILE with a running prefix length
                         # (base + tiles-so-far * QT), not the row's total
+    lo_ref=None,        # SMEM [B]: positions before lo_ref[row] are masked
+                        # (a windowed walk: ``window_walk``)
 ):
     """Walk ONE batch row's live pages through a double-buffered DMA
     pipeline: each loop step DMAs one page's ALL kv heads as a single
@@ -462,6 +470,8 @@ def _ragged_decode_all_heads(
                 # RMW): a query past the cap sees the real prefix only
                 limit = jnp.minimum(limit, max_pos)
         masked = pos < limit
+        if lo_ref is not None:
+            masked = jnp.logical_and(masked, pos >= lo_ref[b])
 
         _fold_page(qs, k_scr.at[slot], v_scr.at[slot], masked, sm_scale,
                    acc_scr, m_scr, l_scr)
@@ -688,7 +698,8 @@ def _write_new_tokens_all_heads(
 
 def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
                        t_pad: int, n_rep_p: int, max_pos: int | None,
-                       wh: int, quantized: bool, sm_scale: float):
+                       wh: int, quantized: bool, sm_scale: float,
+                       windowed: bool = False):
     """Row-GROUP decode kernel body (the multi-row page walk): one program
     walks ``g`` consecutive batch rows' live pages through a single shared
     double-buffered DMA pipeline instead of one program per row.  The
@@ -719,9 +730,15 @@ def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
     (same constraint as the per-row fused kernel).  The batch must be
     padded to a multiple of ``g``; padded rows carry length 0 (zero
     output, null-page RMW — the masked-row convention throughout).
+    ``windowed``: a third scalar-prefetch operand, each row's first visible
+    position (``window_walk``).
     """
 
-    def kernel(pt_ref, len_ref, q_ref, knew_ref, vnew_ref, *rest):
+    def kernel(pt_ref, len_ref, *refs):
+        lo_ref = None
+        if windowed:
+            lo_ref, *refs = refs
+        q_ref, knew_ref, vnew_ref, *rest = refs
         if quantized:
             (ksc_ref, vsc_ref, k_hbm, v_hbm, o_ref, k_out, v_out, k_scr,
              v_scr, acc_scr, m_scr, l_scr, k8_scr, v8_scr, sem, wsem) = rest
@@ -789,10 +806,28 @@ def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
                 page_size=ps, sm_scale=sm_scale, kh=kh,
                 n_rep_p=n_rep_p, n_tokens=n_tokens, max_pos=max_pos,
                 row=row, external_prime=True, after_walk=after_walk,
-                get_kscale=gks, get_vscale=gvs,
+                get_kscale=gks, get_vscale=gvs, lo_ref=lo_ref,
             )
 
     return kernel
+
+
+def window_walk(page_tables, kv_lens, window, page_size: int):
+    """A decode row's walk under a window of ``window`` positions (int32
+    scalar, may be traced; <= 0: no window): (tables, lengths, lo) in the
+    walk's own coordinates.  The walk starts at the page that holds the
+    first visible position ``len - window``: the table is shifted so that
+    page is column 0, the length counts from that page's first row, and
+    ``lo`` is the first visible position in it (rows before it are
+    masked).  The write of the newest token and the causal limit follow the
+    shifted length, so the kernel needs nothing but ``lo``."""
+    w = jnp.asarray(window, jnp.int32)
+    first = jnp.where(w > 0, jnp.maximum(kv_lens - w, 0), 0)
+    start = first // page_size
+    width = page_tables.shape[1]
+    cols = jnp.minimum(start[:, None] + jnp.arange(width)[None, :], width - 1)
+    return (jnp.take_along_axis(page_tables, cols, axis=1),
+            kv_lens - start * page_size, first - start * page_size)
 
 
 def _pad_rows(x, bp: int, fill=0):
@@ -1754,6 +1789,10 @@ def paged_decode_pallas_fused(
     vscale: jnp.ndarray | None = None,  # per-(slot, head, channel) scales
     row_group: int = 1,        # rows per program (multi-row page walk);
                                # 1 = the per-row grid
+    window=None,               # int32 scalar (may be traced): the walk
+                               # starts at the page of position len -
+                               # window and masks what lies before it; 0:
+                               # the whole sequence.  None: no such operand
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Write-fused ragged decode: scatter the current token's K/V into the
     page pool (in place — the pools are input/output aliased) and attend the
@@ -1783,6 +1822,12 @@ def paged_decode_pallas_fused(
     quantized = kscale is not None
     assert quantized == (k_pages.dtype == jnp.int8), (
         "int8 pools need scales and vice versa")
+    windowed = window is not None
+    prefetch = [page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32)]
+    if windowed:
+        prefetch = [a.astype(jnp.int32) for a in window_walk(
+            prefetch[0], prefetch[1], window, ps)]
+    n_pre = len(prefetch)
     wh = 32 if quantized else 8
     n_rep = h // kh
     n_rep_p = -(-n_rep // 8) * 8
@@ -1811,8 +1856,7 @@ def paged_decode_pallas_fused(
         bp = -(-b // g) * g
         qg = _pad_rows(qg, bp)
         knew, vnew = _pad_rows(knew, bp), _pad_rows(vnew, bp)
-        page_tables = _pad_rows(page_tables, bp)
-        kv_lens = _pad_rows(kv_lens, bp)
+        prefetch = [_pad_rows(a, bp) for a in prefetch]
         scale_specs = []
         if quantized:
             # ones, not zeros: a padded row's null-page RMW still divides
@@ -1824,7 +1868,7 @@ def paged_decode_pallas_fused(
                 pl.BlockSpec((bp, kh, hd), lambda gi, *_: (0, 0, 0)),
             ]
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=n_pre,
             grid=(bp // g,),
             in_specs=[
                 pl.BlockSpec((g, kh, n_rep_p, hd),
@@ -1855,11 +1899,12 @@ def paged_decode_pallas_fused(
         )
         kernel = _make_group_kernel(
             g=g, ps=ps, kh=kh, hd=hd, n_tokens=1, t_pad=8, n_rep_p=0,
-            max_pos=None, wh=wh, quantized=quantized, sm_scale=hd**-0.5)
+            max_pos=None, wh=wh, quantized=quantized, sm_scale=hd**-0.5,
+            windowed=windowed)
         operands = [qg, knew, vnew]
         if quantized:
             operands += [kscale, vscale]
-        pool_at = 2 + len(operands)
+        pool_at = n_pre + len(operands)
         out, k_pages, v_pages = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
@@ -1870,8 +1915,7 @@ def paged_decode_pallas_fused(
             ],
             input_output_aliases={pool_at: 1, pool_at + 1: 2},
             interpret=interpret,
-        )(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-          *operands, k_pages, v_pages)
+        )(*prefetch, *operands, k_pages, v_pages)
         return out[:b, :, :n_rep].reshape(b, h, hd), k_pages, v_pages
 
     scale_specs = []
@@ -1884,7 +1928,7 @@ def paged_decode_pallas_fused(
             pl.BlockSpec((b, kh, hd), lambda bi, *_: (0, 0, 0)),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=n_pre,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, kh, n_rep_p, hd), lambda bi, *_: (bi, 0, 0, 0)),
@@ -1915,7 +1959,11 @@ def paged_decode_pallas_fused(
         ],
     )
 
-    def kernel(pt_ref, len_ref, q_ref, knew_ref, vnew_ref, *rest):
+    def kernel(pt_ref, len_ref, *refs):
+        lo_ref = None
+        if windowed:
+            lo_ref, *refs = refs
+        q_ref, knew_ref, vnew_ref, *rest = refs
         if quantized:
             (ksc_ref, vsc_ref, k_hbm, v_hbm, o_ref, k_out, v_out, k_scr,
              v_scr, acc_scr, m_scr, l_scr, k8_scr, v8_scr, sem, wsem) = rest
@@ -1978,7 +2026,7 @@ def paged_decode_pallas_fused(
             k_scr, v_scr, acc_scr, m_scr, l_scr, sem,
             page_size=ps, sm_scale=hd**-0.5, kh=kh,
             external_prime=True,
-            get_kscale=gks, get_vscale=gvs,
+            get_kscale=gks, get_vscale=gvs, lo_ref=lo_ref,
         )
 
         @pl.when(nxt < nb)
@@ -1993,7 +2041,7 @@ def paged_decode_pallas_fused(
     operands = [qg, knew, vnew]
     if quantized:
         operands += [kscale.astype(jnp.float32), vscale.astype(jnp.float32)]
-    pool_at = 2 + len(operands)  # k_pages index among ALL args
+    pool_at = n_pre + len(operands)  # k_pages index among ALL args
     out, k_pages, v_pages = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -2006,8 +2054,7 @@ def paged_decode_pallas_fused(
         # page write happens in the caller's buffers, no pool copy
         input_output_aliases={pool_at: 1, pool_at + 1: 2},
         interpret=interpret,
-    )(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      *operands, k_pages, v_pages)
+    )(*prefetch, *operands, k_pages, v_pages)
     return out[:, :, :n_rep].reshape(b, h, hd), k_pages, v_pages
 
 

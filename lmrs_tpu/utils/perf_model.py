@@ -76,6 +76,8 @@ def matmul_params(cfg: ModelConfig) -> int:
         + 2 * d * cfg.n_kv_heads * hd  # wk, wv
         + cfg.n_heads * hd * d        # wo
     )
+    if cfg.n_routed_experts:  # GQA attention over routed layers
+        return _routed_matmul_params(cfg, per_layer)
     if cfg.n_experts:
         # only the activated experts' FFN weights do per-token work
         per_layer += 3 * d * cfg.hidden_dim * cfg.n_experts_per_token
@@ -102,6 +104,13 @@ def _latent_matmul_params(cfg: ModelConfig) -> int:
             + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
             + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
             + h * cfg.v_head_dim * d)
+    return _routed_matmul_params(cfg, attn)
+
+
+def _routed_matmul_params(cfg: ModelConfig, attn: int) -> int:
+    """A model of ``n_dense_layers`` dense layers and then routed ones
+    (models/blocks.py), ``attn`` a layer's attention projections."""
+    d = cfg.dim
     n_routed = cfg.n_routed_layers
     dense = 3 * d * (cfg.dense_hidden_dim or cfg.hidden_dim)
     expert = 3 * d * cfg.hidden_dim
@@ -138,8 +147,12 @@ def prefill_flops(cfg: ModelConfig, n_tokens: int,
     fl = 2.0 * body * n_tokens
     fl += 2.0 * (head_tokens if head_tokens is not None else n_tokens) \
         * d * cfg.vocab_size
-    fl += 2.0 * cfg.cache_layers * (float(n_tokens) ** 2
-                                + 2.0 * kv_start * n_tokens) \
+    # twice the (query, key) pairs a layer: the causal triangle on a full
+    # layer, at most ``window`` keys a query on a window layer
+    pairs2 = float(n_tokens) ** 2 + 2.0 * kv_start * n_tokens
+    n_win = cfg.n_window_layers
+    fl += 2.0 * ((cfg.cache_layers - n_win) * pairs2
+                 + n_win * min(pairs2, 2.0 * cfg.sliding_window * n_tokens)) \
         * _attn_width(cfg) * cfg.n_heads
     return fl
 
@@ -164,7 +177,10 @@ def kv_bytes_per_token(cfg: ModelConfig) -> float:
     if cfg.kv_lora_rank:  # one latent row a layer, as stored
         return (cfg.n_layers * cfg.latent_width
                 * jnp.dtype(cfg.dtype).itemsize)
-    return (2 * cfg.cache_layers * cfg.n_kv_heads * cfg.hd
+    # a window layer's ring holds and reads ``sliding_window`` positions
+    # whatever the context: left out of what a cached token costs
+    return (2 * (cfg.cache_layers - cfg.n_window_layers) * cfg.n_kv_heads
+            * cfg.hd
             * jnp.dtype(cfg.dtype).itemsize)
 
 

@@ -284,7 +284,7 @@ def test_the_shares_add_up_to_the_uncut_layer(fam, model):
     """Four chips hold experts 0-3, 4-7, 8-11, 12-15 of the 16: the routed
     parts that the program computes for the four shares, with the shared
     expert counted once, are the reference's uncut layer (all 16 held)."""
-    from lmrs_tpu.models.latent import _swiglu
+    from lmrs_tpu.models.blocks import swiglu as _swiglu
     from lmrs_tpu.ops.moe import routed_experts
 
     family, m = fam
