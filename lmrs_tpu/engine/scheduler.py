@@ -62,10 +62,79 @@ logger = logging.getLogger("lmrs.scheduler")
 _pow2_bucket = pow2_bucket
 
 
-# NOTE: quarter-step sequence buckets (p*1.25/1.5/1.75 between powers of
-# two) were tried to cut prefill padding for prompts just past a power of
-# two — measured 3x WORSE end-to-end: the extra compile shapes thrash the
-# multi-second XLA compiles at runtime.  Pure pow2 buckets stay.
+# NOTE: sequence buckets.  Quarter-step buckets for EVERY prompt were
+# measured 3x WORSE end-to-end (2026-07, before the benchmark): each new
+# shape was a multi-second XLA compile at runtime.  Now the BODY of a fresh
+# prefill wave (two prompts or more in one power-of-two bucket) runs on a
+# rung of ``prefill_seq_rung``, an eighth of the octave apart above 1,024
+# positions on a 128-token page, and its longer prompts on the power of two
+# (``prefill_wave_plan``): the shapes of a wave then do not follow whether
+# it holds a job's one long chunk, so a window meets the programs its
+# warm-up met.  A LONE prompt (a reduce step) keeps the power-of-two
+# bucket: its length follows the text generated before it, so a rung would
+# be a new shape in the window.  The windowed continuation, ring-sharded
+# prompts and packed bins stay on powers of two.  Compiles: a wave's body
+# takes the rung of its median prompt, so a traffic whose prompts sit in
+# one rung compiles one program a row rung, as the powers of two did.
+
+
+def prefill_seq_rung(n: int, page_size: int, max_len: int) -> int:
+    """Operand positions of a fresh prefill dispatch whose longest prompt
+    holds ``n`` tokens: the smallest multiple of ``P // 16`` that holds it, ``P``
+    its power-of-two bucket (floor 64), so the rungs lie an eighth of the
+    octave ``(P/2, P]`` apart (1,152 ... 1,920, 2,048 in (1,024, 2,048];
+    512 apart in (4,096, 8,192]); the power of two itself where that step
+    is not a whole number of pages (at or below 1,024 on a 128-token page).
+    Every rung is whole pages, so the pool write stays a page at a time
+    (``ops/paged_attention.whole_pages``).  Capped at ``max_len``.  Reads
+    nothing but its three arguments: one rule for every model, cache type
+    and mesh."""
+    p = pow2_bucket(n, 64)
+    step = p // 16
+    if step >= page_size and step % page_size == 0:
+        p = -(-n // step) * step
+    return min(p, max_len)
+
+
+def prefill_wave_plan(lens: list[int], slots: int, page_size: int,
+                      max_len: int) -> list[tuple[int, int, int]]:
+    """The dispatches of one group of fresh prompts that share a
+    power-of-two bucket: ``[(prompts, rows, positions)]``, one or two, over
+    ``lens`` sorted ascending (the first ``prompts`` go to the first
+    dispatch).  The wave's BODY runs on the rung of its median prompt
+    (``prefill_seq_rung``); the prompts longer than that rung, if any, are
+    cut off into a second dispatch on their power of two.  So a wave's
+    shapes do not follow whether it holds one long prompt: the body keeps
+    its rows and rung, and one long prompt runs the lone-prompt program
+    that every reduce step of the bucket compiles anyway.  The rung is
+    taken only where it saves at least that lone dispatch
+    (``rows x (pow2 - rung) >= pow2``: 8 rows an eighth of an octave
+    below, 4 rows a quarter), and no plan pays more positions than
+    the group's one power-of-two dispatch (the rule before rungs); else the
+    group takes that dispatch.  A lone prompt keeps its power of two (the
+    NOTE above).  Reads nothing but its arguments."""
+    n = len(lens)
+    top = min(pow2_bucket(lens[-1], 64), max_len)
+    rows = prefill_row_rung(n, slots)
+    body = prefill_seq_rung(lens[(n - 1) // 2], page_size, max_len)
+    if n == 1 or rows * (top - body) < top:
+        return [(n, rows, top)]
+    k = sum(1 for x in lens if x <= body)
+    if k == n:
+        return [(n, rows, body)]
+    plan = [(k, prefill_row_rung(k, slots), body if k > 1 else top),
+            (n - k, prefill_row_rung(n - k, slots), top)]
+    if sum(r * s for _, r, s in plan) > rows * top:
+        return [(n, rows, top)]
+    return plan
+
+
+@jax.jit
+def _put_first_tokens(last_tok, tok0, slot_idx, row_idx):
+    """``last_tok[slot_idx] = tok0[row_idx]`` on the device, entries whose
+    slot is out of range dropped: one program per (prefill rows, decode
+    rows), where eager ops compiled a gather and a scatter per count."""
+    return last_tok.at[slot_idx].set(tok0[row_idx], mode="drop")
 
 
 def prefill_row_rung(group: int, slots: int) -> int:
@@ -794,7 +863,7 @@ class ContinuousScheduler:
             "wedged_requests": int(self._c_wedged.value),
             # sums of the anatomy's dispatch records (obs/anatomy.py):
             # prefill_dispatches / _rows / _row_slots / _query_tokens /
-            # _token_slots,
+            # _token_slots / _page_writes / _seq_rung_dispatches,
             # cold_dispatches / cold_seconds; no keys under LMRS_ANATOMY=0
             **self._an.counters(),
             # the pool's two shares (gauges; a windowed model only)
@@ -3836,13 +3905,24 @@ class ContinuousScheduler:
         Prompts that fit one chunk take the fresh-prefill program (attends
         the chunk directly); longer prompts run the windowed continuation
         program per chunk (attends the page window, which includes earlier
-        chunks' KV).  Chunks with the same (program, bucket) run as ONE
-        batched dispatch whose batch dim is a rung of ``prefill_row_rung``'s
-        short ladder between 1 and B (padded up to the rung): XLA compiles
-        are seconds-long and a per-group-size shape zoo would thrash the
-        cache at runtime, so the ladder doubles and a (program, bucket)
-        compiles at most ``ceil(log2(B / 8)) + 2`` times; a B-row operand for
-        every group would make a two-thirds-full wave pay for the whole.
+        chunks' KV).  A dispatch's batch dim is a rung of
+        ``prefill_row_rung``'s short ladder between 1 and B (padded up to
+        the rung): XLA compiles are seconds-long and a per-group-size shape
+        zoo would thrash the cache at runtime, so the ladder doubles; a
+        B-row operand for every group would make a two-thirds-full wave
+        pay for the whole.
+
+        The fresh prompts of one iteration that share a power-of-two
+        bucket are one WAVE, planned by ``prefill_wave_plan``: its body on
+        the sequence rung of its median prompt (``prefill_seq_rung``, whole
+        pages), the prompts longer than that rung in a second dispatch on
+        the power of two, never more positions than the one power-of-two
+        dispatch of the rule before.  A lone prompt (a reduce step) keeps
+        the power of two: its length follows the generated text, so a rung
+        of it would compile in the window.  The windowed continuation,
+        ring-sharded prompts and packed bins stay on the power-of-two
+        bucket: no cell runs them with fresh prompts, and the latent and
+        window caches refuse two of them.
         """
         groups: dict[tuple, list] = {}
         fresh_pack: list[tuple[int, object, list[int]]] = []
@@ -3860,7 +3940,6 @@ class ContinuousScheduler:
                     and not (self._use_ring and len(chunk) >= self._ring_min)):
                 fresh_pack.append((b, st, chunk))
                 continue
-            s_bucket = min(_pow2_bucket(len(chunk), 64), self.max_len)
             # Ring routing is decided by the REAL chunk length, not the
             # bucket (ADVICE r2): a 600-token prompt bucketing to 1024 must
             # not pay ppermute hops to ring-shard mostly-padding.  Ring
@@ -3868,6 +3947,7 @@ class ContinuousScheduler:
             # guaranteed <= max_len by the constructor divisibility check.
             ring = (fresh and self._use_ring
                     and len(chunk) >= self._ring_min)
+            s_bucket = min(_pow2_bucket(len(chunk), 64), self.max_len)
             if ring:
                 s_bucket = min(-(-s_bucket // self._sp) * self._sp,
                                self.max_len)
@@ -3898,7 +3978,19 @@ class ContinuousScheduler:
                 ).append((b, st, chunk, 0, True))
             else:
                 pending.append(self._dispatch_packed(bin_items))
+        batches = []
         for (fresh, s_bucket, w, ring), items in groups.items():
+            if not fresh or ring:
+                batches.append(((fresh, s_bucket, w, ring), items))
+                continue
+            items.sort(key=lambda it: len(it[2]))
+            lo = 0
+            for m, _, s_seq in prefill_wave_plan(
+                    [len(it[2]) for it in items], self.B,
+                    self.cache.page_size, self.max_len):
+                batches.append(((True, s_seq, w, False), items[lo: lo + m]))
+                lo += m
+        for (fresh, s_bucket, w, ring), items in batches:
             if (not fresh and not self._latent and self._use_ragged
                     and self._kernel_mesh() is None):
                 # windowed continuation chunks ride the unified span
@@ -3935,7 +4027,8 @@ class ContinuousScheduler:
                 srows[row] = b
                 st.prefill_pos = pos + len(chunk)
                 self._c_prefill_tokens.inc(len(chunk))
-            batch_tokens = sum(len(c) for _, _, c, _, _ in items)
+            lens = [len(c) for _, _, c, _, _ in items]
+            batch_tokens = sum(lens)
             self._h_prefill_batch.observe(batch_tokens)
             # roofline attribution: real-token FLOPs of this dispatch
             # (window chunks additionally attend their cached prefix),
@@ -3974,8 +4067,9 @@ class ContinuousScheduler:
                     page_writes=(n * whole_pages(
                         s_bucket, self.cache.page_size, w) if fresh else 0),
                     layer_passes=self.model_cfg.cache_layers, cold=cold,
-                    window=self._window_counts(prefill=(
-                        [len(c) for _, _, c, _, _ in items], s_bucket))):
+                    seq_rung=s_bucket < min(_pow2_bucket(max(lens), 64),
+                                            self.max_len),
+                    window=self._window_counts(prefill=(lens, s_bucket))):
                 fn = (self._get_prefill_fn(s_bucket, use_ring=ring)
                       if fresh
                       else self._get_prefill_window_fn(s_bucket, w))
@@ -4407,15 +4501,19 @@ class ContinuousScheduler:
                 srows = srows[perm]
         lt = jnp.asarray(last_tok)
         for tok0_dev, prows in pending:  # on-device scatter, no host sync
-            idx = np.array([b for b, _ in prows], np.int32)
+            # both index vectors padded to the prefill dispatch's rows (pad
+            # targets bc: dropped), so the program's shape follows the row
+            # rung, not the count of prompts a cut gave the dispatch
+            idx = np.full((tok0_dev.shape[0],), bc, np.int32)
+            src = np.zeros((tok0_dev.shape[0],), np.int32)
+            idx[: len(prows)] = [b for b, _ in prows]
+            src[: len(prows)] = [r for _, r in prows]
             if perm is not None:
                 # pending tok0s target SLOTS; map to their dispatch rows
                 inv = np.empty(len(perm), np.int32)
                 inv[perm] = np.arange(len(perm), dtype=np.int32)
-                idx = inv[idx]
-            idx = jnp.asarray(idx)
-            src = tok0_dev[jnp.asarray(np.array([r for _, r in prows], np.int32))]
-            lt = lt.at[idx].set(src)
+                idx[: len(prows)] = inv[idx[: len(prows)]]
+            lt = _put_first_tokens(lt, tok0_dev, idx, src)
         self._key, sub = jax.random.split(self._key)
         args = (
             self.params, self.cache.k, self.cache.v,

@@ -189,11 +189,16 @@ class DispatchWatchdog:
         self._window_graced = True
 
     def grace_end(self) -> None:
-        """The cold dispatch completed: re-arm the detector NOW.  Without
-        this, a grace opened for a compile in the same loop iteration
-        would also mask a genuine stall at the next loop-top heartbeat
-        site — the compile is done, so the wedge clock must run again."""
+        """The cold dispatch completed: re-arm the detector NOW, its clock
+        restarted.  Without the re-arm, a grace opened for a compile in
+        the same loop iteration would also mask a genuine stall at the
+        next loop-top heartbeat site.  Without the restart, the stall
+        would be counted from the beat BEFORE the compile, so a compile
+        longer than the threshold read as a wedge the moment it landed
+        (PERF.md section 6: a lead for PR 36's failed requests on ouro)."""
         self._grace_until = 0.0
+        if self._last_beat is not None:
+            self._last_beat = time.monotonic()
 
     # --------------------------------------------------- watcher-thread side
 

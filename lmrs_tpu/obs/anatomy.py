@@ -278,9 +278,9 @@ class StepAnatomy:
         self._flat = {"prefill_dispatches": 0, "prefill_rows": 0,
                       "prefill_row_slots": 0, "prefill_query_tokens": 0,
                       "prefill_token_slots": 0, "prefill_page_writes": 0,
-                      "layer_passes": 0, "rpa_wide_tokens": 0,
-                      "rpa_kv_page_reads": 0, "cold_dispatches": 0,
-                      "cold_seconds": 0.0}
+                      "prefill_seq_rung_dispatches": 0, "layer_passes": 0,
+                      "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0,
+                      "cold_dispatches": 0, "cold_seconds": 0.0}
         # routed models only (the scheduler sets ``has_moe`` for one): a
         # dense model's counters and report stay as they were
         self._moe_flat = dict.fromkeys(MOE_FIELDS, 0)
@@ -424,6 +424,7 @@ class StepAnatomy:
                  q_slots: int, ctx_tokens: int, cold: bool,
                  wide_tokens: int = 0, kv_page_reads: int = 0,
                  page_writes: int = 0, layer_passes: int = 0,
+                 seq_rung: bool = False,
                  window: dict | None = None) -> _Dispatch:
         """The ``dispatch`` segment of one device dispatch, with what it
         carried.  ``program`` is one of ``PROGRAMS`` and ``key`` the site's
@@ -440,7 +441,10 @@ class StepAnatomy:
         prefill dispatch says how many pages a layer it writes into the
         pool WHOLE (``page_writes``: ``row_slots`` x bucket / page size for
         a fresh prefill over whole pages, ``ops/paged_attention.
-        whole_pages``; 0 where the program writes a row at a time).  Every
+        whole_pages``; 0 where the program writes a row at a time) and
+        whether its operand's positions lie below the power-of-two bucket
+        of its longest prompt (``seq_rung``, ``scheduler.
+        prefill_seq_rung``; summed into the flat counters only).  Every
         dispatch says how many layer applications it runs
         (``layer_passes``: the model's ``cache_layers``, ``n_layers`` x
         the passes of a looped stack, times the steps of a decode or
@@ -459,6 +463,7 @@ class StepAnatomy:
             "kv_page_reads": int(kv_page_reads),
             "page_writes": int(page_writes),
             "layer_passes": int(layer_passes), "cold": bool(cold),
+            "seq_rung": bool(seq_rung),
             **{f: int(v) for f, v in (window or {}).items()}})
 
     def _fold(self, r: dict) -> None:
@@ -483,6 +488,7 @@ class StepAnatomy:
             flat["prefill_query_tokens"] += r["q_tokens"]
             flat["prefill_token_slots"] += r["q_slots"]
             flat["prefill_page_writes"] += r["page_writes"]
+            flat["prefill_seq_rung_dispatches"] += r["seq_rung"]
         if r["program"] == "rpa":
             flat["rpa_wide_tokens"] += r["wide_tokens"]
             flat["rpa_kv_page_reads"] += r["kv_page_reads"]
@@ -513,11 +519,12 @@ class StepAnatomy:
     def counters(self) -> dict:
         """The flat sums ``ContinuousScheduler.metrics`` carries: prefill
         dispatches, their real query positions, the positions their
-        operands held and the pages they wrote whole (the prompt
-        programs), the span kernel's wide-tile tokens and page fetches
-        (the ``rpa`` program), layer applications, cold dispatches and
-        their wall (all programs); for a routed model, the ``MOE_FIELDS``
-        sums over all programs."""
+        operands held, the pages they wrote whole and the dispatches on a
+        sequence rung below the power of two (the prompt programs), the
+        span kernel's wide-tile tokens and page fetches (the ``rpa``
+        program), layer applications, cold dispatches and their wall (all
+        programs); for a routed model, the ``MOE_FIELDS`` sums over all
+        programs."""
         return {**self._flat, **(self._moe_flat if self.has_moe else {}),
                 **(self._window_flat if self.has_window else {})}
 

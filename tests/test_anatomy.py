@@ -210,7 +210,8 @@ def test_dispatch_record_table_and_flat_counters(an):
         "prefill_dispatches": 1, "prefill_rows": 3, "prefill_row_slots": 4,
         "prefill_query_tokens": 150,
         "prefill_token_slots": 256, "prefill_page_writes": 0,
-        "layer_passes": 0, "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
+        "prefill_seq_rung_dispatches": 0, "layer_passes": 0,
+        "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
         "cold_seconds": 0.0}
     assert an.audit(prefill_tokens=150) == []
     with pytest.raises(ValueError):
@@ -592,17 +593,19 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
             assert (programs["prefill"]["page_writes"] > 0) == (
                 path in ("fresh_prefill", "fresh_prefill_narrow_wave"))
         if path == "fresh_prefill_narrow_wave":
-            # the key carries the rung, and the record pays for the rung:
-            # 3 rows of 8 (not of the 12 slots), 8 x 256 positions, 8 x 256
-            # / 16 pages a layer
+            # the key carries the rungs, and the record pays for them: 3
+            # rows of 8 (not of the 12 slots) of prompts of 147 tokens or
+            # fewer, 8 x 160 positions (not 256), 8 x 160 / 16 pages a layer
             (key, krec), = programs["prefill"]["keys"].items()
-            assert key == "prefill:True:8:256:16:False"
+            assert key == "prefill:True:8:160:16:False"
             assert (krec["dispatches"], krec["rows"], krec["row_slots"]) == (
                 1, 3, 8)
-            assert krec["q_slots"] == 1 * 8 * 256
-            assert krec["page_writes"] == 8 * 256 // 16
+            assert krec["q_slots"] == 1 * 8 * 160
+            assert krec["page_writes"] == 8 * 160 // 16
             assert m1["prefill_row_slots"] - m0["prefill_row_slots"] == 8
             assert m1["prefill_rows"] - m0["prefill_rows"] == 3
+            assert (m1["prefill_seq_rung_dispatches"]
+                    - m0["prefill_seq_rung_dispatches"]) == 1
         assert m1["prefill_tokens"] - m0["prefill_tokens"] == sum(
             r["prompt_tokens"] for r in programs.values())
         # a fresh engine's first sight of each key is its one cold dispatch

@@ -601,7 +601,7 @@ def test_prefill_row_rung_holds_the_group_on_a_short_ladder(slots):
 _BENCH = Path(__file__).resolve().parents[1] / "benchmarks"
 
 
-def _family_model(name: str):
+def _family_model(name: str, max_len: int = 256):
     """(ModelConfig in float32, the family's seeded weights as float32) of a
     benchmark family's tiny rehearsal configuration, built as
     tests/test_latent_moe.py and tests/test_looped.py build theirs."""
@@ -620,7 +620,8 @@ def _family_model(name: str):
     family = families.load(config, name)
     m = family.sizes(config)
     cfg = dataclasses.replace(
-        family.model_config("tiny", m, {"max_seq_len": 256}), dtype="float32")
+        family.model_config("tiny", m, {"max_seq_len": max_len}),
+        dtype="float32")
     params = jax.tree.map(lambda x: x.astype(jnp.float32),
                           family.make_params(m, 11))
     return cfg, params
@@ -641,24 +642,14 @@ _LADDER_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_LADDER_CASES))
-def test_a_narrow_prefill_wave_yields_the_tokens_of_the_full_width_one(
-        monkeypatch, case):
-    """Waves of 9, 16 and 5 prompts on 24 slots run the 16-, 16- and 8-row
-    programs; at temperature 0 every request's token ids are those of the
-    parent rule's 24-row dispatch of the same wave (pad rows were one token
-    on the null page whose logits nobody read), on the fresh program, the
-    windowed continuation, int8 pages, a tp mesh, a latent pool and a looped
-    stack."""
+def _spied_engine(monkeypatch, mc, params, over: dict, slots: int):
+    """(engine, scheduler, served) of a tiny continuous engine whose
+    ``served`` dict maps each finished request id to its token ids."""
     import inspect
 
     from lmrs_tpu.config import MeshConfig
-    from lmrs_tpu.engine import scheduler as sched_mod
 
     monkeypatch.setenv("LMRS_WATCHDOG", "0")
-    make, over, program = _LADDER_CASES[case]
-    mc, params = make()
-    slots = 24
     kw = dict(backend="jax", scheduler="continuous", max_tokens=6,
               max_batch_slots=slots, page_size=16, num_pages=1,
               decode_block=3, prefix_cache=False, host_kv=False,
@@ -679,6 +670,36 @@ def test_a_narrow_prefill_wave_yields_the_tokens_of_the_full_width_one(
         return finish(*a, **k)
 
     monkeypatch.setattr(sched, "_finish_slot", spy)
+    return eng, sched, served
+
+
+def _pow2_plan(lens, slots, page_size, max_len):
+    """The wave rule before PR 37 for a wave within one power of two: one
+    dispatch, the row rung of the wave, the power-of-two bucket."""
+    from lmrs_tpu.engine.scheduler import prefill_row_rung
+    from lmrs_tpu.ops.paged_attention import pow2_bucket
+
+    return [(len(lens), prefill_row_rung(len(lens), slots),
+             min(pow2_bucket(lens[-1], 64), max_len))]
+
+
+@pytest.mark.parametrize("case", sorted(_LADDER_CASES))
+def test_a_narrow_prefill_wave_yields_the_tokens_of_the_full_width_one(
+        monkeypatch, case):
+    """Waves of 9, 16 and 5 prompts on 24 slots run the 16-, 16- and 8-row
+    programs; at temperature 0 every request's token ids are those of the
+    parent rule's 24-row dispatch of the same wave (pad rows were one token
+    on the null page whose logits nobody read), on the fresh program, the
+    windowed continuation, int8 pages, a tp mesh, a latent pool and a looped
+    stack.  The row ladder alone: a wave is one dispatch on its power of
+    two (the cut and the sequence rungs are the next tests')."""
+    from lmrs_tpu.engine import scheduler as sched_mod
+
+    make, over, program = _LADDER_CASES[case]
+    mc, params = make()
+    slots = 24
+    monkeypatch.setattr(sched_mod, "prefill_wave_plan", _pow2_plan)
+    eng, sched, served = _spied_engine(monkeypatch, mc, params, over, slots)
 
     def wave(k: int, start: int) -> list[GenerationRequest]:
         # over half the context each: no two share a packed bin, so every
@@ -723,3 +744,220 @@ def test_a_narrow_prefill_wave_yields_the_tokens_of_the_full_width_one(
     assert narrow["rows"] == full["rows"]
     assert narrow["q_tokens"] == full["q_tokens"]
     assert narrow["q_slots"] < full["q_slots"]
+
+
+# --------------------------------------- the prefill waves' sequence rungs
+
+
+@pytest.mark.parametrize("page_size,max_len,cases", [
+    # a 128-token page: the power of two at or below 1,024 (the step of an
+    # eighth of the octave is under a page), rungs 128 apart in (1,024,
+    # 2,048], 512 apart in (4,096, 8,192], the cap at the window
+    (128, 2048, [(1, 64), (64, 64), (65, 128), (700, 1024), (1024, 1024),
+                 (1025, 1152), (1152, 1152), (1153, 1280), (1719, 1792),
+                 (1792, 1792), (1793, 1920), (1876, 1920), (1920, 1920),
+                 (1921, 2048), (2048, 2048)]),
+    (128, 8192, [(3069, 3072), (4097, 4608), (4322, 4608), (7196, 7680),
+                 (7680, 7680), (7681, 8192), (8192, 8192)]),
+    (128, 1900, [(1793, 1900), (1876, 1900), (1000, 1024)]),
+    # a 32-token page: rungs from 512 up, 32 apart in (256, 512]
+    (32, 2048, [(200, 256), (256, 256), (257, 288), (300, 320), (511, 512),
+                (1719, 1792), (1876, 1920)]),
+])
+def test_prefill_seq_rung_table(page_size, max_len, cases):
+    """The operand positions of a fresh prefill wave: the smallest multiple
+    of an eighth of the octave below the power-of-two bucket that holds the
+    longest prompt, where that step is whole pages; the power of two where
+    it is not; never above the window."""
+    from lmrs_tpu.engine.scheduler import prefill_seq_rung
+
+    for n, want in cases:
+        assert prefill_seq_rung(n, page_size, max_len) == want, n
+    for n in range(1, max_len + 1, 7):  # every rung is whole pages or pow2
+        r = prefill_seq_rung(n, page_size, max_len)
+        assert n <= r <= max_len and (r % page_size == 0 or r == max_len
+                                      or r & (r - 1) == 0), (n, r)
+
+
+# lengths ascending -> the plan: [(prompts, rows, positions)]
+_LONG, _SHORT = 1876, 1750
+
+
+@pytest.mark.parametrize("lens,slots,want", [
+    # 24 slots, a map wave with its job's long last chunk: the body keeps
+    # the 24-row program on its rung, the long chunk runs the lone 2,048
+    # program a reduce step compiles; without it the same body program
+    ([_SHORT] * 23 + [_LONG], 24, [(23, 24, 1792), (1, 1, 2048)]),
+    ([_SHORT] * 24, 24, [(24, 24, 1792)]),
+    # 16 slots: the 16-row body and the lone program
+    ([_SHORT] * 15 + [_LONG], 16, [(15, 16, 1792), (1, 1, 2048)]),
+    # an 8-row wave saves exactly the lone dispatch: taken
+    ([_SHORT] * 7 + [_LONG], 16, [(7, 8, 1792), (1, 1, 2048)]),
+    # nine prompts on 24 slots: eight and one (9 rows, not 16)
+    ([_SHORT] * 8 + [_LONG], 24, [(8, 8, 1792), (1, 1, 2048)]),
+    # 4 slots: four rows an eighth of an octave below save less than a
+    # lone dispatch, so the wave keeps its power of two, long chunk or not
+    ([_SHORT] * 3 + [_LONG], 4, [(4, 4, 2048)]),
+    ([_SHORT] * 4, 4, [(4, 4, 2048)]),
+    ([7296] * 4, 4, [(4, 4, 8192)]),
+    # four rows a quarter of an octave below save the lone dispatch
+    ([1500] * 3 + [1600], 4, [(3, 4, 1536), (1, 1, 2048)]),
+    # the median prompt is long: the body's rung holds every prompt
+    ([_SHORT] * 10 + [_LONG] * 14, 24, [(24, 24, 1920)]),
+    # half the wave long: a cut would pay more than the power of two
+    ([_SHORT] * 12 + [_LONG] * 12, 24, [(24, 24, 2048)]),
+    # two prompts: each alone on the lone program (2 rows, not 8)
+    ([_SHORT, _LONG], 24, [(1, 1, 2048), (1, 1, 2048)]),
+    # at or below 1,024 on a 128-token page the rung is the power of two
+    ([700] * 8, 8, [(8, 8, 1024)]),
+    # a lone prompt keeps its power of two
+    ([_LONG], 24, [(1, 1, 2048)]),
+    ([1308], 4, [(1, 1, 2048)]),
+])
+def test_prefill_wave_plan_puts_the_body_on_its_rung(lens, slots, want):
+    from lmrs_tpu.engine.scheduler import prefill_wave_plan
+
+    assert prefill_wave_plan(lens, slots, 128, 2048 if lens[-1] <= 2048
+                             else 8192) == want
+
+
+@pytest.mark.parametrize("slots", [4, 8, 16, 24])
+def test_prefill_wave_plan_never_pays_more_than_the_power_of_two(slots):
+    """Seeded groups of one power of two (1,025 to 2,048 tokens, a body
+    and a few long prompts): at most two dispatches that carry every
+    prompt in order, each on a rung that holds its longest prompt, and
+    never more positions than the group's one power-of-two dispatch."""
+    import random
+
+    from lmrs_tpu.engine.scheduler import prefill_row_rung, prefill_wave_plan
+
+    rng = random.Random(slots)
+    for _ in range(300):
+        n = rng.randint(1, slots)
+        body = rng.randint(1025, 1990)
+        lens = sorted(min(body + rng.choice([0, 0, 0, rng.randint(0, 300)]),
+                          2048) for _ in range(n))
+        plan = prefill_wave_plan(lens, slots, 128, 2048)
+        assert 1 <= len(plan) <= 2 and sum(m for m, _, _ in plan) == n
+        lo = 0
+        for m, rows, seq in plan:
+            assert rows == prefill_row_rung(m, slots)
+            assert lens[lo + m - 1] <= seq <= 2048 and seq % 128 == 0
+            assert m > 1 or seq == 2048  # a lone prompt: its power of two
+            lo += m
+        assert (sum(r * s for _, r, s in plan)
+                <= prefill_row_rung(n, slots) * 2048), lens
+
+
+# case -> (model and weights, engine overrides): every family whose fresh
+# prefill is its own (dense on int8 pages, latent pool, looped stack on int8
+# pages, window and full layers over a two-kind cache), a 1,024 window on
+# 32-token pages (int8 pages need 32), where rungs lie 64 apart above 512
+def _dense_1k():
+    import dataclasses
+
+    return dataclasses.replace(tiny_model(), max_seq_len=1024), None
+
+
+_RUNG_CASES = {
+    "dense_int8_kv": (_dense_1k, {"kv_quantize": "int8"}),
+    "latent": (lambda: _family_model("tiny-mla-moe-rehearsal.json", 1024),
+               {}),
+    "looped_int8_kv": (lambda: _family_model("tiny-looped-rehearsal.json",
+                                             1024), {"kv_quantize": "int8"}),
+    "windowed": (lambda: _family_model("tiny-swa-moe-rehearsal.json", 1024),
+                 {}),
+}
+
+
+def _map_wave(n_short: int, n_long: int, start: int, reps: int = 5,
+              extra: int = 3):
+    """A map wave: ``n_short`` prompts of one length, then ``n_long``
+    longer ones (a job's long last chunk)."""
+    return [GenerationRequest(
+        prompt=f"speaker {i % 10}: " + "the review covered budgets " * reps
+        + "and hiring " * (extra if i >= n_short else 0),
+        request_id=start + i, temperature=0.0, max_new_tokens=6)
+        for i in range(n_short + n_long)]
+
+
+@pytest.mark.parametrize("case", sorted(_RUNG_CASES))
+def test_a_prefill_wave_on_sequence_rungs_yields_the_tokens_of_the_pow2_one(
+        monkeypatch, case):
+    """Fifteen prompts of about 600 tokens and one of about 700 on 16
+    slots: the body runs [16 x its rung] under the power of two and the
+    long prompt the lone [1 x 1024] program; at temperature 0 every
+    request's token ids are those of the one [16 x 1024] dispatch of the
+    rule before (positions past a row's length are masked and written to
+    the null page)."""
+    from lmrs_tpu.engine import scheduler as sched_mod
+
+    make, over = _RUNG_CASES[case]
+    mc, params = make()
+    eng, sched, served = _spied_engine(monkeypatch, mc, params,
+                                       {"page_size": 32, **over}, 16)
+    ps = sched.cache.page_size
+
+    def run_wave() -> tuple[dict, dict, dict, list[int]]:
+        served.clear()
+        an0, m0 = sched.anatomy_snapshot(), dict(sched.metrics)
+        out = eng.generate_batch(_map_wave(15, 1, 0, reps=22, extra=8))
+        assert all(r.error is None and r.completion_tokens > 0 for r in out)
+        assert sched.audit() == []
+        m1 = sched.metrics
+        return (dict(served), sched.anatomy_report(an0)["programs"]["prefill"],
+                {k: m1[k] - m0[k] for k in ("prefill_token_slots",
+                                            "prefill_seq_rung_dispatches")},
+                sorted(r.prompt_tokens for r in out))
+
+    got, rung, d_rung, lens = run_wave()
+    monkeypatch.setattr(sched_mod, "prefill_wave_plan", _pow2_plan)
+    want, pow2, d_pow2, _ = run_wave()
+    eng.shutdown()
+    assert len(want) == 16 and got == want
+
+    a = sched_mod.prefill_seq_rung(lens[7], ps, mc.max_seq_len)
+    assert lens[14] <= a < lens[15] < 1024 == mc.max_seq_len, (a, lens)
+    keys = {(int(k.split(":")[2]), int(k.split(":")[3])): v["dispatches"]
+            for k, v in rung["keys"].items()}
+    assert keys == {(16, a): 1, (1, 1024): 1}
+    assert set(pow2["keys"]) == {"prefill:True:16:1024:32:False"}
+    assert rung["q_tokens"] == pow2["q_tokens"] == sum(lens)
+    assert d_rung["prefill_token_slots"] == 16 * a + 1024
+    assert d_rung["prefill_token_slots"] < d_pow2["prefill_token_slots"]
+    assert d_rung["prefill_seq_rung_dispatches"] == 1
+    assert d_pow2["prefill_seq_rung_dispatches"] == 0
+    assert rung["page_writes"] == (16 * a + 1024) // ps
+
+
+def test_a_wave_with_a_long_chunk_compiles_nothing_new(monkeypatch, caplog):
+    """After a wave of eight short prompts and a lone long one, a wave of
+    seven short and one long runs the same two programs, the body on
+    [8 x 160] and the long prompt on [1 x 256], and compiles nothing: not
+    a prefill program, and not the first tokens' scatter into the decode
+    block, which is padded to the dispatch's rows so that its shape follows
+    the row rung and not the count of prompts."""
+    import logging
+
+    eng, sched, _ = _spied_engine(monkeypatch, tiny_model(), None, {}, 8)
+
+    def wave(n_short: int, n_long: int, start: int) -> None:
+        out = eng.generate_batch(_map_wave(n_short, n_long, start))
+        assert all(r.error is None and r.completion_tokens > 0 for r in out)
+
+    wave(8, 0, 0)
+    wave(0, 1, 100)
+    keys = set(sched.anatomy_report()["programs"]["prefill"]["keys"])
+    assert keys == {"prefill:True:8:160:16:False",
+                    "prefill:True:1:256:16:False"}
+    m0 = dict(sched.metrics)
+    with caplog.at_level(logging.WARNING), jax.log_compiles(True):
+        caplog.clear()
+        wave(7, 1, 200)
+    compiled = [r.getMessage() for r in caplog.records
+                if "Finished XLA compilation" in r.getMessage()]
+    assert set(sched.anatomy_report()["programs"]["prefill"]["keys"]) == keys
+    assert compiled == []
+    assert sched.metrics["prefill_dispatches"] - m0["prefill_dispatches"] == 2
+    assert sched.audit() == []
+    eng.shutdown()

@@ -98,6 +98,62 @@ def test_watchdog_ema_ignores_graced_windows():
     assert wd.ema_step_s != ema1
 
 
+def test_watchdog_grace_end_restarts_the_stall_clock(monkeypatch):
+    """A graced compile longer than the wedge threshold is no wedge when
+    it lands: grace_end() counts the stall from the compile's end, not from
+    the beat before the compile (PERF.md section 6).  A genuine stall after
+    it is still caught."""
+    from lmrs_tpu.engine.watchdog import DispatchWatchdog
+
+    monkeypatch.setenv("LMRS_WATCHDOG_S", "0.05")
+    wd = DispatchWatchdog()
+    wd.run_started()
+    wd.grace_cold()
+    time.sleep(0.12)  # the compile, longer than the threshold
+    assert wd.stalled_for() == 0.0
+    wd.grace_end()
+    assert wd.stalled_for() < wd.timeout_s()
+    time.sleep(0.12)
+    assert wd.stalled_for() > wd.timeout_s()
+
+
+def test_a_graced_compile_longer_than_the_threshold_is_no_wedge(
+        wd_engine, monkeypatch):
+    """The same through the engine: a cold prefill whose "compile" (1.5 s
+    inside the grace) outlasts a 0.6 s threshold, and whose device sync
+    (0.3 s) keeps the loop from its next beat after the grace closed,
+    finishes its request with no watchdog fire."""
+    monkeypatch.setenv("LMRS_WATCHDOG_S", "0.6")
+    sched = wd_engine._scheduler
+    fires = sched.metrics["watchdog_fires"]
+    grace, ran_ok = sched._wd_grace_cold, sched._note_ran_ok
+    seen = []
+
+    def slow_grace():
+        grace()
+        if not seen:
+            seen.append("compile")
+            time.sleep(1.5)
+
+    def ran_ok_then_sync(key):
+        ran_ok(key)
+        if seen == ["compile"]:
+            seen.append("sync")
+            time.sleep(0.3)
+
+    # every prefill shape reads as new: the next prefill dispatch is cold
+    sched._ran_ok -= {k for k in sched._ran_ok if str(k[0]).startswith(
+        "prefill")}
+    monkeypatch.setattr(sched, "_wd_grace_cold", slow_grace)
+    monkeypatch.setattr(sched, "_note_ran_ok", ran_ok_then_sync)
+    out = wd_engine.generate_batch([_req(70)])
+    assert seen == ["compile", "sync"]
+    assert out[0].error is None
+    assert out[0].finish_reason in ("stop", "length")
+    assert sched.metrics["watchdog_fires"] == fires
+    assert not wd_engine.wedged()
+
+
 def test_watchdog_armed_by_default(wd_engine):
     """LMRS_WATCHDOG defaults on: the runner thread exists, the scheduler
     carries a heartbeat, and a plain batch behaves exactly as before."""
