@@ -54,6 +54,7 @@ from lmrs_tpu.obs.anatomy import WINDOW_FIELDS
 from lmrs_tpu.ops.sampling import sample_logits
 from lmrs_tpu.testing import faults
 from lmrs_tpu.utils.env import env_bool, env_float, env_int, env_str
+from lmrs_tpu.utils.perf_model import StepBytes
 
 logger = logging.getLogger("lmrs.scheduler")
 
@@ -270,6 +271,10 @@ class ContinuousScheduler:
             self.vscale = jnp.ones(sshape, jnp.float32)
         else:
             self.kscale = self.vscale = None
+        # what a decode step reads, from the tree and pool held here: every
+        # decode and spec record's ``hbm_bytes`` and the live attribution
+        self._bytes = StepBytes.held(model_cfg, params, self.cache.k,
+                                     self.cache.v)
         # LMRS_FORCE_KERNELS=interpret: run the Pallas kernels in interpret
         # mode regardless of platform — the CPU-mesh test path for the
         # shard_map-wrapped kernels (tests can't see a real TPU)
@@ -740,8 +745,7 @@ class ContinuousScheduler:
         # bookkeeping: prefill dispatches issued this iteration are
         # sequenced on device before the decode block that fetches their
         # tok0s, so their model FLOPs are attributed to that block's wall.
-        self._perf = DispatchAttribution(model_cfg, engine_cfg,
-                                         self.registry)
+        self._perf = DispatchAttribution(model_cfg, self.registry)
         self._attr_pending_flops = 0.0
         self._attr_prefill_cold = False  # a compiling shape in the wave
         self._attr_last_gb = 0.0  # last block's model bytes (span arg)
@@ -1327,21 +1331,31 @@ class ContinuousScheduler:
 
     def _moe_take(self, program: str, key: tuple, out: tuple) -> tuple:
         """A routed model's programs return the held experts' counts last
-        ([routed layers, experts_held + 2], ops/moe.py): kept on the device
+        ([routed layers, experts_held + 3], ops/moe.py): kept on the device
         until the next fetch brings them back with the tokens."""
         if not self._moe_on:
             return out
         self._moe_pending.append((program, key, out[-1]))
         return out[:-1]
 
-    def _moe_note(self, pending: list, fetched) -> None:
+    def _moe_note(self, pending: list, fetched) -> int:
+        """Fold the fetched counts into their dispatches' records; returns
+        the bytes of the experts the decode blocks among them touched."""
         held = self.model_cfg.experts_held
+        decode_bytes = 0
         for (program, key, _), st in zip(pending, fetched):
             st = np.asarray(st)
+            touched = int(st[:, held + 2].sum())
+            nbytes = (int(touched * self._bytes.expert)
+                      if program in ("decode", "spec") else 0)
             self._an.note_moe(
                 program, key, pairs=int(st[:, :held].sum()),
                 tokens_max=int(st[:, held].sum()),
-                extra_passes=int(st[:, held + 1].sum()), held=held)
+                extra_passes=int(st[:, held + 1].sum()), touched=touched,
+                held=held, hbm_bytes=nbytes)
+            if program == "decode":
+                decode_bytes += nbytes
+        return decode_bytes
 
     def _moe_drain(self) -> None:
         """Counts that no decode fetch picked up (a run whose last
@@ -3788,9 +3802,12 @@ class ContinuousScheduler:
             # decode side of a span step is adv tokens per live row, not
             # one
             extra_flops, cold_pf = self._consume_prefill_attr()
-            nb = self._perf.note_mixed_step(
-                t_disp, t_done, len(rows), live_tokens, flops + extra_flops,
-                warm=warm and not cold_pf, span_tokens=dec_tokens)
+            nb = self._bytes.block(
+                [slots[b].kv_len for b in rows], 1,
+                self.model_cfg.cache_layers, queries=dec_tokens / len(rows))
+            self._perf.note_mixed_step(t_disp, t_done, nb,
+                                       flops + extra_flops,
+                                       warm=warm and not cold_pf)
             self._attr_last_gb = round(nb / 1e9, 3)
             if self._cost.enabled:
                 dcost, pcost = self._roofline_phase_costs(
@@ -4430,10 +4447,13 @@ class ContinuousScheduler:
         fetched together with the block's outputs in the one device_get."""
         w, table = self._decode_window(slots, self.decode_block)
         B = self.B
-        # attribution inputs, taken from the caller's FULL slot arrays
+        # dispatch record inputs, taken from the caller's FULL slot arrays
         # before any compaction/permutation below rewrites them
         attr_live_rows = int(np.sum(active))
         attr_live_tokens = int(np.sum(kv_lens[active]))
+        layer_passes = self.model_cfg.cache_layers * self.decode_block
+        hbm = self._bytes.block(kv_lens[active], self.decode_block,
+                                layer_passes)
         # Compact-batch drain: the decode program's cost scales with its
         # batch dim even for masked rows, so when few slots are live (queue
         # drained, reduce-tree tails) gather the live rows into one fixed
@@ -4532,9 +4552,8 @@ class ContinuousScheduler:
                 "decode", key_, rows=attr_live_rows, row_slots=bc,
                 q_tokens=0, prompt_tokens=0,
                 q_slots=bc * self.decode_block,
-                ctx_tokens=attr_live_tokens,
-                layer_passes=self.model_cfg.cache_layers * self.decode_block,
-                cold=not decode_warm,
+                ctx_tokens=attr_live_tokens, layer_passes=layer_passes,
+                hbm_bytes=hbm, cold=not decode_warm,
                 window=self._window_counts(decode=(
                     kv_lens[active], self.decode_block))) as disp:
             out = self._get_decode_fn(w)(*args)
@@ -4547,7 +4566,7 @@ class ContinuousScheduler:
                 (toks, n_valid, *[t for t, _ in pending],
                  *[m[2] for m in moe]))
         if moe:  # the routed layers' counts rode the same transfer
-            self._moe_note(moe, tok0s[len(pending):])
+            hbm += self._moe_note(moe, tok0s[len(pending):])
             tok0s = tok0s[:len(pending)]
         toks, n_valid = np.asarray(toks), np.asarray(n_valid)
         disp.emitted(int(n_valid.sum()))
@@ -4557,13 +4576,11 @@ class ContinuousScheduler:
             # block's device work (plus any same-iteration prefill
             # sequenced before it — its FLOPs are pending and charged here)
             flops, cold_pf = self._consume_prefill_attr()
-            nb = self._perf.note_block(
-                t_disp, t_done, self.decode_block, attr_live_rows,
-                attr_live_tokens, flops,
-                warm=decode_warm and not cold_pf)
-            self._attr_last_gb = round(nb / 1e9, 3)
+            self._perf.note_block(t_disp, t_done, hbm, flops,
+                                  warm=decode_warm and not cold_pf)
+            self._attr_last_gb = round(hbm / 1e9, 3)
             if self._cost.enabled:
-                dcost, pcost = self._roofline_phase_costs(nb, flops)
+                dcost, pcost = self._roofline_phase_costs(hbm, flops)
                 self._cost_step = (max(0.0, t_done - t_disp), dcost, pcost,
                                    self._consume_prefill_cost())
             self._maybe_profile_slow_step(t_done - t_disp,
@@ -4635,7 +4652,7 @@ class ContinuousScheduler:
             carry = (k_pages, v_pages, last_tok, kv_lens, ~active, key)
             if moe:
                 carry += (jnp.zeros((cfg.n_routed_layers,
-                                     cfg.experts_held + 2), jnp.int32),)
+                                     cfg.experts_held + 3), jnp.int32),)
             carry, (toks, valid) = jax.lax.scan(
                 step, carry, None, length=n_steps)
             k_pages, v_pages = carry[:2]
@@ -4725,6 +4742,9 @@ class ContinuousScheduler:
                 q_slots=self.B * self.decode_steps * (1 + self.spec_k),
                 ctx_tokens=int(np.sum(kv_lens[active])),
                 layer_passes=self.model_cfg.cache_layers * self.decode_steps,
+                hbm_bytes=self._bytes.block(
+                    kv_lens[active], self.decode_steps,
+                    self.model_cfg.cache_layers * self.decode_steps),
                 cold=cold) as disp:
             out = self._get_spec_decode_fn(w)(*args)
         self._note_ran_ok(key_)

@@ -26,7 +26,7 @@ model takes.  What differs from the dense block:
   softmax scale carries YaRN's ``mscale`` squared.
 
 Routed models also hand back, from every paged call, the held experts'
-token counts ``[routed layers, experts_held + 2]`` (ops/moe.py), which the
+token counts ``[routed layers, experts_held + 3]`` (ops/moe.py), which the
 scheduler fetches with the tokens.
 """
 
@@ -226,7 +226,7 @@ def forward_paged(params, cfg: ModelConfig, tokens, positions, pool,
                   interpret: bool = False, last_pos=None, token_valid=None):
     """``transformer.forward_paged`` for the latent cache.  ``pool`` is the
     one latent pool [L*P, 1, ps, latent_width]; returns (logits, pool, None)
-    and, for a routed model, the experts' counts [routed layers, held + 2]
+    and, for a routed model, the experts' counts [routed layers, held + 3]
     as a fourth element.  Fresh prefill (positions from 0), the windowed
     continuation of a chunked prompt, and single-token decode."""
     from lmrs_tpu.models.transformer import _use_flash_prefill

@@ -28,7 +28,7 @@ model takes.  What differs from the dense block:
   experts, one group of dense layers.
 
 Routed models also hand back, from every paged call, the held experts'
-token counts ``[routed layers, experts_held + 2]`` (ops/moe.py).
+token counts ``[routed layers, experts_held + 3]`` (ops/moe.py).
 """
 
 from __future__ import annotations

@@ -24,7 +24,8 @@ shapes".  This module names every microsecond between two dispatches:
   device dispatch AND its record: the site says what the dispatch carried
   (rows and row slots, real and padded query positions, prompt positions,
   context tokens, the span kernel's wide-tile tokens and page fetches,
-  the pages a fresh prefill writes whole, cold or warm) and the record
+  the pages a fresh prefill writes whole, layer applications, the bytes a
+  decode block's steps read, cold or warm) and the record
   folds into a per-program, per-key table that ``snapshot()`` /
   ``report(before=...)`` window like the segment totals.  On a cold key
   the segment's wall is the compile.
@@ -76,12 +77,13 @@ RECORD_FIELDS: tuple[str, ...] = ("dispatches", "rows", "row_slots",
                                   "q_tokens", "prompt_tokens", "q_slots",
                                   "ctx_tokens", "wide_tokens",
                                   "kv_page_reads", "page_writes",
-                                  "layer_passes", "cold")
+                                  "layer_passes", "hbm_bytes", "cold")
 
 # what a routed model's dispatches add to their record once their counts
 # are back (``note_moe``); absent from a dense model's records and report
 MOE_FIELDS: tuple[str, ...] = ("moe_routed_pairs", "moe_expert_tokens_max",
-                               "moe_expert_tokens_mean", "moe_extra_passes")
+                               "moe_expert_tokens_mean", "moe_extra_passes",
+                               "moe_experts_touched")
 
 # what a windowed model's dispatches add to their record (``dispatch``'s
 # ``window``): pages its decode steps walk in full and in window layers,
@@ -279,6 +281,7 @@ class StepAnatomy:
                       "prefill_row_slots": 0, "prefill_query_tokens": 0,
                       "prefill_token_slots": 0, "prefill_page_writes": 0,
                       "prefill_seq_rung_dispatches": 0, "layer_passes": 0,
+                      "decode_hbm_bytes": 0,
                       "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0,
                       "cold_dispatches": 0, "cold_seconds": 0.0}
         # routed models only (the scheduler sets ``has_moe`` for one): a
@@ -424,7 +427,7 @@ class StepAnatomy:
                  q_slots: int, ctx_tokens: int, cold: bool,
                  wide_tokens: int = 0, kv_page_reads: int = 0,
                  page_writes: int = 0, layer_passes: int = 0,
-                 seq_rung: bool = False,
+                 hbm_bytes: int = 0, seq_rung: bool = False,
                  window: dict | None = None) -> _Dispatch:
         """The ``dispatch`` segment of one device dispatch, with what it
         carried.  ``program`` is one of ``PROGRAMS`` and ``key`` the site's
@@ -449,8 +452,12 @@ class StepAnatomy:
         (``layer_passes``: the model's ``cache_layers``, ``n_layers`` x
         the passes of a looped stack, times the steps of a decode or
         speculative block; one step for a prefill or span dispatch).  A
-        windowed model's dispatch adds ``window``: ``WINDOW_FIELDS`` by the
-        host's rule (``ContinuousScheduler._window_counts``)."""
+        decode or speculative block says how many bytes its steps had to
+        read before its routed experts (``hbm_bytes``: utils/perf_model.
+        StepBytes.block; ``note_moe`` adds the experts once their counts
+        are back; 0 on the other programs).  A windowed model's dispatch
+        adds ``window``: ``WINDOW_FIELDS`` by the host's rule
+        (``ContinuousScheduler._window_counts``)."""
         if program not in PROGRAMS:
             raise ValueError(f"unknown dispatch program {program!r} "
                              f"(want one of {PROGRAMS})")
@@ -462,7 +469,8 @@ class StepAnatomy:
             "wide_tokens": int(wide_tokens),
             "kv_page_reads": int(kv_page_reads),
             "page_writes": int(page_writes),
-            "layer_passes": int(layer_passes), "cold": bool(cold),
+            "layer_passes": int(layer_passes),
+            "hbm_bytes": int(hbm_bytes), "cold": bool(cold),
             "seq_rung": bool(seq_rung),
             **{f: int(v) for f, v in (window or {}).items()}})
 
@@ -481,6 +489,8 @@ class StepAnatomy:
         flat = self._flat
         flat["cold_dispatches"] += r["cold"]
         flat["layer_passes"] += r["layer_passes"]
+        if r["program"] == "decode":
+            flat["decode_hbm_bytes"] += r["hbm_bytes"]
         if r["program"] in PROMPT_PROGRAMS:
             flat["prefill_dispatches"] += 1
             flat["prefill_rows"] += r["rows"]
@@ -503,18 +513,24 @@ class StepAnatomy:
             self._c_b_compile.inc(seconds)
 
     def note_moe(self, program: str, key: tuple, *, pairs: int,
-                 tokens_max: int, extra_passes: int, held: int) -> None:
+                 tokens_max: int, extra_passes: int, touched: int,
+                 held: int, hbm_bytes: int = 0) -> None:
         """The routed layers' counts of dispatches on ``(program, key)``,
         known once a fetch has brought them back: token-expert pairs that
         landed on the ``held`` experts here, the busiest held expert's
-        tokens and the mean over the held experts (each summed over the
-        routed layers and, in a decode block, its steps), and grouped-
-        product passes beyond the first."""
+        tokens and the mean over the held experts, grouped-product passes
+        beyond the first, and the held experts that at least one pair
+        chose (each summed over the routed layers and, in a decode block,
+        its steps); ``hbm_bytes`` are the touched experts' bytes, which a
+        decode or speculative block's record adds to its own."""
         rec = self._table[(program, tuple(key))]
         for f, v in zip(MOE_FIELDS, (pairs, tokens_max, pairs / held,
-                                     extra_passes)):
+                                     extra_passes, touched)):
             rec[f] = rec.get(f, 0) + v
             self._moe_flat[f] += v
+        rec["hbm_bytes"] += int(hbm_bytes)
+        if program == "decode":
+            self._flat["decode_hbm_bytes"] += int(hbm_bytes)
 
     def counters(self) -> dict:
         """The flat sums ``ContinuousScheduler.metrics`` carries: prefill
@@ -523,8 +539,9 @@ class StepAnatomy:
         sequence rung below the power of two (the prompt programs), the
         span kernel's wide-tile tokens and page fetches (the ``rpa``
         program), layer applications, cold dispatches and their wall (all
-        programs); for a routed model, the ``MOE_FIELDS`` sums over all
-        programs."""
+        programs), the bytes the decode program's steps had to read
+        (``decode_hbm_bytes``: its records' ``hbm_bytes``); for a routed
+        model, the ``MOE_FIELDS`` sums over all programs."""
         return {**self._flat, **(self._moe_flat if self.has_moe else {}),
                 **(self._window_flat if self.has_window else {})}
 

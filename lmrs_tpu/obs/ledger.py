@@ -424,37 +424,28 @@ class CostLedger:
                 "totals": totals_from_tenants(tenants),
                 "live_requests": live}
 
-    def report(self, before: dict | None = None) -> dict:
-        """The ``cost`` block of ``metrics_report()``.  With ``before`` (a
-        prior ``report()``), the work fields window to the delta."""
+    def report(self) -> dict:
+        """The ``cost`` block of ``metrics_report()``."""
         if not self.enabled:
             return {"enabled": False}
         with self._lock:
             wall = self._wall_seconds
             finished = self._finished
             tenants = len(self._tenants)
-        doc = self.usage_report()
-        tot = doc["totals"]
-        b = (before or {})
-        bt = b.get("totals", {})
-        out = {
+        tot = self.usage_report()["totals"]
+        return {
             "enabled": True,
-            "requests_finished": finished - b.get("requests_finished", 0),
+            "requests_finished": finished,
             "tenants": tenants,
-            "attributed_wall_seconds": round(
-                wall - b.get("attributed_wall_seconds_raw", 0.0), 6),
-            "attributed_wall_seconds_raw": wall,
+            "attributed_wall_seconds": round(wall, 6),
             "totals": {
-                k: (round(tot.get(k, 0) - bt.get(k, 0), 6)
-                    if isinstance(tot.get(k, 0), float)
-                    else tot.get(k, 0) - bt.get(k, 0))
+                k: (round(tot.get(k, 0), 6)
+                    if isinstance(tot.get(k, 0), float) else tot.get(k, 0))
                 for k in ("device_seconds", "prefill_device_seconds",
                           "decode_device_seconds", "goodput_tokens",
                           "wasted_tokens", "queue_wait_seconds",
                           "kv_page_seconds")},
-            "totals_raw": tot,
         }
-        return out
 
     # ---------------------------------------------------------------- audit
 

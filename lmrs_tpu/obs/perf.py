@@ -4,9 +4,10 @@ This module turns the roofline model (utils/perf_model) into a LIVE
 signal on the serving path:
 
 * ``DispatchAttribution`` — owned by the continuous scheduler, fed from
-  the real dispatch loop.  Each decode block knows its model byte cost
-  (weights once per step + live-KV walk) and each prefill dispatch its
-  model FLOP cost; measured dispatch walls (minus the host<->device
+  the real dispatch loop.  Each decode block brings its byte cost (the
+  scheduler's count from the tree and pool it holds, utils/perf_model.
+  StepBytes: the bytes its dispatch record carries) and each prefill
+  dispatch its model FLOP cost; measured dispatch walls (minus the host<->device
   fetch round trip, which a small dispatch's wall is mostly made of) turn
   those into ``lmrs_decode_hbm_util_ratio`` and
   ``lmrs_prefill_mfu_ratio`` samples, plus ``lmrs_step_gap_ms`` — the
@@ -28,8 +29,9 @@ signal on the serving path:
     to each phase's roofline time — no EMA estimate involved
     (``note_mixed_step``);
   - first-run (compiling) shapes never produce samples;
-  - speculative-decode blocks contribute step gaps only (their byte
-    model differs; spec is off on the bench and default-off in serving).
+  - speculative-decode blocks contribute step gaps only (their dispatch
+    record carries their bytes; spec is off on the bench and default-off
+    in serving).
 
 * ``start_profile_capture`` — the ``POST /v1/debug/profile`` /
   ``LMRS_PROFILE_ON_SLOW_STEP`` hook: a bounded, one-at-a-time
@@ -54,12 +56,10 @@ class DispatchAttribution:
     doc).  Registers its metrics on the scheduler's registry so they ride
     the existing ``metrics_report()`` / Prometheus surfaces."""
 
-    def __init__(self, model_cfg, engine_cfg, registry):
+    def __init__(self, model_cfg, registry):
         from lmrs_tpu.obs.metrics import MS_LATENCY_BUCKETS, RATIO_BUCKETS
 
         self.model_cfg = model_cfg
-        self._quantized = bool(getattr(engine_cfg, "quantize", None))
-        self._kv_quantized = bool(getattr(engine_cfg, "kv_quantize", None))
         self._rtt: float | None = None
         self._rtt_t: float | None = None  # clock time of the last probe
         self._clock = time.time  # injectable (stale-RTT regression test)
@@ -168,20 +168,6 @@ class DispatchAttribution:
         return prefill_flops(self.model_cfg, max(1, chunk_tokens),
                              head_tokens=1, kv_start=kv_start)
 
-    def decode_bytes(self, steps: int, n_live: int, live_tokens: int) -> float:
-        """Model HBM bytes of one decode block: every matmul weight once
-        per step (batch-amortized) plus the live-KV walk, whose per-step
-        total grows by one token per live row per step."""
-        from lmrs_tpu.utils.perf_model import (kv_bytes_per_token,
-                                               weight_bytes)
-
-        kv_token_steps = (steps * live_tokens
-                          + n_live * steps * (steps - 1) / 2.0)
-        kv = kv_bytes_per_token(self.model_cfg) * kv_token_steps
-        if self._kv_quantized:
-            kv /= 2
-        return steps * weight_bytes(self.model_cfg, self._quantized) + kv
-
     def note_prefetch(self, nbytes: float) -> None:
         """A KV spill prefetch was issued (async scatter): count its HBM
         bytes and mark the next decode block dirty — its wall includes
@@ -204,17 +190,14 @@ class DispatchAttribution:
             self.g_gap.set(gap_ms)
         self._last_block_end = t_end
 
-    def note_block(self, t_start: float, t_end: float, steps: int,
-                   n_live: int, live_tokens: int, prefill_flops: float,
-                   warm: bool) -> float:
-        """One decode-block dispatch: wall [t_start, t_end], ``n_live``
-        rows at ``live_tokens`` total context, with ``prefill_flops`` of
-        same-iteration prefill work sequenced before it on device (0 for
-        a clean decode block).  ``warm=False`` (a compiling shape) counts
-        work but never samples.  Returns the block's model byte cost (the
-        ``hbm_gb`` trace-span arg)."""
+    def note_block(self, t_start: float, t_end: float, nbytes: float,
+                   prefill_flops: float, warm: bool) -> None:
+        """One decode-block dispatch: wall [t_start, t_end], ``nbytes``
+        its steps had to read (the decode record's ``hbm_bytes``), with
+        ``prefill_flops`` of same-iteration prefill work sequenced before
+        it on device (0 for a clean decode block).  ``warm=False`` (a
+        compiling shape) counts work but never samples."""
         self.note_gap(t_start, t_end)
-        nbytes = self.decode_bytes(steps, n_live, live_tokens)
         self.c_bytes.inc(nbytes)
         if prefill_flops > 0:
             self.c_flops.inc(prefill_flops)
@@ -225,10 +208,10 @@ class DispatchAttribution:
             warm = False
         spec = self._spec() if warm else None
         if spec is None:
-            return nbytes
+            return
         t = (t_end - t_start) - self.ensure_rtt()
         if t <= 1e-6:
-            return nbytes
+            return
         if prefill_flops <= 0:
             util = nbytes / t / spec.peak_hbm_bw
             if 0.0 < util < 4.0:  # garbage guard (clock steps, CPU fallback)
@@ -237,25 +220,23 @@ class DispatchAttribution:
                 self._hbm_util_est = (util if self._hbm_util_est is None
                                       else 0.8 * self._hbm_util_est
                                       + 0.2 * util)
-            return nbytes
+            return
         # mixed block: subtract the decode share estimated from clean
         # samples; the remainder is the prefill compute the device spent
         if self._hbm_util_est is None or self._hbm_util_est <= 0:
-            return nbytes
+            return
         t_decode = nbytes / (spec.peak_hbm_bw * self._hbm_util_est)
         t_prefill = t - t_decode
         if t_prefill <= 1e-6:
-            return nbytes
+            return
         mfu = prefill_flops / t_prefill / spec.peak_flops
         if 0.0 < mfu < 4.0:
             self.h_mfu.observe(mfu)
             self.g_mfu.set(mfu)
-        return nbytes
 
-    def note_mixed_step(self, t_start: float, t_end: float, n_live: int,
-                        live_tokens: int, prefill_flops: float,
-                        warm: bool, span_tokens: int | None = None) -> float:
-        """One FUSED mixed dispatch (SARATHI mixed batches): ``n_live``
+    def note_mixed_step(self, t_start: float, t_end: float, nbytes: float,
+                        prefill_flops: float, warm: bool) -> None:
+        """One FUSED mixed dispatch (SARATHI mixed batches): the live
         decode rows advance one token and a prefill slice of known size
         rides the SAME program.  Unlike the sequenced-prefill decode
         blocks (``note_block``, whose decode share must be ESTIMATED from
@@ -266,28 +247,11 @@ class DispatchAttribution:
         phase samples equal the step's combined roofline utilization —
         the assumption-free number for a step whose two phases share one
         kernel launch (they cannot be timed apart host-side).  Clean
-        decode samples alone keep feeding the EMA.  Returns the step's
-        model byte cost (the ``hbm_gb`` trace-span arg).
-
-        ``span_tokens`` is the SPAN-LEVEL decode token count from a
-        ragged span dispatch: total decode-side query tokens in the step
-        — ``(1 + spec_k) * n_live`` when decode rows carry verify spans.
-        Defaults to ``n_live`` (one token per live row), under which the
-        byte model is unchanged."""
+        decode samples alone keep feeding the EMA.  ``nbytes`` is the
+        step's decode side, priced by the scheduler with the same
+        ``StepBytes`` as a decode block (every query token of a row's
+        span walks that row's positions)."""
         self.note_gap(t_start, t_end)
-        if span_tokens is None or span_tokens <= n_live or n_live <= 0:
-            nbytes = self.decode_bytes(1, n_live, live_tokens)
-        else:
-            # ragged span step: every query token in a row's span walks
-            # that row's KV, so the walk term scales by the mean span
-            # length instead of the legacy one-token-per-row shape
-            from lmrs_tpu.utils.perf_model import (kv_bytes_per_token,
-                                                   weight_bytes)
-            kv = (kv_bytes_per_token(self.model_cfg)
-                  * live_tokens * span_tokens / n_live)
-            if self._kv_quantized:
-                kv /= 2
-            nbytes = weight_bytes(self.model_cfg, self._quantized) + kv
         self.c_bytes.inc(nbytes)
         if prefill_flops > 0:
             self.c_flops.inc(prefill_flops)
@@ -296,10 +260,10 @@ class DispatchAttribution:
             warm = False
         spec = self._spec() if warm else None
         if spec is None:
-            return nbytes
+            return
         t = (t_end - t_start) - self.ensure_rtt()
         if t <= 1e-6:
-            return nbytes
+            return
         t_model = (nbytes / spec.peak_hbm_bw
                    + max(prefill_flops, 0.0) / spec.peak_flops)
         util = t_model / t
@@ -309,7 +273,6 @@ class DispatchAttribution:
             if prefill_flops > 0:
                 self.h_mfu.observe(util)
                 self.g_mfu.set(util)
-        return nbytes
 
     def note_prefill_sync(self, flops: float, t_start: float,
                           t_end: float, warm: bool) -> None:

@@ -131,8 +131,10 @@ def routed_experts(mp, cfg: ModelConfig, x: jnp.ndarray,
                    token_valid: jnp.ndarray | None = None,
                    layer: jnp.ndarray | None = None):
     """The held experts' part of a routed layer.  x [B,S,D] (normed) ->
-    (out [B,S,D], stats int32 [experts_held + 2]: pairs landed on each held
-    expert, the busiest one's, and the passes beyond the first).
+    (out [B,S,D], stats int32 [experts_held + 3]: pairs landed on each held
+    expert, the busiest one's, the passes beyond the first, and the held
+    experts at least one pair chose: the ones whose weights a decode step
+    reads).
 
     ``mp``: router [D, n_routed_experts], bias [n_routed_experts], w_gate /
     w_up [experts_held, D, F], w_down [experts_held, F, D].  ``token_valid``
@@ -207,5 +209,6 @@ def routed_experts(mp, cfg: ModelConfig, x: jnp.ndarray,
             lambda c: c[1] < n_passes,
             lambda c: (one_pass(c[0], c[1]), c[1] + 1), (out, jnp.int32(1)))
     stats = jnp.concatenate([
-        counts, counts.max()[None], jnp.maximum(n_passes - 1, 0)[None]])
+        counts, counts.max()[None], jnp.maximum(n_passes - 1, 0)[None],
+        (counts > 0).sum()[None]])
     return out.reshape(b, s, d), stats.astype(jnp.int32)

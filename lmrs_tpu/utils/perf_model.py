@@ -4,12 +4,16 @@ HBM-bandwidth utilization reporting (obs/perf.py, obs/ledger.py).
 The reference publishes no perf model at all (its compute is a vendor API);
 these counts are the standard decoder-transformer roofline: dense-matmul
 FLOPs dominate prefill (MFU vs the MXU peak), weight+KV bytes dominate
-decode (utilization vs the HBM peak).
+decode (utilization vs the HBM peak).  FLOPs come from the configuration;
+a decode step's bytes from the tree and pool the engine holds
+(``StepBytes``).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
+
+import numpy as np
 
 from lmrs_tpu.config import ModelConfig
 
@@ -157,40 +161,72 @@ def prefill_flops(cfg: ModelConfig, n_tokens: int,
     return fl
 
 
-def weight_bytes(cfg: ModelConfig, quantized: bool = False) -> float:
-    """Bytes of MATMUL weights a decode step streams from HBM (all of
-    them, once a pass of the stack — one read serves the whole batch).
-    The embedding lookup gathers only B rows per step and is excluded
-    (negligible; counting the full table would overstate untied models'
-    bandwidth)."""
-    import jax.numpy as jnp
-
-    itemsize = 1 if quantized else jnp.dtype(cfg.dtype).itemsize
-    return matmul_params(cfg) * itemsize
+# a routed layer's held experts (ops/moe.routed_experts): read once a step
+# for each expert a row chose, not with the layer
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
-def kv_bytes_per_token(cfg: ModelConfig) -> float:
-    """KV-cache bytes per cached token (K + V, all cache layers, one per
-    (pass, layer) of a looped stack, all kv heads)."""
-    import jax.numpy as jnp
+@dataclass(frozen=True)
+class StepBytes:
+    """What a decode step has to read from HBM, counted once at engine start
+    from what the engine holds: the weight tree and the KV pool on the
+    device, not the configuration.  So int8 weights count with their scales,
+    a looped stack's shared layers once a pass, a latent row, a window
+    layer's ring, each as stored.  ``block`` prices a decode dispatch before
+    its experts; ``expert`` is added for each held expert a step touched
+    once the routed layers' counts are back (obs/anatomy.note_moe).  A lower
+    bound: positions rather than pages, the rows live at the block's start,
+    an expert that several rows chose once, and the int8 pages' scales (one
+    per slot, head and channel, not per position) left out.  The embedding
+    table is left out too (a step gathers a row of it) unless the head is
+    tied to it."""
 
-    if cfg.kv_lora_rank:  # one latent row a layer, as stored
-        return (cfg.n_layers * cfg.latent_width
-                * jnp.dtype(cfg.dtype).itemsize)
-    # a window layer's ring holds and reads ``sliding_window`` positions
-    # whatever the context: left out of what a cached token costs
-    return (2 * (cfg.cache_layers - cfg.n_window_layers) * cfg.n_kv_heads
-            * cfg.hd
-            * jnp.dtype(cfg.dtype).itemsize)
+    layer: float        # one layer application outside the routed experts
+    expert: float       # one held expert: its w_gate, w_up and w_down
+    head: int           # the head (the embedding where tied), the final norm
+    position: float     # one position of one cache layer: K and V, or a latent row
+    full_layers: int    # cache layers that attend every position
+    window_layers: int  # cache layers that attend the last ``window`` only
+    window: int
+    max_len: int
 
+    @classmethod
+    def held(cls, cfg: ModelConfig, params, k_pages, v_pages) -> "StepBytes":
+        """From the engine's tree and its pool ``[pages, heads, page, width]``
+        (``v_pages`` None for a latent pool): arrays or their shapes."""
+        from lmrs_tpu.ops.quant import quantized_bytes as _nbytes
 
-def decode_step_bytes(cfg: ModelConfig, total_live_tokens: int,
-                      quantized: bool = False,
-                      kv_quantized: bool = False) -> float:
-    """HBM bytes one batched decode step moves: every weight once (batch
-    amortized — one read serves all rows) + every live KV token's K and V
-    (halved when the pages are int8)."""
-    kv = kv_bytes_per_token(cfg) * total_live_tokens
-    if kv_quantized:
-        kv /= 2
-    return weight_bytes(cfg, quantized) + kv
+        rest = {k: v for k, v in params.items()
+                if k not in ("embed", "lm_head", "final_norm")}
+        head = (_nbytes(params.get("lm_head") or params["embed"])
+                + _nbytes(params.get("final_norm")))
+        experts = 0
+        if cfg.n_routed_experts:
+            moe = params["layers"]["moe"]
+            experts = sum(_nbytes(moe[k]) for k in _EXPERT_LEAVES)
+        positions = k_pages.shape[0] * k_pages.shape[2]
+        return cls(
+            layer=(_nbytes(rest) - experts) / cfg.n_layers,
+            expert=experts / max(cfg.n_routed_layers * cfg.experts_held, 1),
+            head=head,
+            position=(_nbytes(k_pages) + _nbytes(v_pages)) / positions,
+            full_layers=cfg.cache_layers - cfg.n_window_layers,
+            window_layers=cfg.n_window_layers, window=cfg.sliding_window,
+            max_len=cfg.max_seq_len)
+
+    def block(self, lens, steps: int, layer_passes: int,
+              queries: float = 1.0) -> int:
+        """A decode dispatch of ``steps`` steps over live rows at ``lens``
+        positions: its ``layer_passes`` layer applications, the head once a
+        step, and at step t a row's ``len + t + 1`` positions (at most
+        ``max_len``) in each full layer and at most ``window`` of them in
+        each window layer (``scheduler._window_counts``' arithmetic, in
+        positions); ``queries`` a row walks its positions a step (a span
+        step's verify tokens)."""
+        n = np.minimum(np.asarray(lens, np.int64)[:, None]
+                       + np.arange(1, steps + 1)[None, :], self.max_len)
+        pos = int(n.sum()) * self.full_layers
+        if self.window_layers:
+            pos += int(np.minimum(n, self.window).sum()) * self.window_layers
+        return int(layer_passes * self.layer + steps * self.head
+                   + queries * pos * self.position)

@@ -211,6 +211,7 @@ def test_dispatch_record_table_and_flat_counters(an):
         "prefill_query_tokens": 150,
         "prefill_token_slots": 256, "prefill_page_writes": 0,
         "prefill_seq_rung_dispatches": 0, "layer_passes": 0,
+        "decode_hbm_bytes": 0,
         "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
         "cold_seconds": 0.0}
     assert an.audit(prefill_tokens=150) == []
@@ -343,8 +344,7 @@ def test_ensure_rtt_resamples_on_slow_cadence(monkeypatch):
     refreshes the timestamp, so a long-lived process tracks link drift."""
     from lmrs_tpu.obs.perf import DispatchAttribution
 
-    da = DispatchAttribution(tiny_model(), EngineConfig(backend="jax"),
-                             MetricsRegistry())
+    da = DispatchAttribution(tiny_model(), MetricsRegistry())
     clock = FakeClock()
     da._clock = clock
     monkeypatch.setenv("LMRS_RTT_RESAMPLE_S", "100")

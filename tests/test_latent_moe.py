@@ -348,24 +348,56 @@ def _engine(cfg, params, **over):
     return JaxEngine(EngineConfig(**kw), cfg, params=params)
 
 
+@pytest.fixture(scope="module")
+def served(model):
+    """Three requests through JaxEngine / ContinuousScheduler /
+    PagedKVCache, the decode kernel interpreted, blocks of 3 steps; what
+    the live attribution was handed and the routed layers' counts each
+    fetch brought back are kept beside the results."""
+    from lmrs_tpu.engine.api import GenerationRequest
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("LMRS_FORCE_KERNELS", "interpret")
+        mp.setenv("LMRS_WATCHDOG", "0")
+        cfg, params = model
+        eng = _engine(cfg, params)
+        sched = eng._scheduler
+        seen = {"blocks": [], "counts": []}
+        note_block, moe_note = sched._perf.note_block, sched._moe_note
+
+        def on_block(t0, t1, nbytes, flops, warm):
+            rec = sched._an._table
+            seen["blocks"].append((nbytes, sum(
+                r["hbm_bytes"] for (p, _), r in rec.items()
+                if p == "decode")))
+            return note_block(t0, t1, nbytes, flops, warm)
+
+        def on_moe(pending, fetched):
+            seen["counts"] += [(p[0], np.asarray(st))
+                               for p, st in zip(pending, fetched)]
+            return moe_note(pending, fetched)
+
+        mp.setattr(sched._perf, "note_block", on_block)
+        mp.setattr(sched, "_moe_note", on_moe)
+        reqs = [GenerationRequest(prompt="the quarterly review " * (i + 1),
+                                  request_id=i, max_new_tokens=6,
+                                  temperature=0.0)
+                for i in range(3)]
+        res = eng.generate_batch(reqs)
+    yield eng, reqs, res, seen
+    eng.shutdown()
+
+
 def test_greedy_tokens_through_the_scheduler_equal_the_plain_forward(
-        model, monkeypatch):
+        model, served):
     """JaxEngine / ContinuousScheduler / PagedKVCache, the decode kernel
     interpreted: fresh prefill, then decode blocks over the latent pool;
     the tokens are the plain forward's greedy ones, and the held experts'
     counts come back with them."""
-    from lmrs_tpu.engine.api import GenerationRequest
-
-    monkeypatch.setenv("LMRS_FORCE_KERNELS", "interpret")
-    monkeypatch.setenv("LMRS_WATCHDOG", "0")
     cfg, params = model
-    eng = _engine(cfg, params)
+    eng, reqs, res, _ = served
     sched = eng._scheduler
     assert sched._use_ragged and sched.cache.v is None
-    reqs = [GenerationRequest(prompt="the quarterly review " * (i + 1),
-                              request_id=i, max_new_tokens=6, temperature=0.0)
-            for i in range(3)]
-    res = eng.generate_batch(reqs)
     from lmrs_tpu.models import latent
 
     tok = eng.tokenizer
@@ -388,7 +420,66 @@ def test_greedy_tokens_through_the_scheduler_equal_the_plain_forward(
     assert (progs["prefill"]["moe_routed_pairs"]
             + progs["decode"]["moe_routed_pairs"] == met["moe_routed_pairs"])
     assert sched.audit() == []
-    eng.shutdown()
+
+
+def test_a_decode_records_bytes_are_what_the_attribution_is_handed(served):
+    """Block by block, the bytes ``note_block`` prices a decode block by
+    are what its record gained: the steps' layers, head and positions at
+    dispatch and the touched experts at the fetch; their sum is the flat
+    ``decode_hbm_bytes`` and the live ``lmrs_decode_model_bytes_total``."""
+    eng, _, _, seen = served
+    sched = eng._scheduler
+    blocks = seen["blocks"]
+    assert len(blocks) >= 2
+    before = 0
+    for nbytes, in_records in blocks:
+        assert nbytes == in_records - before > 0
+        before = in_records
+    met = sched.metrics
+    assert met["decode_hbm_bytes"] == before
+    assert sched.anatomy_report()["programs"]["decode"]["hbm_bytes"] == before
+    assert sched._perf.c_bytes.value == before
+    sb, held = sched._bytes, sched.model_cfg.experts_held
+    touched = sum(int(st[:, held + 2].sum())
+                  for p, st in seen["counts"] if p == "decode")
+    assert 0 < touched == sched.anatomy_report()["programs"]["decode"][
+        "moe_experts_touched"]
+    assert met["moe_experts_touched"] >= touched
+    # a block of 3 steps touches at least the experts its summed counts
+    # show, and at most 3 steps of them
+    for p, st in seen["counts"]:
+        nonzero = (st[:, :held] > 0).sum(axis=1)
+        steps = 3 if p == "decode" else 1
+        assert (nonzero <= st[:, held + 2]).all()
+        assert (st[:, held + 2] <= steps * nonzero).all()
+    assert before - int(touched * sb.expert) > 0
+
+
+def test_touched_experts_are_the_nonzero_held_columns_step_by_step(model):
+    """The stats' last column is the held experts that at least one pair
+    chose, a step at a time; a decode block's carry adds its steps' stats,
+    so the block's column is the touches of its steps added up."""
+    from lmrs_tpu.ops.moe import routed_experts
+
+    cfg, params = model
+    mp = _routed_layer_params(params)
+    held = cfg.experts_held
+    hs = jax.random.normal(jax.random.PRNGKey(9), (5, 3, 1, cfg.dim))
+    valid = jnp.asarray([[True], [True], [False]])
+
+    def block(hs):
+        def step(acc, h):
+            st = routed_experts(mp, cfg, h, valid)[1]
+            return acc + st, st
+        return jax.lax.scan(step, jnp.zeros((held + 3,), jnp.int32), hs)
+
+    acc, per = jax.jit(block)(hs)
+    per = np.asarray(per)
+    assert per.shape == (5, held + 3)
+    for st in per:
+        assert st[held + 2] == (st[:held] > 0).sum()
+    assert int(acc[held + 2]) == per[:, held + 2].sum() > 0
+    assert per[:, :held].sum() <= 5 * 2 * cfg.n_experts_per_token
 
 
 @pytest.mark.parametrize("over, named", [

@@ -490,11 +490,11 @@ def test_what_is_not_built_is_refused_by_name(fam):
 
 @pytest.mark.parametrize("which", ["tiny", "published"])
 def test_family_counts_and_perf_model_agree(fam, which):
-    """``utils/perf_model.py`` follows ``n_loops`` as the family's counts do
-    (``checks/check_flops.py`` shows the same for the dense family): a token
-    multiplies, and a decode step reads, every layer once a pass and the
-    head once; K and V are held for every (pass, layer); the weight TREE
-    holds each layer once."""
+    """``utils/perf_model.py`` follows ``n_loops`` as the family's counts do:
+    a token multiplies, and a decode step reads (``StepBytes``, counted from
+    the family's tree and a pool), every layer once a pass and the head
+    once; K and V are held for every (pass, layer); the weight TREE holds
+    each layer once."""
     import json
 
     from lmrs_tpu.engine.jax_engine import _bf16_tree_gb
@@ -515,15 +515,21 @@ def test_family_counts_and_perf_model_agree(fam, which):
     for n, head, start in ((1900, 1, 0), (256, None, 0), (128, 1, 1024)):
         assert perf_model.prefill_flops(cfg, n, head, start) == \
             family.prefill_flops(m, n, head, start)
-    kv_item = 1 if m["kv"] == "int8" else 2
-    assert perf_model.kv_bytes_per_token(cfg) * kv_item == \
-        family.kv_bytes_per_token(m) * 2
-    assert perf_model.weight_bytes(cfg) == family.weight_stream_bytes(m)
-    assert perf_model.decode_step_bytes(
-        cfg, 7000, kv_quantized=m["kv"] == "int8") == \
-        family.decode_step_bytes(m, 7000)
-    # what the tree holds: every layer once, the embedding and the head
+    # the engine's byte count, from the family's tree and pool as held: a
+    # position's K and V in every cache layer, the matmul weights every
+    # pass plus the norms (four a layer application, the final one a step)
     shapes = family.param_shapes(m)
+    pool = jax.ShapeDtypeStruct(
+        (cfg.cache_layers * 3, m["n_kv_heads"], 16, cfg.hd),
+        jnp.int8 if m["kv"] == "int8" else jnp.bfloat16)
+    sb = perf_model.StepBytes.held(cfg, shapes, pool, pool)
+    assert sb.position * cfg.cache_layers == family.kv_bytes_per_token(m)
+    norms = (4 * cfg.cache_layers + 1) * m["dim"] * 2
+    step = sb.block([1999], 1, cfg.cache_layers)
+    assert step == family.decode_step_bytes(m, 2000) + norms
+    assert step - 2000 * cfg.cache_layers * sb.position == \
+        family.weight_stream_bytes(m) + norms
+    # what the tree holds: every layer once, the embedding and the head
     projections = sum(
         x.size for path, x in jax.tree_util.tree_leaves_with_path(shapes)
         if x.ndim > 2 or "lm_head" in jax.tree_util.keystr(path)

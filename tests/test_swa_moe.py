@@ -493,7 +493,8 @@ def test_window_counters_of_one_prefill_and_one_decode_dispatch(monkeypatch):
 @pytest.mark.parametrize("preset,extra", [
     ("bench-smoke", ()),
     ("tiny-latent", ("moe_routed_pairs", "moe_expert_tokens_max",
-                     "moe_expert_tokens_mean", "moe_extra_passes")),
+                     "moe_expert_tokens_mean", "moe_extra_passes",
+                     "moe_experts_touched")),
 ])
 def test_models_without_a_window_keep_their_keys_and_record_fields(
         preset, extra):
