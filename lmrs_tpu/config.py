@@ -350,11 +350,12 @@ class EngineConfig:
     decode_block: int = 16  # decode steps per host sync (see scheduler)
     # Multi-row decode page walk (ops/paged_attention.py): each ragged
     # decode program walks `decode_row_group` batch rows' live pages
-    # through one shared double-buffered DMA pipeline, amortizing the
-    # per-program fixed cost that one-row-per-program dispatch pays per
-    # row (~2.8 ms of the 8B decode step; docs/PERF.md r5 intercept
-    # decomposition).  The scheduler length-balances the row→group
-    # assignment per dispatch and clamps to the slot count.
+    # through one shared DMA pipeline (a ring of three page buffers, two
+    # pages in flight while one folds: ops/paged_attention._RING),
+    # amortizing the per-program fixed cost that one-row-per-program
+    # dispatch pays per row (~2.8 ms of the 8B decode step; docs/PERF.md
+    # r5 intercept decomposition).  The scheduler length-balances the
+    # row→group assignment per dispatch and clamps to the slot count.
     # LMRS_MULTIROW=0 is the kill switch (per-row grid, exact previous
     # behavior — same A/B convention as LMRS_PACK_PREFILL);
     # LMRS_DECODE_ROW_GROUP overrides the group size.

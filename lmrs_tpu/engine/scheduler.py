@@ -54,7 +54,7 @@ from lmrs_tpu.obs.anatomy import WINDOW_FIELDS
 from lmrs_tpu.ops.sampling import sample_logits
 from lmrs_tpu.testing import faults
 from lmrs_tpu.utils.env import env_bool, env_float, env_int, env_str
-from lmrs_tpu.utils.perf_model import StepBytes
+from lmrs_tpu.utils.perf_model import StepBytes, walk_pages
 
 logger = logging.getLogger("lmrs.scheduler")
 
@@ -1306,8 +1306,7 @@ class ContinuousScheduler:
             n = (np.asarray(lens, np.int64)[:, None]
                  + np.arange(1, steps + 1)[None, :])
             n = np.minimum(n, self.max_len)
-            full = -(-n // ps)
-            win = (n - 1) // ps - np.maximum(n - w, 0) // ps + 1
+            full, win = walk_pages(n, ps), walk_pages(n, ps, w)
             out["kv_pages_full"] = int(full.sum()) * lay["n_full"]
             out["kv_pages_window"] = int(win.sum()) * lay["n_win"]
         if prefill is not None:
@@ -4553,6 +4552,8 @@ class ContinuousScheduler:
                 q_tokens=0, prompt_tokens=0,
                 q_slots=bc * self.decode_block,
                 ctx_tokens=attr_live_tokens, layer_passes=layer_passes,
+                kv_page_reads=self._bytes.pages(kv_lens[active],
+                                                self.decode_block),
                 hbm_bytes=hbm, cold=not decode_warm,
                 window=self._window_counts(decode=(
                     kv_lens[active], self.decode_block))) as disp:

@@ -281,7 +281,7 @@ class StepAnatomy:
                       "prefill_row_slots": 0, "prefill_query_tokens": 0,
                       "prefill_token_slots": 0, "prefill_page_writes": 0,
                       "prefill_seq_rung_dispatches": 0, "layer_passes": 0,
-                      "decode_hbm_bytes": 0,
+                      "decode_hbm_bytes": 0, "decode_kv_page_reads": 0,
                       "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0,
                       "cold_dispatches": 0, "cold_seconds": 0.0}
         # routed models only (the scheduler sets ``has_moe`` for one): a
@@ -440,7 +440,9 @@ class StepAnatomy:
         runs in the ragged span kernel also says what the kernel will do
         with it (``ops/paged_attention.span_walk_counts``, the kernel's
         rule on the host): ``wide_tokens`` of the query positions fall in
-        wide tiles, and its page walks fetch ``kv_page_reads`` pages.  A
+        wide tiles, and its page walks fetch ``kv_page_reads`` pages; a
+        decode block says the pages its walks fetch over every cache layer
+        and step (``kv_page_reads``: utils/perf_model.StepBytes.pages).  A
         prefill dispatch says how many pages a layer it writes into the
         pool WHOLE (``page_writes``: ``row_slots`` x bucket / page size for
         a fresh prefill over whole pages, ``ops/paged_attention.
@@ -491,6 +493,7 @@ class StepAnatomy:
         flat["layer_passes"] += r["layer_passes"]
         if r["program"] == "decode":
             flat["decode_hbm_bytes"] += r["hbm_bytes"]
+            flat["decode_kv_page_reads"] += r["kv_page_reads"]
         if r["program"] in PROMPT_PROGRAMS:
             flat["prefill_dispatches"] += 1
             flat["prefill_rows"] += r["rows"]
@@ -539,8 +542,10 @@ class StepAnatomy:
         sequence rung below the power of two (the prompt programs), the
         span kernel's wide-tile tokens and page fetches (the ``rpa``
         program), layer applications, cold dispatches and their wall (all
-        programs), the bytes the decode program's steps had to read
-        (``decode_hbm_bytes``: its records' ``hbm_bytes``); for a routed
+        programs), the bytes the decode program's steps had to read and
+        the pages its walks fetch (``decode_hbm_bytes``,
+        ``decode_kv_page_reads``: its records' ``hbm_bytes`` and
+        ``kv_page_reads``); for a routed
         model, the ``MOE_FIELDS`` sums over all programs."""
         return {**self._flat, **(self._moe_flat if self.has_moe else {}),
                 **(self._window_flat if self.has_window else {})}

@@ -25,25 +25,32 @@ Every Pallas kernel here has an XLA twin with the same contract:
 Cache layout: [P_total, K, page_size, hd], PAGE-major: one page's ALL kv
 heads are a single contiguous [K, page_size, hd] DMA, and the kv-head axis
 is folded INTO each program as a statically-unrolled loop over the buffered
-block, so one double-buffered DMA pipeline serves every head of a row.
+block, so one DMA pipeline serves every head of a row.  The pipeline is a
+ring of ``_RING`` page buffers: a step folds page p while the DMAs of pages
+p+1 and p+2 are in flight, so the DMAs keep the HBM's rate through the fold
+(``_RING``'s comment).
 P_total flattens the layer axis into the page axis —
 engine/kv_cache.PagedKVCache — and callers pass GLOBAL page ids.
 
 The BATCH axis folds the same way with ``row_group > 1`` (the multi-row
 page walk): one program walks a GROUP of G rows through the shared pipeline
-— grid=(B/G,) — priming row r+1's first page and running its RMW cycle
+— grid=(B/G,) — priming row r+1's first pages and running its RMW cycle
 inside row r's compute bubbles (``_make_group_kernel``), so launch, scratch
 init and the pipeline's prime are paid per group.  Callers pass a host-side
 length-balanced row order (``balanced_row_order``) so one straggler row
 cannot serialize a whole group.  ``row_group=1`` is the per-row grid, one
 program a row.
 
-What the walk costs on the v5e is the longest chain of latencies one page
-step strings together, not the page's bytes: with float32 operands on both
-sides of its two products and the heads folded one after another a step
-took the same time on bf16 and on int8 pages.  ``_fold_page`` multiplies in
-the pool's stored type, keeps the softmax state lane-replicated and folds a
-page in three passes over the heads (the readings: PERF.md section 6, PR 30).
+What a page step of the walk costs on the v5e is the larger of its DMAs
+and its fold, where each hides under the other.  The fold's cost is the
+longest chain of latencies it strings together: with float32 operands on
+both sides of its two products and the heads folded one after another a
+step took the same time on bf16 and on int8 pages, so ``_fold_page``
+multiplies in the pool's stored type, keeps the softmax state
+lane-replicated and folds a page in three passes over the heads.  The
+DMAs hide the fold only with two pages in flight (``_RING``); a bf16 step
+then costs what its DMAs alone cost, an int8 one what its fold costs (the
+readings: PERF.md section 6).
 """
 
 from __future__ import annotations
@@ -120,15 +127,28 @@ def _n_live_pages(page_tables_ref, kv_lens_ref, row, page_size, length=None):
 
 def _fetch_page(page_tables_ref, k_hbm, v_hbm, k_scr, v_scr, sem,
                 row, p, slot):
-    """Start the K+V page DMAs for (row, page index p) into double-buffer
+    """Start the K+V page DMAs for (row, page index p) into ring buffer
     ``slot`` — ONE [K, ps, hd] copy each brings every kv head's rows of the
     page (the page-major layout's point).  ONE shared implementation: the
-    walk's steady-state prefetches and the fused kernel's cross-row prime
-    must agree on the slot/semaphore layout or the next wait pairs with
-    the wrong DMA."""
+    walk's steady-state prefetches and the cross-row prime must agree on
+    the slot/semaphore layout or the next wait pairs with the wrong DMA."""
     page = page_tables_ref[row, p]
     pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot], sem.at[slot, 0]).start()
     pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot], sem.at[slot, 1]).start()
+
+
+def _prime_walk(page_tables_ref, k_hbm, v_hbm, k_scr, v_scr, sem, row,
+                n_pages):
+    """Start a walk's first ``_RING - 1`` page fetches, page p into slot p
+    (fewer where the row has fewer live pages): what the walk's first steps
+    wait for.  The walk itself primes (``_ragged_decode_all_heads``), or a
+    multi-row kernel does it for the next row once that row's RMW has
+    drained (so even the newest token's page is read fresh)."""
+    for p in range(_RING - 1):
+        @pl.when(n_pages > p)
+        def _(p=p):
+            _fetch_page(page_tables_ref, k_hbm, v_hbm, k_scr, v_scr, sem,
+                        row, p, p)
 
 
 # ------------------------------------------------------------ XLA fallback
@@ -232,6 +252,16 @@ def paged_decode_xla(
 # bf16 parts an f32 left-side operand is split into: 8 significant bits
 # each, so three carry all 24 and the split rounds nothing
 _SPLIT = 3
+
+# page buffers in a decode walk's DMA ring.  Step p waits for page p in slot
+# p % _RING and starts page p + _RING - 1 into the slot page p - 1 left, so
+# _RING - 1 pages are in flight while one folds.  One page DMA streaming
+# alone does not reach the HBM's rate and two do: with two buffers the
+# page that streamed while a page folded streamed alone, and a step cost
+# its DMAs' time plus part of the fold.  Three, not more: the DMAs alone
+# take the same time at any depth, and a fourth buffer reads the same as a
+# third (PERF.md section 6)
+_RING = 3
 
 
 def _lanes(x, n: int):
@@ -356,12 +386,12 @@ def _ragged_decode_all_heads(
     # output
     o_ref,            # VMEM [kh, n_tokens*n_rep_p, hd]
     # scratch
-    k_scr,            # VMEM [2, K, ps, hd] double-buffered whole pages
-    v_scr,            # VMEM [2, K, ps, hd]
+    k_scr,            # VMEM [_RING, K, ps, hd] a ring of whole pages
+    v_scr,            # VMEM [_RING, K, ps, hd]
     acc_scr,          # VMEM [kh, n_tokens*n_rep_p, hd] f32
     m_scr,            # VMEM [kh, n_tokens*n_rep_p, 128] f32
     l_scr,            # VMEM [kh, n_tokens*n_rep_p, 128] f32
-    sem,              # DMA semaphores (2, 2): [buffer parity, k/v]
+    sem,              # DMA semaphores (_RING, 2): [ring slot, k/v]
     *,
     page_size: int,
     sm_scale: float,
@@ -370,11 +400,11 @@ def _ragged_decode_all_heads(
     n_tokens: int = 1,  # queries per row (speculative verify: k+1)
     max_pos: int | None = None,  # static cap: no position >= this is valid
     row=None,           # batch row to walk (default: this program's row)
-    external_prime: bool = False,  # caller already DMA'd page 0 into slot 0
+    external_prime: bool = False,  # caller already primed (_prime_walk)
     after_walk=None,    # hook between the page loop and the output write:
-                        # the multi-row group kernels start the NEXT row's
-                        # first-page fetch here so its DMA overlaps this
-                        # row's epilogue (softmax normalize + output write)
+                        # the multi-row group kernels prime the NEXT row's
+                        # walk here so its DMAs overlap this row's
+                        # epilogue (softmax normalize + output write)
     get_kscale=None,    # (row, ki) -> [hd] f32: int8 pools.  The scales are
     get_vscale=None,    # per-CHANNEL on the contracted axis, so K's dequant
                         # folds into q (one multiply per head, before the
@@ -387,10 +417,11 @@ def _ragged_decode_all_heads(
     lo_ref=None,        # SMEM [B]: positions before lo_ref[row] are masked
                         # (a windowed walk: ``window_walk``)
 ):
-    """Walk ONE batch row's live pages through a double-buffered DMA
-    pipeline: each loop step DMAs one page's ALL kv heads as a single
+    """Walk ONE batch row's live pages through a ring of ``_RING`` page
+    buffers: each loop step DMAs one page's ALL kv heads as a single
     [K, ps, hd] copy (the page-major layout) and unrolls the heads' folds
-    over the buffered block, page p+1 streaming while page p computes.
+    over the buffered block, pages p+1 and p+2 streaming while page p
+    computes (``_RING``'s comment says why two).
     Every head keeps its own online-softmax state (acc/m/l gain a leading
     kh axis, statically indexed; m and l lane-replicated).
 
@@ -417,9 +448,8 @@ def _ragged_decode_all_heads(
         o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
     if not external_prime:
-        @pl.when(n_pages > 0)
-        def _prime():
-            fetch(0, 0)
+        _prime_walk(page_tables_ref, k_hbm, v_hbm, k_scr, v_scr, sem, b,
+                    n_pages)
 
     # int8 dequant is row-count-agnostic: the K scale folds into EVERY q
     # row (all tokens share the slot's per-channel scales — draft tokens
@@ -441,12 +471,15 @@ def _ragged_decode_all_heads(
         qs.append(_left_rows(q, _mxu_dtype(k_scr.dtype)))
 
     def body(p, _):
-        slot = jax.lax.rem(p, 2)
+        slot = jax.lax.rem(p, _RING)
+        ahead = p + _RING - 1
 
-        # overlap: the NEXT page's DMA streams while this one computes
-        @pl.when(p + 1 < n_pages)
+        # the page _RING - 1 ahead starts into the slot page p - 1 left
+        # (folded last step), so pages p+1 .. p+_RING-1 stream while page
+        # p computes
+        @pl.when(ahead < n_pages)
         def _prefetch():
-            fetch(p + 1, jax.lax.rem(p + 1, 2))
+            fetch(ahead, jax.lax.rem(ahead, _RING))
 
         page = page_tables_ref[b, p]
         pltpu.make_async_copy(
@@ -479,9 +512,9 @@ def _ragged_decode_all_heads(
 
     jax.lax.fori_loop(0, n_pages, body, None)
 
-    # safe to issue new DMAs into the double buffers here: every copy the
-    # loop started has been waited, and the last page's compute consumed
-    # its buffer before the loop returned
+    # safe to issue new DMAs into the ring here: every copy the loop
+    # started has been waited, and the last page's compute consumed its
+    # buffer before the loop returned
     if after_walk is not None:
         after_walk()
 
@@ -702,14 +735,15 @@ def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
                        windowed: bool = False):
     """Row-GROUP decode kernel body (the multi-row page walk): one program
     walks ``g`` consecutive batch rows' live pages through a single shared
-    double-buffered DMA pipeline instead of one program per row.  The
-    per-program fixed cost — launch, scratch init, pipeline prime — is
-    paid once per group, and the cross-row software pipeline runs at ROW
-    granularity inside the program: while row r computes, row r+1's RMW
-    windows read/blend/write and its first page prefetches into row r's
-    compute bubbles.  This is the per-row fused kernel's cross-iteration
-    trick moved from grid iterations to unrolled in-program rows, where no
-    program boundary sits between them.  Both dense cells of the benchmark
+    DMA pipeline, the ring of ``_RING`` page buffers, instead of one
+    program per row.  The per-program fixed cost — launch, scratch init,
+    pipeline prime — is paid once per group, and the cross-row software
+    pipeline runs at ROW granularity inside the program: while row r
+    computes, row r+1's RMW windows read/blend/write and its first
+    ``_RING - 1`` pages prefetch into row r's compute bubbles.  This is
+    the per-row fused kernel's cross-iteration trick moved from grid
+    iterations to unrolled in-program rows, where no program boundary
+    sits between them.  Both dense cells of the benchmark
     dispatch it at ``g`` 4 (24 rows, 13-15 pages a live row): there the
     page steps, not the rows' fixed costs, are the kernel's time (PERF.md
     section 6, PR 30).
@@ -719,10 +753,12 @@ def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
     and the walk are already row- and token-count-parametrized.  The
     pipeline invariants are the per-row kernel's, unchanged: rows' pages
     are disjoint (slots own their pages exclusively), exactly one RMW
-    cycle is in flight at a time, and row r+1's first-page prime happens
-    only after r+1's RMW drain.  The LAST row of group ``gi`` hands off to
-    the FIRST row of group ``gi+1`` exactly as consecutive grid iterations
-    used to — the pipeline crosses group boundaries seamlessly.
+    cycle is in flight at a time, and row r+1's prime (its first two
+    pages, ``_prime_walk``) happens only after r+1's RMW drain, so a row
+    whose newest token lies in its first or second page reads it fresh.
+    The LAST row of group ``gi`` hands off to the FIRST row of group
+    ``gi+1`` exactly as consecutive grid iterations used to — the
+    pipeline crosses group boundaries seamlessly.
 
     Expects the caller's operand layout: q/o blocked ``(g, kh, rows, hd)``
     per group; knew/vnew (and int8 scales) as WHOLE-array blocks — row
@@ -761,12 +797,9 @@ def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
         )
 
         def prime_row(row):
-            # same fetch layout as the walk body: its step-0 wait pairs
-            # with fetch(page 0, slot 0)
-            @pl.when(_n_live_pages(pt_ref, len_ref, row, ps) > 0)
-            def _():
-                _fetch_page(pt_ref, k_out, v_out, k_scr, v_scr, sem,
-                            row, 0, 0)
+            # the walk's own prime: its first steps wait for these
+            _prime_walk(pt_ref, k_out, v_out, k_scr, v_scr, sem, row,
+                        _n_live_pages(pt_ref, len_ref, row, ps))
 
         @pl.when(gi == 0)
         def _bootstrap():  # the very first row has no predecessor
@@ -792,7 +825,7 @@ def _make_group_kernel(*, g: int, ps: int, kh: int, hd: int, n_tokens: int,
 
             def after_walk(nxt=nxt, nxt_blend=nxt_blend,
                            nxt_drain=nxt_drain):
-                # row nxt's RMW completes and its first page primes while
+                # row nxt's RMW completes and its walk primes while
                 # row ``row``'s epilogue (normalize + output write) runs
                 @pl.when(nxt < nrows)
                 def _():
@@ -947,14 +980,14 @@ def paged_decode_pallas_multi(
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             scratch_shapes=[
-                pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages
-                pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
+                pltpu.VMEM((_RING, kh, ps, hd), k_pages.dtype),  # page ring
+                pltpu.VMEM((_RING, kh, ps, hd), v_pages.dtype),
                 pltpu.VMEM((kh, rows, hd), jnp.float32),
                 pltpu.VMEM((kh, rows, 128), jnp.float32),
                 pltpu.VMEM((kh, rows, 128), jnp.float32),
                 pltpu.VMEM((n_win, kh, wh, hd), k_pages.dtype),
                 pltpu.VMEM((n_win, kh, wh, hd), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((_RING, 2)),
                 pltpu.SemaphoreType.DMA((n_win, 2)),
             ],
         )
@@ -1005,14 +1038,14 @@ def paged_decode_pallas_multi(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages
-            pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
+            pltpu.VMEM((_RING, kh, ps, hd), k_pages.dtype),  # page ring
+            pltpu.VMEM((_RING, kh, ps, hd), v_pages.dtype),
             pltpu.VMEM((kh, rows, hd), jnp.float32),
             pltpu.VMEM((kh, rows, 128), jnp.float32),
             pltpu.VMEM((kh, rows, 128), jnp.float32),
             pltpu.VMEM((n_win, kh, wh, hd), k_pages.dtype),
             pltpu.VMEM((n_win, kh, wh, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((_RING, 2)),
             pltpu.SemaphoreType.DMA((n_win, 2)),
         ],
     )
@@ -1222,8 +1255,9 @@ def _wide_span_tiles(
     # HBM operands
     q_hbm, kn_hbm, vn_hbm, k_out, v_out, o_hbm,
     # scratch
-    k_scr, v_scr,     # VMEM [2, kh, ps, hd] double-buffered whole pages
-    sem,              # DMA (2, 2): page fetches [buffer parity, k/v]
+    k_scr, v_scr,     # VMEM [_RING, kh, ps, hd]: slots 0 and 1 alone, two
+                      # whole pages (this path keeps one in flight)
+    sem,              # DMA (_RING, 2): page fetches [buffer parity, k/v]
     q_scr,            # VMEM [2, kh, W*n_rep, hd] q tiles (tile parity)
     kn_scr, vn_scr,   # VMEM [2, kh, W, hd] the tiles' new-token K/V
     o_scr,            # VMEM [kh, W*n_rep, hd]
@@ -1610,9 +1644,9 @@ def ragged_spans_pallas(
         operands += [kscale.astype(jnp.float32), vscale.astype(jnp.float32)]
     f32 = jnp.float32
     scratch = [
-        pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages
-        pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
-        pltpu.SemaphoreType.DMA((2, 2)),             # page fetches
+        pltpu.VMEM((_RING, kh, ps, hd), k_pages.dtype),  # page ring
+        pltpu.VMEM((_RING, kh, ps, hd), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((_RING, 2)),         # page fetches
         # narrow tiles
         pltpu.VMEM((kh, tile_rows, hd), q.dtype),    # q tile
         pltpu.VMEM((kh, tile_rows, hd), q.dtype),    # o tile
@@ -1886,14 +1920,14 @@ def paged_decode_pallas_fused(
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             scratch_shapes=[
-                pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages
-                pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
+                pltpu.VMEM((_RING, kh, ps, hd), k_pages.dtype),  # page ring
+                pltpu.VMEM((_RING, kh, ps, hd), v_pages.dtype),
                 pltpu.VMEM((kh, n_rep_p, hd), jnp.float32),
                 pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
                 pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
                 pltpu.VMEM((1, kh, wh, hd), k_pages.dtype),  # one RMW window
                 pltpu.VMEM((1, kh, wh, hd), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((_RING, 2)),
                 pltpu.SemaphoreType.DMA((1, 2)),
             ],
         )
@@ -1947,14 +1981,14 @@ def paged_decode_pallas_fused(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, kh, ps, hd), k_pages.dtype),  # whole pages x2
-            pltpu.VMEM((2, kh, ps, hd), v_pages.dtype),
+            pltpu.VMEM((_RING, kh, ps, hd), k_pages.dtype),  # page ring
+            pltpu.VMEM((_RING, kh, ps, hd), v_pages.dtype),
             pltpu.VMEM((kh, n_rep_p, hd), jnp.float32),
             pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
             pltpu.VMEM((kh, n_rep_p, 128), jnp.float32),
             pltpu.VMEM((1, kh, wh, hd), k_pages.dtype),  # one RMW window
             pltpu.VMEM((1, kh, wh, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((_RING, 2)),
             pltpu.SemaphoreType.DMA((1, 2)),
         ],
     )
@@ -1977,10 +2011,10 @@ def paged_decode_pallas_fused(
         # (slots own their pages exclusively), so iteration b
         #   1. starts row b+1's RMW window READS (tiny DMAs that land
         #      while row b's pages stream),
-        #   2. walks row b (its first page was DMA'd by iteration b-1),
+        #   2. walks row b (its first pages were primed by iteration b-1),
         #   3. blends + writes + drains row b+1's RMW and primes row b+1's
-        #      first page fetch (safe: the RMW just drained, so even a
-        #      1-page row reads fresh K/V).
+        #      walk (``_prime_walk``; safe: the RMW just drained, so even
+        #      a 1- or 2-page row reads fresh K/V).
         # Iteration 0 bootstraps its own RMW + prime inline.  Exactly one
         # RMW cycle is in flight at a time, so the shared scratch/sems are
         # race-free; the n_tokens=1 degenerate of the multi-token writer
@@ -2002,12 +2036,10 @@ def paged_decode_pallas_fused(
         nxt_reads, nxt_blend, nxt_drain = rmw(jnp.minimum(nxt, nb - 1))
 
         def prime_row(row):
-            # same fetch layout as the walk's body: the wait at the next
-            # iteration's step 0 is fetch(page 0, slot 0)
-            @pl.when(_n_live_pages(pt_ref, len_ref, row, ps) > 0)
-            def _():
-                _fetch_page(pt_ref, k_out, v_out, k_scr, v_scr, sem,
-                            row, 0, 0)
+            # the walk's own prime: the next iteration's first steps wait
+            # for these
+            _prime_walk(pt_ref, k_out, v_out, k_scr, v_scr, sem, row,
+                        _n_live_pages(pt_ref, len_ref, row, ps))
 
         @pl.when(bi == 0)
         def _bootstrap():

@@ -166,6 +166,18 @@ def prefill_flops(cfg: ModelConfig, n_tokens: int,
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
+def walk_pages(n, page_size: int, window: int = 0):
+    """The K/V pages a decode walk over ``n`` positions fetches, elementwise
+    (``n`` >= 1): ceil(n / page), or under a window of ``window`` positions
+    the pages from the one that holds position ``n - window`` on
+    (ops/paged_attention.window_walk)."""
+    n = np.asarray(n, np.int64)
+    if window > 0:
+        return ((n - 1) // page_size - np.maximum(n - window, 0) // page_size
+                + 1)
+    return -(-n // page_size)
+
+
 @dataclass(frozen=True)
 class StepBytes:
     """What a decode step has to read from HBM, counted once at engine start
@@ -189,6 +201,7 @@ class StepBytes:
     window_layers: int  # cache layers that attend the last ``window`` only
     window: int
     max_len: int
+    page_size: int      # positions a pool page holds
 
     @classmethod
     def held(cls, cfg: ModelConfig, params, k_pages, v_pages) -> "StepBytes":
@@ -212,7 +225,13 @@ class StepBytes:
             position=(_nbytes(k_pages) + _nbytes(v_pages)) / positions,
             full_layers=cfg.cache_layers - cfg.n_window_layers,
             window_layers=cfg.n_window_layers, window=cfg.sliding_window,
-            max_len=cfg.max_seq_len)
+            max_len=cfg.max_seq_len, page_size=k_pages.shape[2])
+
+    def _positions(self, lens, steps: int) -> np.ndarray:
+        """[rows, steps]: at step t a row attends ``len + t + 1`` positions,
+        at most ``max_len``."""
+        return np.minimum(np.asarray(lens, np.int64)[:, None]
+                          + np.arange(1, steps + 1)[None, :], self.max_len)
 
     def block(self, lens, steps: int, layer_passes: int,
               queries: float = 1.0) -> int:
@@ -223,10 +242,23 @@ class StepBytes:
         each window layer (``scheduler._window_counts``' arithmetic, in
         positions); ``queries`` a row walks its positions a step (a span
         step's verify tokens)."""
-        n = np.minimum(np.asarray(lens, np.int64)[:, None]
-                       + np.arange(1, steps + 1)[None, :], self.max_len)
+        n = self._positions(lens, steps)
         pos = int(n.sum()) * self.full_layers
         if self.window_layers:
             pos += int(np.minimum(n, self.window).sum()) * self.window_layers
         return int(layer_passes * self.layer + steps * self.head
                    + queries * pos * self.position)
+
+    def pages(self, lens, steps: int) -> int:
+        """The K/V pages the walks of a decode dispatch fetch, over every
+        cache layer and step (its record's ``kv_page_reads``): the
+        positions ``block`` prices, a full layer's walk ceil(n / page)
+        pages and a window layer's from the page of position n - window
+        on (``walk_pages``).  Over ``layer_passes`` and the live rows it is
+        the mean walk a page step serves."""
+        n = self._positions(lens, steps)
+        pages = int(walk_pages(n, self.page_size).sum()) * self.full_layers
+        if self.window_layers:
+            pages += int(walk_pages(n, self.page_size, self.window).sum()
+                         ) * self.window_layers
+        return pages

@@ -211,13 +211,54 @@ def test_dispatch_record_table_and_flat_counters(an):
         "prefill_query_tokens": 150,
         "prefill_token_slots": 256, "prefill_page_writes": 0,
         "prefill_seq_rung_dispatches": 0, "layer_passes": 0,
-        "decode_hbm_bytes": 0,
+        "decode_hbm_bytes": 0, "decode_kv_page_reads": 0,
         "rpa_wide_tokens": 0, "rpa_kv_page_reads": 0, "cold_dispatches": 0,
         "cold_seconds": 0.0}
     assert an.audit(prefill_tokens=150) == []
     with pytest.raises(ValueError):
         an.dispatch("warp", ("warp",), rows=0, row_slots=0, q_tokens=0,
                     prompt_tokens=0, q_slots=0, ctx_tokens=0, cold=False)
+
+
+@pytest.mark.parametrize("window", [0, 16], ids=["dense", "windowed"])
+def test_decode_kv_page_reads_are_the_walks_pages(an, window):
+    """A decode record's ``kv_page_reads`` (``StepBytes.pages``) is the
+    pages its walks fetch, counted walk by walk the kernel's way: at step t
+    a row's walk covers ``len + t + 1`` positions, all its pages in a full
+    layer and, in a window layer, the pages ``window_walk`` leaves it
+    (8-position pages; rows below, at and past a page edge and the
+    window).  ``decode_kv_page_reads`` is the decode records' sum."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from lmrs_tpu.ops.paged_attention import window_walk
+    from lmrs_tpu.utils.perf_model import StepBytes
+
+    ps, steps, full, win = 8, 3, 2, 3 if window else 0
+    sb = StepBytes(layer=0.0, expert=0.0, head=0, position=0.0,
+                   full_layers=full, window_layers=win, window=window,
+                   max_len=64, page_size=ps)
+    blocks = [[0, 7, 15, 40], [16, 62]]
+    table = jnp.arange(8)[None, :].repeat(4, 0)
+    want = 0
+    for lens in blocks:
+        for t in range(steps):
+            n = np.minimum(np.asarray(lens) + t + 1, sb.max_len)
+            want += full * int(sum(-(-int(x) // ps) for x in n))
+            if win:
+                _, walk, _ = window_walk(table[: len(n)], jnp.asarray(n),
+                                         jnp.int32(window), ps)
+                want += win * int(sum(-(-int(x) // ps) for x in walk))
+    got = [sb.pages(lens, steps) for lens in blocks]
+    assert sum(got) == want
+    for lens, pages in zip(blocks, got):
+        with an.dispatch("decode", ("decode", 4, 8), rows=len(lens),
+                         row_slots=4, q_tokens=0, prompt_tokens=0,
+                         q_slots=4 * steps, ctx_tokens=sum(lens),
+                         cold=False, kv_page_reads=pages):
+            pass
+    assert an.report()["programs"]["decode"]["kv_page_reads"] == want
+    assert an.counters()["decode_kv_page_reads"] == want
 
 
 def test_span_walk_fields_sum_and_audit(an):
@@ -554,9 +595,10 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
                 name not in PROMPT_PROGRAMS)
             assert rec["cold"] <= rec["dispatches"]
             assert rec["wide_tokens"] <= rec["q_tokens"], (name, rec)
-            # only a span dispatch that runs the span KERNEL walks pages
-            assert (rec["kv_page_reads"] > 0) == (
-                path == "prefix_hit_spans" and name == "rpa"), (name, rec)
+            # a decode block counts its walks' pages whatever serves its
+            # attention; a span dispatch only where the span KERNEL runs
+            assert (rec["kv_page_reads"] > 0) == (name == "decode" or (
+                path == "prefix_hit_spans" and name == "rpa")), (name, rec)
             for f in RECORD_FIELDS:
                 assert rec[f] == sum(k[f] for k in rec["keys"].values())
             # a fresh prefill whose bucket is whole pages writes them whole:
@@ -577,6 +619,8 @@ def test_every_dispatch_path_lands_in_the_programs_table(monkeypatch, path):
         for f in ("wide_tokens", "kv_page_reads"):
             assert m1["rpa_" + f] - m0["rpa_" + f] == programs.get(
                 "rpa", {}).get(f, 0)
+        assert (m1["decode_kv_page_reads"] - m0["decode_kv_page_reads"]
+                == programs.get("decode", {}).get("kv_page_reads", 0))
         prompt = [r for n, r in programs.items() if n in PROMPT_PROGRAMS]
         assert m1["prefill_dispatches"] - m0["prefill_dispatches"] == sum(
             r["dispatches"] for r in prompt)

@@ -108,36 +108,66 @@ def _scatter_new_token(pools, k_new, v_new, tables, kv_lens, rows):
             pools.v.at[page, :, pos % ps].set(v_rows))
 
 
-@pytest.mark.parametrize("pool", PAGED_POOLS)
-def test_fused_decode_matches_scatter_plus_xla(pool):
+def _ring_walks(ps):
+    """Rows whose walks are 5, 1, 3, 0, 2 and 1 pages long (under a window
+    of one page, 2, 1, 1, 0, 2 and 1: a window layer's ring of two pages):
+    the walk's DMA ring primes two pages, or one where a row has one, and
+    in a group the row after the 5-page walk has ONE page (the two-page
+    prime's edge); row 4's newest token lies in its second page, which the
+    prime fetches, so the prime must follow that row's write.  Returns
+    (tables of 5 distinct pages a row, lengths)."""
+    lens = np.asarray([4 * ps + 3, 1, 3 * ps, 0, ps + 1, 2], np.int32)
+    ids = 1 + np.arange(len(lens) * 5).reshape(len(lens), 5)
+    tables = np.where(lens[:, None] > 0, ids, 0).astype(np.int32)
+    return jnp.asarray(tables), jnp.asarray(lens)
+
+
+_WALKS = [pytest.param(p, "edge", id=p) for p in PAGED_POOLS] + [
+    pytest.param(p, "ring", id=f"{p}-ring") for p in PAGED_POOLS] + [
+    pytest.param("bf16", "window", id="bf16-window")]
+
+
+@pytest.mark.parametrize("pool,walks", _WALKS)
+def test_fused_decode_matches_scatter_plus_xla(pool, walks):
     """The write-fused ragged decode kernel (interpret mode) must produce
     the same attention output AND the same pool contents as the XLA
     scatter + gather fallback, whatever the pool's stored type: the walk
     multiplies bf16 and int8 pages in bf16 with its float32 operands split
     into stacked bf16 rows, and owes the float32 reference the tolerance a
-    float32 pool owes it."""
+    float32 pool owes it.  The ``ring`` cases walk 0 to 5 pages a row
+    through the DMA ring (``_ring_walks``), the ``window`` case a window
+    of one page, and both check the two-row group kernel bit for bit
+    against the per-row grid."""
     from lmrs_tpu.ops.paged_attention import (
         paged_decode_pallas_fused,
         paged_decode_xla,
     )
 
-    q, k_new, v_new, pools, ps = _decode_case(pool, 0)
-    # row 0: pos ps+12 = page 1, offset 12 (an 8-row window starts at 8);
-    # row 1: 5 tokens (window start 0); row 2: inactive, on the null page;
-    # row 3: its last page holds one token
-    tables = jnp.asarray([[3, 5, 7], [9, 0, 0], [0, 0, 0], [2, 4, 6]],
-                         jnp.int32)
-    kv_lens = jnp.asarray([ps + 13, 5, 0, 2 * ps + 1], jnp.int32)
+    q, k_new, v_new, pools, ps = _decode_case(
+        pool, 0, b=4 if walks == "edge" else 6, n_pages=12 if walks == "edge"
+        else 31)
+    wkw = {}
+    if walks == "edge":
+        # row 0: pos ps+12 = page 1, offset 12 (an 8-row window starts at
+        # 8); row 1: 5 tokens (window start 0); row 2: inactive, on the null
+        # page; row 3: its last page holds one token
+        tables = jnp.asarray([[3, 5, 7], [9, 0, 0], [0, 0, 0], [2, 4, 6]],
+                             jnp.int32)
+        kv_lens = jnp.asarray([ps + 13, 5, 0, 2 * ps + 1], jnp.int32)
+    else:
+        tables, kv_lens = _ring_walks(ps)
+        if walks == "window":
+            wkw = dict(window=jnp.int32(ps))
     act = np.asarray(kv_lens) > 0
 
     k_ref, v_ref = _scatter_new_token(pools, k_new, v_new, tables, kv_lens,
                                       np.flatnonzero(act))
     want = paged_decode_xla(q, pools.ref(k_ref), pools.ref(v_ref), tables,
-                            kv_lens, **pools.xkw)
+                            kv_lens, **pools.xkw, **wkw)
 
     got, k_out, v_out = paged_decode_pallas_fused(
         q, k_new, v_new, pools.k, pools.v, tables, kv_lens, interpret=True,
-        **pools.kw)
+        **pools.kw, **wkw)
     np.testing.assert_allclose(np.asarray(got)[act], np.asarray(want)[act],
                                rtol=PAGED_TOL, atol=PAGED_TOL)
     assert not np.asarray(got)[~act].any()  # an inactive row reads zeros
@@ -146,6 +176,15 @@ def test_fused_decode_matches_scatter_plus_xla(pool):
                                   np.asarray(k_ref[1:]))
     np.testing.assert_array_equal(np.asarray(v_out[1:]),
                                   np.asarray(v_ref[1:]))
+    if walks != "edge":
+        got2, k2, v2 = paged_decode_pallas_fused(
+            q, k_new, v_new, pools.k, pools.v, tables, kv_lens,
+            interpret=True, row_group=2, **pools.kw, **wkw)
+        np.testing.assert_array_equal(np.asarray(got2), np.asarray(got))
+        np.testing.assert_array_equal(np.asarray(k2[1:]),
+                                      np.asarray(k_out[1:]))
+        np.testing.assert_array_equal(np.asarray(v2[1:]),
+                                      np.asarray(v_out[1:]))
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
@@ -471,37 +510,46 @@ def test_multi_token_verify_no_window_alias_at_table_edge():
 
 
 def _ragged_fixture(seed, b=5, h=4, kh=2, hd=128, ps=16, n_pages=32,
-                    dtype=jnp.float32):
+                    dtype=jnp.float32, ring=False):
     rng = jax.random.split(jax.random.PRNGKey(seed), 5)
+    if ring:  # walks of 5, 1, 3, 0, 2 and 1 pages (``_ring_walks``)
+        tables, kv_lens = _ring_walks(ps)
+        b = len(kv_lens)
+    else:
+        tables = jnp.asarray(
+            np.random.default_rng(seed).permutation(n_pages - 1)[: b * 3]
+            .reshape(b, 3) + 1, jnp.int32)
+        # ragged: multi-page, inactive (0), one token past a page's edge,
+        # page-boundary, single-token rows
+        kv_lens = jnp.asarray([40, 0, 17, 48, 1], jnp.int32)
     k_pages = jax.random.normal(rng[0], (n_pages, kh, ps, hd), dtype)
     v_pages = jax.random.normal(rng[1], (n_pages, kh, ps, hd), dtype)
     q = jax.random.normal(rng[2], (b, h, hd), dtype)
     k_new = jax.random.normal(rng[3], (b, kh, hd), dtype)
     v_new = jax.random.normal(rng[4], (b, kh, hd), dtype)
-    tables = jnp.asarray(
-        np.random.default_rng(seed).permutation(n_pages - 1)[: b * 3]
-        .reshape(b, 3) + 1, jnp.int32)
-    # ragged: multi-page, inactive (0), one token past a page's edge,
-    # page-boundary, single-token rows
-    kv_lens = jnp.asarray([40, 0, 17, 48, 1], jnp.int32)
     return q, k_new, v_new, k_pages, v_pages, tables, kv_lens
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_multirow_fused_parity_bf16(dtype):
+@pytest.mark.parametrize("dtype,ring", [
+    pytest.param(jnp.float32, False, id="f32"),
+    pytest.param(jnp.bfloat16, False, id="bf16"),
+    pytest.param(jnp.float32, True, id="f32-ring"),
+    pytest.param(jnp.bfloat16, True, id="bf16-ring")])
+def test_multirow_fused_parity_bf16(dtype, ring):
     """Fused walk+RMW group kernel vs per-row: outputs and REAL pool pages
     bit-identical (the cross-row RMW pipeline crossing group boundaries),
     on a float32 pool and on the engine's own types (bf16 q, new K/V and
-    pool)."""
+    pool); the ``ring`` cases on walks of 0 to 5 pages through the DMA
+    ring, the row after a 5-page walk holding one page."""
     from lmrs_tpu.ops.paged_attention import paged_decode_pallas_fused
 
-    q, kn, vn, kp, vp, tables, kv_lens = _ragged_fixture(1, dtype=dtype)
+    q, kn, vn, kp, vp, tables, kv_lens = _ragged_fixture(1, dtype=dtype,
+                                                         ring=ring)
     want, k_ref, v_ref = paged_decode_pallas_fused(
         q, kn, vn, kp, vp, tables, kv_lens, interpret=True)
     # 4 is the dense cells' row_group (a tail of one row behind three
     # padded ones); 5 is one group and no tail
-    for g in (2, 4, 5):
+    for g in ((4,) if ring else (2, 4, 5)):
         got, k_out, v_out = paged_decode_pallas_fused(
             q, kn, vn, kp, vp, tables, kv_lens, interpret=True, row_group=g)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

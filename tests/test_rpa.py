@@ -145,20 +145,25 @@ def test_rpa_verify_parity_vs_multi():
     _assert_pool_parity(v_out, v_ref, tables, upto, ps)
 
 
-@pytest.mark.parametrize("pool", ["f32", "bf16"])
-def test_rpa_mixed_spans_match_xla_reference(pool):
+@pytest.mark.parametrize("pool,ring", [
+    pytest.param("f32", False, id="f32"),
+    pytest.param("bf16", False, id="bf16"),
+    pytest.param("f32", True, id="f32-ring")])
+def test_rpa_mixed_spans_match_xla_reference(pool, ring):
     """A genuinely MIXED span list — decode rows, a long prefill-slice
     row whose length is not a SPAN_QT multiple, and an inactive row —
     against the scatter+gather reference (the sp>1 / CPU-fallback path):
     in-span outputs agree and pools agree over every valid prefix.  All
     spans are narrow (SPAN_QT-token tiles through the decode walk): on a
     bf16 pool the walk multiplies in bf16 with its float32 operands split
-    into stacked rows, and owes the float32 reference the same tolerance."""
+    into stacked rows, and owes the float32 reference the same tolerance.
+    The ``ring`` case puts the rows further in, so the tiles' walks are
+    5, 3 and 3 pages long through the walk's DMA ring."""
     q_lens = [1, 13, 1, 0]
-    bases = np.asarray([20, 7, 0, 0], np.int32)
+    bases = np.asarray([70, 30, 0, 0] if ring else [20, 7, 0, 0], np.int32)
     ps = 16
     qs, total, qf, knf, vnf, kp, vp, tables, row_flat = _span_fixture(
-        2, q_lens, kh=2, h=4, ps=ps)
+        2, q_lens, kh=2, h=4, ps=ps, width=5 if ring else 3)
     ref_pools = kp, vp
     if pool == "bf16":
         # new K/V the pool holds exactly; the reference reads float32 pages
